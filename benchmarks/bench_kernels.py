@@ -88,6 +88,8 @@ def _ep_dispatch_case(fast):
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
                          if env.get("PYTHONPATH") else src)
+    # the forced host mesh is a CPU mesh; the parent may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, "-c", _EP_BENCH_SCRIPT, "fast" if fast else "full"],
         capture_output=True, text=True, timeout=900, env=env)

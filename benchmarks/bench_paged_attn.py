@@ -30,8 +30,8 @@ def _decode_case(rs, *, B, n_lp, page_size, Hp, KV, hd, page_counts,
     profile; tp=1 so ps_loc == page_size."""
     n_pages = 1 + sum(page_counts)
     q = jnp.asarray(rs.randn(B, 1, Hp, hd), dtype)
-    kp = jnp.asarray(rs.randn(n_pages, page_size, KV, hd), dtype)
-    vp = jnp.asarray(rs.randn(n_pages, page_size, KV, hd), dtype)
+    kp = jnp.asarray(rs.randn(n_pages, KV, page_size, hd), dtype)
+    vp = jnp.asarray(rs.randn(n_pages, KV, page_size, hd), dtype)
     table = np.zeros((B, n_lp), np.int32)
     nxt = 1
     pos = np.zeros((B,), np.int32)
@@ -53,11 +53,11 @@ def _gathered_attn(q, kp, vp, table, mask):
     grouped-einsum scores, softmax, PV contraction (the same math
     `_paged_scores_combine` runs per layer)."""
     B, Qn, Hp, hd = q.shape
-    _, ps_loc, KV, _ = kp.shape
-    S_g = table.shape[1] * ps_loc
+    KV = kp.shape[1]
     g = Hp // KV
-    k_g = ops.paged_gather(kp, table).reshape(B, S_g, KV, hd)
-    v_g = ops.paged_gather(vp, table).reshape(B, S_g, KV, hd)
+    k_g = ops.paged_gather(kp, table)
+    v_g = ops.paged_gather(vp, table)
+    S_g = k_g.shape[1]
     s = jnp.einsum("bqkgd,bskd->bqkgs", q.reshape(B, Qn, KV, g, hd), k_g,
                    preferred_element_type=jnp.float32) * hd ** -0.5
     s = jnp.where(mask[:, :, None, None, :], s.reshape(B, Qn, KV, g, S_g),

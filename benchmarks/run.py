@@ -45,6 +45,8 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     os.makedirs("experiments/bench", exist_ok=True)
     names = [args.only] if args.only else BENCHES
     print("name,us_per_call,derived")

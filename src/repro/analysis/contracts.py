@@ -73,6 +73,9 @@ class CompileCounter:
             self.bump(label)  # runs at trace time, not per call
             return fn(*args, **kwargs)
 
+        # the program takes the label's name: compile logs, compile-time
+        # events and profiler traces then say which step they are
+        traced.__name__ = traced.__qualname__ = label
         return jax.jit(traced, **jit_kwargs)
 
     def __getitem__(self, label: str) -> int:

@@ -442,6 +442,32 @@ class Runner:
         out_specs = (P(), pspecs)
         return _shard_map(fn, self.mesh, in_specs, out_specs)
 
+    def make_paged_logits(self, page_size: int):
+        """Logit twins of `make_paged_prefill` / `make_paged_decode_step`
+        for comparing serving paths numerically: the same steps, with
+        the vocab-sharded fp32 logits in place of the sampled tokens.
+        Returns ``(prefill, decode)``: ``prefill(params, pools, tokens
+        (C,), base, n_valid, table_row) -> (logits (C, Vp), pools)`` and
+        ``decode(params, pools, token (B,), pos (B,), table, active) ->
+        (logits (B, Vp), pools)``."""
+        cfg, env, flags = self.cfg, self.env, self.flags
+        pspecs = paged_cache_specs(cfg, env)
+
+        def prefill_logits(params, pools, tokens, base, n_valid, table_row):
+            return M.paged_prefill_logits(
+                cfg, env, params, pools, tokens, base, n_valid, table_row,
+                page_size=page_size, flags=flags)
+
+        def decode_logits(params, pools, token, pos, table, active):
+            return M.paged_decode_logits(cfg, env, params, pools, token, pos,
+                                         table, active, page_size=page_size,
+                                         flags=flags)
+
+        in_specs = (self.specs, pspecs, P(), P(), P(), P())
+        out_specs = (P(None, env.tp_axis), pspecs)
+        return (_shard_map(prefill_logits, self.mesh, in_specs, out_specs),
+                _shard_map(decode_logits, self.mesh, in_specs, out_specs))
+
     # -- speculative decoding (draft proposals + verify) -----------------------
     def make_paged_draft_propose(self, page_size: int, k: int):
         """Drafter-side propose step (call on the DRAFTER's runner):
@@ -585,16 +611,15 @@ def globalize_shapes(shape_tree, spec_tree, mesh_sizes):
 def paged_cache_specs(cfg, env: AxisEnv):
     """PartitionSpecs for the paged KV pools (serving/online.py).
 
-    Pool layout per layer: (n_pages, page_size, KV, hd) with the page_size
+    Pool layout per layer: (n_pages, KV, page_size, hd) with the page_size
     dim sharded over tp (each rank stores ps_loc = page_size/tp offsets of
     every page); uniform archs carry a leading layer dim.  Pages are
     slot-agnostic and may be referenced by several page tables at once —
     refcounted prefix sharing and the cross-request radix cache retain a
     page across requests; the pools themselves never change shape for it."""
     lead = 1 if (cfg.uniform_blocks and not cfg.is_encoder_decoder) else 0
-    one = {"self": {"k": P(*([None] * lead), None, env.tp_axis, None, None),
-                    "v": P(*([None] * lead), None, env.tp_axis, None,
-                           None)}}
+    spec = P(*([None] * lead), None, None, env.tp_axis, None)
+    one = {"self": {"k": spec, "v": spec}}
     if lead:
         return one
     return [one for _ in range(cfg.n_layers)]

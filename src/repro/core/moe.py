@@ -118,19 +118,28 @@ def init_moe(key, cfg, env: AxisEnv):
     return params, specs
 
 
+def _in_groups(n_rows: int, group_sizes):
+    """(n_rows, 1) bool: rows inside some group of a ragged_dot."""
+    return (jnp.arange(n_rows) < jnp.sum(group_sizes))[:, None]
+
+
 def grouped_ffn(cfg, w1, w2, w3, xs, group_sizes):
     """Grouped expert FFN over expert-sorted rows.
 
     xs (cap, d), w* (E_l, d, ff)/(E_l, ff, d), group_sizes (E_l,).
-    Rows beyond sum(group_sizes) produce zeros (ragged_dot semantics).
+    Rows beyond sum(group_sizes) produce zeros and take no gradient.
+    On TPU, ragged_dot leaves those rows of its output and of its lhs
+    cotangent unwritten, so both ends are masked here.
     This is the compute the `kernels/grouped_matmul` Pallas kernel targets.
     """
+    live = _in_groups(xs.shape[0], group_sizes)
+    xs = jnp.where(live, xs, 0)
     h = jax.lax.ragged_dot(xs, w1, group_sizes)
     if cfg.mlp_act in L.GATED_ACTS:
         h = L._act(cfg.mlp_act, h) * jax.lax.ragged_dot(xs, w3, group_sizes)
     else:
         h = L._act(cfg.mlp_act, h)
-    return jax.lax.ragged_dot(h, w2, group_sizes)
+    return jnp.where(live, jax.lax.ragged_dot(h, w2, group_sizes), 0)
 
 
 def _fused_ragged_ref(act, x, w1, w2, w3, tok, gate, group_sizes):
@@ -139,7 +148,8 @@ def _fused_ragged_ref(act, x, w1, w2, w3, tok, gate, group_sizes):
     Used as the custom-vjp backward of `fused_ffn` and as the exact-parity
     fallback when the fused path is unavailable."""
     T, d = x.shape
-    xs = jnp.take(x, tok, axis=0).astype(jnp.float32)
+    live = _in_groups(tok.shape[0], group_sizes)       # as in grouped_ffn
+    xs = jnp.where(live, jnp.take(x, tok, axis=0).astype(jnp.float32), 0)
     w1f, w2f = w1.astype(jnp.float32), w2.astype(jnp.float32)
     h = jax.lax.ragged_dot(xs, w1f, group_sizes)
     if w3 is not None:
@@ -147,7 +157,7 @@ def _fused_ragged_ref(act, x, w1, w2, w3, tok, gate, group_sizes):
             xs, w3.astype(jnp.float32), group_sizes)
     else:
         h = L._act(act, h)
-    out = jax.lax.ragged_dot(h, w2f, group_sizes)      # rows past sum() = 0
+    out = jnp.where(live, jax.lax.ragged_dot(h, w2f, group_sizes), 0)
     out = out * gate.astype(jnp.float32)[:, None]
     return jnp.zeros((T, d), jnp.float32).at[tok].add(out)
 
@@ -275,7 +285,7 @@ def _ep_moe_ffn(cfg, env: AxisEnv, params, x, w1, w2, w3, *,
                    ).astype(jnp.int32)
     xr = x_recv.reshape(R, d)
     ones = valid2.astype(cdt)                      # gate=1: gates stay home
-    if kops.INTERPRET:
+    if kops.interpret_default():
         y_r = fused_ffn(cfg.mlp_act, xr, w1, w2, w3, order2, ones,
                         group_sizes)
     else:
@@ -357,10 +367,10 @@ def moe_ffn(cfg, env: AxisEnv, params, x: jax.Array, *,
         # training shapes — stay on ragged/batched there until the ROADMAP
         # tile sweep (T-tiled output + DMA gather) lands.
         if env.tp > 1:
-            dispatch = ("ep" if kops.INTERPRET and T % env.tp == 0
+            dispatch = ("ep" if kops.interpret_default() and T % env.tp == 0
                         else "batched")
         else:
-            dispatch = "fused" if kops.INTERPRET else "ragged"
+            dispatch = "fused" if kops.interpret_default() else "ragged"
     if dispatch not in ("fused", "ragged", "batched", "ep"):
         raise ValueError(f"unknown moe dispatch mode: {dispatch!r}")
     if dispatch == "ep" and T % env.tp:

@@ -103,20 +103,21 @@ def grouped_matmul_aligned(lhs: jax.Array, rhs: jax.Array,
                            bm: int = 128, bk: int = 128, bn: int = 128,
                            interpret: bool = False) -> jax.Array:
     """lhs (M, K) group-aligned; rhs (G, K, N); tile_group (M/bm,) int32
-    (values in [0, G], G = overflow/zero tile).  Returns (M, N)."""
+    (values in [0, G], G = overflow tile: it reads group G-1's weights
+    and the kernel zeroes its output).  Returns (M, N)."""
     M, K = lhs.shape
     G, K2, N = rhs.shape
     assert K == K2 and M % bm == 0 and K % bk == 0 and N % bn == 0
     n_m, n_n, n_k = M // bm, N // bn, K // bk
-    # pad rhs with a zero overflow group so gid==G is addressable
-    rhs_p = jnp.concatenate([rhs, jnp.zeros((1, K, N), rhs.dtype)], axis=0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_m, n_n, n_k),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k, tg: (i, k)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, k, tg: (tg[i], k, j)),
+            pl.BlockSpec((1, bk, bn),
+                         lambda i, j, k, tg: (jnp.minimum(tg[i], G - 1),
+                                              k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, tg: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
@@ -127,7 +128,7 @@ def grouped_matmul_aligned(lhs: jax.Array, rhs: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
         interpret=interpret,
     )
-    return fn(tile_group, lhs, rhs_p)
+    return fn(tile_group, lhs, rhs)
 
 
 # ---------------------------------------------------------------------------
@@ -135,11 +136,12 @@ def grouped_matmul_aligned(lhs: jax.Array, rhs: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _one_hot_rows(idx, n_rows):
-    """(bm,) int32 row indices -> (bm, n_rows) fp32 selection matrix.
-    broadcasted_iota keeps the comparison 2D (a Mosaic requirement)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n_rows), 1)
-    return (idx[:, None] == iota).astype(jnp.float32)
+def _one_hot_cols(idx, n_rows):
+    """(1, bm) int32 row indices -> (n_rows, bm) fp32 selection matrix,
+    column j one-hot at row idx[j].  Built with the indices along lanes,
+    so Mosaic needs no lane-to-sublane transpose."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n_rows, idx.shape[1]), 0)
+    return (iota == idx).astype(jnp.float32)
 
 
 def _fused_kernel(tile_group_ref, row_idx_ref, gates_ref, x_ref,
@@ -168,10 +170,10 @@ def _fused_kernel(tile_group_ref, row_idx_ref, gates_ref, x_ref,
 
     @pl.when(f == 0)
     def _gather():
-        oh = _one_hot_rows(row_idx_ref[0], T)           # (bm, T)
+        oh = _one_hot_cols(row_idx_ref[0], T)           # (T, bm)
         x_tile_ref[...] = jax.lax.dot_general(
             oh, x_ref[...].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
+            dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)         # (bm, d)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -195,13 +197,14 @@ def _fused_kernel(tile_group_ref, row_idx_ref, gates_ref, x_ref,
 
     @pl.when(f == n_f - 1)
     def _combine():
-        # invalid / overflow rows carry gate == 0, so clamped indices that
-        # gathered an arbitrary real row contribute nothing.
-        contrib = acc_ref[...] * gates_ref[0][:, None]
-        oh = _one_hot_rows(row_idx_ref[0], T)           # (bm, T)
+        # the gates weight the selection columns: invalid / overflow rows
+        # carry gate == 0, so clamped indices that gathered an arbitrary
+        # real row (and all-padding tiles run on a real expert's weights)
+        # contribute nothing.
+        oh = _one_hot_cols(row_idx_ref[0], T) * gates_ref[0]   # (T, bm)
         out_ref[...] += jax.lax.dot_general(            # scatter-add (T, d)
-            oh, contrib,
-            dimension_numbers=(((0,), (0,)), ((), ())),
+            oh, acc_ref[...],
+            dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
 
@@ -218,6 +221,12 @@ def fused_moe_ffn(x: jax.Array, w1: jax.Array, w2: jax.Array,
     router gate per row, 0 for padding/overflow;  tile_group (n_m,) int32:
     expert per row tile, G for all-padding tiles.  Returns (T, d) fp32 —
     the combined `sum_e gate * FFN_e(x)` partial.
+
+    All-padding tiles read expert G-1's weights (index clamped) and are
+    zeroed by their gates, so no zero "overflow" expert is materialized.
+    Row indices and gates ride as (n_m, 1, bm) so each tile's block has
+    full-extent last two dims, as Mosaic requires.  The (T, d) input and
+    f32 output stay resident in VMEM, which bounds T (docs/kernels.md).
     """
     T, d = x.shape
     G, d2, ff = w1.shape
@@ -226,26 +235,19 @@ def fused_moe_ffn(x: jax.Array, w1: jax.Array, w2: jax.Array,
     n_f = ff // bf
     gated = w3 is not None
 
-    # zero overflow expert so tile_group == G is addressable
-    w1_p = jnp.concatenate([w1, jnp.zeros((1, d, ff), w1.dtype)], axis=0)
-    w2_p = jnp.concatenate([w2, jnp.zeros((1, ff, d), w2.dtype)], axis=0)
-    w_in = [w1_p]
-    w_specs = [pl.BlockSpec((1, d, bf), lambda i, f, tg: (tg[i], 0, f))]
-    if gated:
-        w3_p = jnp.concatenate([w3, jnp.zeros((1, d, ff), w3.dtype)],
-                               axis=0)
-        w_in.append(w3_p)
-        w_specs.append(
-            pl.BlockSpec((1, d, bf), lambda i, f, tg: (tg[i], 0, f)))
-    w_in.append(w2_p)
-    w_specs.append(pl.BlockSpec((1, bf, d), lambda i, f, tg: (tg[i], f, 0)))
+    e = lambda tg, i: jnp.minimum(tg[i], G - 1)
+    w_in = [w1] + ([w3] if gated else []) + [w2]
+    w_specs = [pl.BlockSpec((1, d, bf), lambda i, f, tg: (e(tg, i), 0, f))
+               for _ in range(1 + gated)]
+    w_specs.append(
+        pl.BlockSpec((1, bf, d), lambda i, f, tg: (e(tg, i), f, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_m, n_f),
         in_specs=[
-            pl.BlockSpec((1, bm), lambda i, f, tg: (i, 0)),    # row_idx
-            pl.BlockSpec((1, bm), lambda i, f, tg: (i, 0)),    # gates
+            pl.BlockSpec((1, 1, bm), lambda i, f, tg: (i, 0, 0)),  # row_idx
+            pl.BlockSpec((1, 1, bm), lambda i, f, tg: (i, 0, 0)),  # gates
             pl.BlockSpec((T, d), lambda i, f, tg: (0, 0)),     # x resident
             *w_specs,
         ],
@@ -267,4 +269,5 @@ def fused_moe_ffn(x: jax.Array, w1: jax.Array, w2: jax.Array,
         out_shape=jax.ShapeDtypeStruct((T, d), jnp.float32),
         interpret=interpret,
     )
-    return fn(tile_group, row_idx, gates.astype(jnp.float32), x, *w_in)
+    return fn(tile_group, row_idx.reshape(n_m, 1, bm),
+              gates.astype(jnp.float32).reshape(n_m, 1, bm), x, *w_in)

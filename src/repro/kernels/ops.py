@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute with interpret=True (the kernel
-body runs as jnp ops, validating the tiling logic); on a real TPU the same
-call sites compile the Mosaic kernels.  `INTERPRET` flips automatically.
+Off a TPU the kernels execute with interpret=True (the kernel body runs
+as jnp ops, validating the tiling logic); on a TPU the same call sites
+compile the Mosaic kernels.  `interpret_default()` decides per call, from
+the backend in use when the kernel is traced; an explicit `interpret=`
+overrides it (the v5e compile tests pass False on a CPU host).
 """
 from __future__ import annotations
 
@@ -16,7 +18,11 @@ from repro.kernels import normhead as _nh
 from repro.kernels import paged_attn as _pa
 from repro.kernels import wkv6 as _wkv
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_default() -> bool:
+    """True unless JAX's default backend is a TPU.  Read at call time, so
+    importing this module never initialises a backend."""
+    return jax.default_backend() != "tpu"
 
 
 def _align_groups(lhs, group_sizes, bm: int):
@@ -54,7 +60,7 @@ def grouped_matmul(lhs, rhs, group_sizes, *, bm: int = 128, bk: int = 128,
     """Drop-in for jax.lax.ragged_dot: lhs (M,K) group-sorted rows,
     rhs (G,K,N), group_sizes (G,).  Handles non-aligned groups by
     re-laying rows out to bm-aligned group starts."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     M, K = lhs.shape
     G, _, N = rhs.shape
     bm = min(bm, max(8, M))
@@ -126,7 +132,7 @@ def moe_fused_ffn(x, w1, w2, w3, tok, gate, group_sizes, *,
     expert.  Slots beyond sum(group_sizes) are dropped (ragged_dot
     semantics).  Returns the combined (T, d) fp32 partial.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     T, d = x.shape
     G, _, ff = w1.shape
     cap = tok.shape[0]
@@ -143,24 +149,24 @@ def moe_fused_ffn(x, w1, w2, w3, tok, gate, group_sizes, *,
 def paged_gather(pool, table):
     """Gather KV pages for paged-attention decode (serving/online.py).
 
-    pool (n_pages, ps_loc, ...) is a device-resident page pool whose
+    pool (n_pages, KV, ps_loc, hd) is a device-resident page pool whose
     in-page offset dim is the tp-local slice of the global page_size;
-    table (..., n_lp) holds the physical page id backing each logical
-    page (0 = the reserved scratch page, which doubles as the
-    "unallocated" sentinel — callers mask those positions).  Returns
-    (..., n_lp, ps_loc, ...): each slot's logical KV sequence assembled
-    in logical-page order, so reshaping the two page dims together
-    yields a dense (S, ...) cache view the standard decode-attention
+    table (B, n_lp) holds the physical page id backing each logical page
+    (0 = the reserved scratch page, which doubles as the "unallocated"
+    sentinel — callers mask those positions).  Returns the dense
+    (B, n_lp * ps_loc, KV, hd) view of each slot's logical KV sequence
+    in logical-page order, the layout the standard decode-attention
     einsums consume unchanged.
 
-    This is a pure gather along the page dim — it materializes the full
-    table-width view in HBM once per layer per tick.  It backs the
-    "gathered" paged-attention mode (the parity oracle and real-TPU
-    fallback); the "fused" mode (`paged_attention` below) walks the page
-    table inside the attention kernel instead, so this view never
-    exists.
+    This materializes the full table-width view in HBM once per layer
+    per tick.  It backs the "gathered" paged-attention mode (the parity
+    oracle and TPU fallback); the "fused" mode walks the page table
+    inside the attention kernel instead, so this view never exists.
     """
-    return jnp.take(pool, table, axis=0)
+    B, n_lp = table.shape
+    _, KV, ps_loc, hd = pool.shape
+    g = jnp.take(pool, table, axis=0)           # (B, n_lp, KV, ps_loc, hd)
+    return g.swapaxes(2, 3).reshape(B, n_lp * ps_loc, KV, hd)
 
 
 def _pa_group_q(q, KV):
@@ -181,6 +187,15 @@ def _pa_ungroup(x, Qn, Hp):
     return y.reshape((B, Qn, Hp) + x.shape[3:])
 
 
+def _pa_mask(mask, k_pool, table):
+    """(B, Q, S_g) bool -> (B, n_lp, Q, ps_loc) int32: one full-extent
+    (Q, ps_loc) block per logical page, in a dtype Mosaic can load."""
+    B, Qn, _ = mask.shape
+    n_lp, ps_loc = table.shape[1], k_pool.shape[2]
+    return mask.reshape(B, Qn, n_lp, ps_loc).swapaxes(1, 2).astype(
+        jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_scores_max(q, k_pool, table, mask, *,
                                interpret: bool | None = None):
@@ -188,7 +203,7 @@ def paged_attention_scores_max(q, k_pool, table, mask, *,
     scores, page table walked in-kernel (kernels/paged_attn.py).
 
     q (B, Q, Hp, hd): query-batched heads — Q=1 decode, Q=C chunked
-    prefill, Q=k+1 spec-decode verify;  k_pool (n_pages, ps_loc, KV, hd):
+    prefill, Q=k+1 spec-decode verify;  k_pool (n_pages, KV, ps_loc, hd):
     the layer's K page pool (ps_loc = this tp rank's row-slice of each
     page);  table (B, n_lp) int32 physical page per logical page (0 =
     scratch/unallocated — rows must be masked);  mask (B, Q, S_g) bool
@@ -206,14 +221,12 @@ def paged_attention_scores_max(q, k_pool, table, mask, *,
     `paged_attention_accumulate` so p is computed against the GLOBAL max
     exactly like the gathered oracle.
     """
-    interpret = INTERPRET if interpret is None else interpret
-    B, Qn, Hp, hd = q.shape
-    _, ps_loc, KV, _ = k_pool.shape
-    n_lp = table.shape[1]
+    interpret = interpret_default() if interpret is None else interpret
+    KV = k_pool.shape[1]
     m = _pa.paged_attn_scores_max(
-        _pa_group_q(q, KV), k_pool, table,
-        mask.reshape(B, Qn, n_lp, ps_loc), interpret=interpret)
-    return _pa_ungroup(m, Qn, Hp)
+        _pa_group_q(q, KV), k_pool, table, _pa_mask(mask, k_pool, table),
+        interpret=interpret)
+    return _pa_ungroup(m, q.shape[1], q.shape[2])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -231,13 +244,12 @@ def paged_attention_accumulate(q, k_pool, v_pool, table, mask, m_safe, *,
     (num (B, Q, Hp, hd), den (B, Q, Hp)) over this rank's pool rows;
     callers psum both over tp and normalize.
     """
-    interpret = INTERPRET if interpret is None else interpret
-    B, Qn, Hp, hd = q.shape
-    _, ps_loc, KV, _ = k_pool.shape
-    n_lp = table.shape[1]
+    interpret = interpret_default() if interpret is None else interpret
+    _, Qn, Hp, _ = q.shape
+    KV = k_pool.shape[1]
     num, den = _pa.paged_attn_accumulate(
         _pa_group_q(q, KV), k_pool, v_pool, table,
-        mask.reshape(B, Qn, n_lp, ps_loc),
+        _pa_mask(mask, k_pool, table),
         _pa_group_q(m_safe[..., None], KV)[..., 0], interpret=interpret)
     return _pa_ungroup(num, Qn, Hp), _pa_ungroup(den, Qn, Hp)
 
@@ -286,7 +298,7 @@ def ep_all_to_all(x, *, axis_name: str):
 def normhead_logits(x, w, *, bt: int = 128, bv: int = 128, bk: int = 128,
                     interpret: bool | None = None):
     """Fused NormHead: x (T,d) @ normalize_rows(w (V,d)).T -> (T,V) fp32."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     T, d = x.shape
     V, _ = w.shape
     bt_, bv_, bk_ = min(bt, T), min(bv, V), min(bk, d)
@@ -305,7 +317,7 @@ def wkv6(r, k, v, w, u, state, *, chunk: int = 256,
          interpret: bool | None = None):
     """RWKV6 recurrence.  r,k,v,w (B,T,H,hd); u (H,hd);
     state (B,H,hd,hd) fp32.  Returns (y (B,T,H,hd), state')."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     B, T, H, hd = r.shape
     ck = min(chunk, T)
     while T % ck:

@@ -68,8 +68,8 @@ def _masked_scores(q_ref, k_ref, mask_ref, *, g: int, n_q: int,
     """Shared per-page score block: (g*Q, ps_loc) masked f32 scores and
     the broadcast (g*Q, ps_loc) mask."""
     q = q_ref[0, 0].astype(jnp.float32)                  # (g*Q, hd)
-    kp = k_ref[0, :, 0, :].astype(jnp.float32)           # (ps_loc, hd)
-    msk = mask_ref[0, :, 0, :]                           # (Q, ps_loc)
+    kp = k_ref[0, 0].astype(jnp.float32)                 # (ps_loc, hd)
+    msk = mask_ref[0, 0] != 0                            # (Q, ps_loc)
     mskg = jnp.broadcast_to(msk[None], (g, n_q, msk.shape[-1])
                             ).reshape(g * n_q, -1)       # (g*Q, ps_loc)
     s = jax.lax.dot_general(                             # (g*Q, ps_loc)
@@ -96,7 +96,7 @@ def _max_kernel(table_ref, q_ref, k_ref, mask_ref, m_ref, m_s_ref, *,
 
     @pl.when(i == n_lp - 1)
     def _flush():
-        m_ref[0, 0] = m_s_ref[...][:, 0]
+        m_ref[0, 0] = m_s_ref[...]
 
 
 def _acc_kernel(table_ref, q_ref, k_ref, v_ref, mask_ref, msafe_ref,
@@ -113,8 +113,8 @@ def _acc_kernel(table_ref, q_ref, k_ref, v_ref, mask_ref, msafe_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         den_s_ref[...] = jnp.zeros_like(den_s_ref)
 
-    vp = v_ref[0, :, 0, :]                               # (ps_loc, hd)
-    m_safe = msafe_ref[0, 0][:, None]                    # (g*Q, 1)
+    vp = v_ref[0, 0]                                     # (ps_loc, hd)
+    m_safe = msafe_ref[0, 0]                             # (g*Q, 1)
     p = jnp.where(mskg, jnp.exp(s - m_safe), 0.0)        # (g*Q, ps_loc)
     # round p to the pool dtype BEFORE the PV contraction — the same
     # point the gathered combine rounds (`p.astype(cdt)` einsum), so
@@ -129,31 +129,39 @@ def _acc_kernel(table_ref, q_ref, k_ref, v_ref, mask_ref, msafe_ref,
     @pl.when(i == n_lp - 1)
     def _flush():
         num_ref[0, 0] = acc_ref[...]
-        den_ref[0, 0] = den_s_ref[...][:, 0]
+        den_ref[0, 0] = den_s_ref[...]
 
 
 def _check_shapes(q, k_pool, table, mask):
     B, KV, GQ, hd = q.shape
-    n_pages, ps_loc, KV2, hd2 = k_pool.shape
+    n_pages, KV2, ps_loc, hd2 = k_pool.shape
     assert (KV, hd) == (KV2, hd2), (q.shape, k_pool.shape)
     n_lp = table.shape[1]
-    n_q = mask.shape[1]
+    n_q = mask.shape[2]
     g = GQ // n_q
-    assert g * n_q == GQ and mask.shape == (B, n_q, n_lp, ps_loc), \
+    assert g * n_q == GQ and mask.shape == (B, n_lp, n_q, ps_loc), \
         (q.shape, mask.shape, table.shape)
     return B, KV, GQ, hd, ps_loc, n_lp, n_q, g
 
 
+# Every block below spans the full extent of its array's last two dims
+# (page rows x hd, Q x ps_loc, g*Q x 1), as Mosaic requires of blocks
+# narrower than an (8, 128) tile.
 def _qkm_specs(GQ, hd, ps_loc, n_q):
     """BlockSpecs shared by both passes: q block, K page block (physical
     page selected by the scalar-prefetched table — dynamic-slice DMA of
     one page row-block per step), mask block."""
     return [
         pl.BlockSpec((1, 1, GQ, hd), lambda b, k, i, t: (b, k, 0, 0)),
-        pl.BlockSpec((1, ps_loc, 1, hd),
-                     lambda b, k, i, t: (t[b, i], 0, k, 0)),
-        pl.BlockSpec((1, n_q, 1, ps_loc), lambda b, k, i, t: (b, 0, i, 0)),
+        pl.BlockSpec((1, 1, ps_loc, hd),
+                     lambda b, k, i, t: (t[b, i], k, 0, 0)),
+        pl.BlockSpec((1, 1, n_q, ps_loc), lambda b, k, i, t: (b, i, 0, 0)),
     ]
+
+
+def _row_spec(GQ):
+    """(1, 1, g*Q, 1) block of a per-query-row statistic."""
+    return pl.BlockSpec((1, 1, GQ, 1), lambda b, k, i, t: (b, k, 0, 0))
 
 
 def paged_attn_scores_max(q, k_pool, table, mask, *,
@@ -162,12 +170,12 @@ def paged_attn_scores_max(q, k_pool, table, mask, *,
 
     q (B, KV, g*Q, hd): per-kv-head query groups, g-major (a (Q, ps_loc)
     mask block broadcasts over the group);  k_pool
-    (n_pages, ps_loc, KV, hd): this rank's page-row pool;  table
+    (n_pages, KV, ps_loc, hd): this rank's page-row pool;  table
     (B, n_lp) int32 (0 = scratch/unallocated);  mask
-    (B, Q, n_lp, ps_loc) bool.  Returns m (B, KV, g*Q) f32 — the max
-    masked score over this rank's pool rows, -inf where nothing is
-    valid.  Callers pmax over tp and feed the safe max to
-    `paged_attn_accumulate`.
+    (B, n_lp, Q, ps_loc) int32, nonzero where valid.  Returns m
+    (B, KV, g*Q) f32 — the max masked score over this rank's pool rows,
+    -inf where nothing is valid.  Callers pmax over tp and feed the safe
+    max to `paged_attn_accumulate`.
     """
     B, KV, GQ, hd, ps_loc, n_lp, n_q, g = _check_shapes(q, k_pool, table,
                                                         mask)
@@ -176,17 +184,17 @@ def paged_attn_scores_max(q, k_pool, table, mask, *,
         num_scalar_prefetch=1,
         grid=(B, KV, n_lp),
         in_specs=[qspec, kspec, mspec],
-        out_specs=[pl.BlockSpec((1, 1, GQ), lambda b, k, i, t: (b, k, 0))],
+        out_specs=[_row_spec(GQ)],
         scratch_shapes=[pltpu.VMEM((GQ, 1), jnp.float32)],
     )
     fn = pl.pallas_call(
         functools.partial(_max_kernel, n_lp=n_lp, g=g, n_q=n_q,
                           scale=hd ** -0.5),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, KV, GQ), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((B, KV, GQ, 1), jnp.float32)],
         interpret=interpret,
     )
-    return fn(table.astype(jnp.int32), q, k_pool, mask)[0]
+    return fn(table.astype(jnp.int32), q, k_pool, mask)[0][..., 0]
 
 
 def paged_attn_accumulate(q, k_pool, v_pool, table, mask, m_safe, *,
@@ -212,16 +220,10 @@ def paged_attn_accumulate(q, k_pool, v_pool, table, mask, m_safe, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, KV, n_lp),
-        in_specs=[
-            qspec, kspec,
-            pl.BlockSpec((1, ps_loc, 1, hd),
-                         lambda b, k, i, t: (t[b, i], 0, k, 0)),
-            mspec,
-            pl.BlockSpec((1, 1, GQ), lambda b, k, i, t: (b, k, 0)),
-        ],
+        in_specs=[qspec, kspec, kspec, mspec, _row_spec(GQ)],
         out_specs=[
             pl.BlockSpec((1, 1, GQ, hd), lambda b, k, i, t: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, GQ), lambda b, k, i, t: (b, k, 0)),
+            _row_spec(GQ),
         ],
         scratch_shapes=[pltpu.VMEM((GQ, hd), jnp.float32),
                         pltpu.VMEM((GQ, 1), jnp.float32)],
@@ -231,9 +233,9 @@ def paged_attn_accumulate(q, k_pool, v_pool, table, mask, m_safe, *,
                           scale=hd ** -0.5),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, KV, GQ, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, GQ), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, KV, GQ, 1), jnp.float32)],
         interpret=interpret,
     )
     num, den = fn(table.astype(jnp.int32), q, k_pool, v_pool, mask,
-                  m_safe.reshape(B, KV, GQ))
-    return num, den
+                  m_safe[..., None])
+    return num, den[..., 0]

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro import api
 from repro.configs.base import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.serving.draft import (ConfigDrafter, SelfDrafter,
@@ -200,8 +201,10 @@ def run_online(cfg, mesh, flags, args) -> None:
                    "slo": dataclasses.asdict(slo) if slo else None,
                    "tp": args.tp, "moe_dispatch": args.moe_dispatch,
                    "paged_attn": args.paged_attn},
-        "note": ("interpret-mode CPU wall clock - scheduling/latency "
-                 "shape, NOT TPU performance"),
+        # the device the wall clocks above were taken on
+        "device": {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())},
         "rates": cases,
     }
     with open(args.report, "w") as f:
@@ -330,6 +333,7 @@ def main():
                          "interpret builds, gathered on real TPUs")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_local_mesh(1, args.tp)
     flags = M.RunFlags(moe_dispatch=args.moe_dispatch,

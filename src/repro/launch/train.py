@@ -29,6 +29,7 @@ from repro.configs.base import get_config, get_smoke_config
 from repro.core import spikes as spikes_lib
 from repro.core.edit import EDiTConfig, EDiTTrainer
 from repro.data.pipeline import DataPipeline, PipelineConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.optim import adamw
@@ -83,6 +84,7 @@ def main():
                          "trainer path only")
     args = ap.parse_args()
 
+    enable_compile_cache()
     bs_warmup = None
     if args.bs_warmup:
         if args.edit_workers > 0:

@@ -549,9 +549,10 @@ def expand_cache_from_prefill(prefill_cache):
 #
 # The online engine (serving/online.py) stores the decode KV cache as a
 # slot-agnostic *page pool* instead of a dense (B, S) tensor: pool k/v are
-# (n_pages, ps_loc, KV, hd) with the in-page offset dim sharded over tp
+# (n_pages, KV, ps_loc, hd) with the in-page offset dim sharded over tp
 # (ps_loc = page_size // tp — rank r owns offsets [r*ps_loc, (r+1)*ps_loc)
 # of every page, preserving the dense path's 1/tp cache-memory sharding).
+# One kv head's (ps_loc, hd) page block is one fused-kernel DMA.
 # A per-slot page table maps logical page -> physical page; admission,
 # completion, and preemption are pure table/mask updates, so the jitted
 # step never recompiles.  Physical page 0 is reserved as a scratch page:
@@ -565,7 +566,7 @@ def init_paged_kv_pool(cfg, n_pages: int, page_size: int
     Runner shards the page_size dim over tp via `api.paged_cache_specs`."""
     cdt = jnp.dtype(cfg.compute_dtype)
     hd = cfg.head_dim or cfg.d_model // cfg.n_heads
-    shape = (n_pages, page_size, cfg.n_kv_heads, hd)
+    shape = (n_pages, cfg.n_kv_heads, page_size, hd)
     return {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
 
 
@@ -601,13 +602,13 @@ def _paged_write(pool, k_new, v_new, pos, page_table, owns, *,
     (...,) bool — lanes that are inactive, unallocated, or whose in-page
     offset belongs to another tp rank write to scratch page 0 instead.
     """
-    ps_loc = pool["k"].shape[1]
+    ps_loc = pool["k"].shape[2]
     r = env.tp_index()
     o = pos % page_size
     dest = jnp.where(owns, page_table, 0)
     o_loc = jnp.clip(o - r * ps_loc, 0, ps_loc - 1)
-    return {"k": pool["k"].at[dest, o_loc].set(k_new.astype(cdt)),
-            "v": pool["v"].at[dest, o_loc].set(v_new.astype(cdt))}
+    return {"k": pool["k"].at[dest, :, o_loc].set(k_new.astype(cdt)),
+            "v": pool["v"].at[dest, :, o_loc].set(v_new.astype(cdt))}
 
 
 def _paged_scores_combine(cfg, env: AxisEnv, ad: AttnDims, q_all, k_g, v_g,
@@ -679,7 +680,7 @@ def resolve_paged_attn(mode: str) -> str:
     jnp oracle on real TPUs until the tile sweep (ROADMAP item 3)."""
     if mode == "auto":
         from repro.kernels import ops as kops
-        return "fused" if kops.INTERPRET else "gathered"
+        return "fused" if kops.interpret_default() else "gathered"
     if mode not in ("fused", "gathered"):
         raise ValueError(f"paged_attn must be auto|fused|gathered: {mode}")
     return mode
@@ -690,7 +691,7 @@ def _paged_attention_core(cfg, env: AxisEnv, ad: AttnDims, q_all, pool,
     """Shared query-batched paged-attention core for all three callers
     (decode Q=1, chunked prefill Q=C, spec-decode verify Q=k+1).
 
-    q_all (B, Q, Hp, hd); pool k/v (n_pages, ps_loc, KV, hd); table
+    q_all (B, Q, Hp, hd); pool k/v (n_pages, KV, ps_loc, hd); table
     (B, n_lp); valid (B, Q, S_g).  "fused" walks the page table inside
     the Pallas kernel (kernels/paged_attn.py) and combines the local
     (num, m, den) partials over tp here — the gathered (B, S_g, KV, hd)
@@ -699,8 +700,6 @@ def _paged_attention_core(cfg, env: AxisEnv, ad: AttnDims, q_all, pool,
     the grouped GQA layout; head-padded / unevenly-grouped archs fall
     back to gathered.  Returns (B, Q, Hp, hd)."""
     from repro.kernels import ops as kops
-    hd = ad.head_dim
-    B = q_all.shape[0]
     mode = resolve_paged_attn(paged_attn)
     grouped = (ad.n_heads == ad.heads_padded
                and ad.heads_padded % ad.n_kv == 0)
@@ -716,9 +715,8 @@ def _paged_attention_core(cfg, env: AxisEnv, ad: AttnDims, q_all, pool,
             q_all, pool["k"], pool["v"], table, valid, m_safe)
         num, den = env.psum_tp((num, den))
         return (num / jnp.maximum(den, 1e-20)[..., None]).astype(cdt)
-    S_g = valid.shape[-1]
-    k_g = kops.paged_gather(pool["k"], table).reshape(B, S_g, ad.n_kv, hd)
-    v_g = kops.paged_gather(pool["v"], table).reshape(B, S_g, ad.n_kv, hd)
+    k_g = kops.paged_gather(pool["k"], table)
+    v_g = kops.paged_gather(pool["v"], table)
     return _paged_scores_combine(cfg, env, ad, q_all, k_g, v_g, valid, cdt)
 
 
@@ -743,7 +741,7 @@ def paged_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     B = x.shape[0]
     hd = ad.head_dim
     n_lp = table.shape[1]
-    ps_loc = pool["k"].shape[1]
+    ps_loc = pool["k"].shape[2]
     r = env.tp_index()
 
     wq = env.gather_fsdp(params["wq"], 0, dtype=cdt)
@@ -800,7 +798,7 @@ def paged_prefill_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     C = x.shape[0]
     hd = ad.head_dim
     n_lp = table_row.shape[0]
-    ps_loc = pool["k"].shape[1]
+    ps_loc = pool["k"].shape[2]
     r = env.tp_index()
 
     wq = env.gather_fsdp(params["wq"], 0, dtype=cdt)
@@ -862,7 +860,7 @@ def paged_verify_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     B, Q, d = x.shape
     hd = ad.head_dim
     n_lp = table.shape[1]
-    ps_loc = pool["k"].shape[1]
+    ps_loc = pool["k"].shape[2]
     r = env.tp_index()
 
     wq = env.gather_fsdp(params["wq"], 0, dtype=cdt)

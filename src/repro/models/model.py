@@ -509,7 +509,7 @@ def _pool_ps_loc(cfg, pools) -> int:
     carry a leading layer dim)."""
     pool0 = pools["self"] if cfg.uniform_blocks else pools[0]["self"]
     k = pool0["k"]
-    return k.shape[2] if cfg.uniform_blocks else k.shape[1]
+    return k.shape[3] if cfg.uniform_blocks else k.shape[2]
 
 
 def block_decode_paged(cfg, env: AxisEnv, params, x, pool, pos, table,
@@ -781,21 +781,12 @@ def block_prefill_paged(cfg, env: AxisEnv, params, x, pool, base, n_valid,
     return x, pool
 
 
-def paged_prefill_chunk(cfg: ModelConfig, env: AxisEnv, params, pools,
-                        tokens: jax.Array, base: jax.Array,
-                        n_valid: jax.Array, table_row: jax.Array, *,
-                        page_size: int, flags: RunFlags = DEFAULT_FLAGS,
-                        sample=None):
-    """Prefill one chunk of one request's prompt into its pages.
-
-    tokens (C,) the chunk (tail past n_valid is padding); base (scalar)
-    tokens already written; table_row (n_lp,) the request's page table.
-    Returns (next (scalar int32) — the token after the last valid chunk
-    position, meaningful only on the request's final chunk — and the
-    updated pools).  `sample=(seed, temperature, top_p, top_k)` scalars
-    switches the returned token from greedy to the shared-key-schedule
-    draw at position base + n_valid - 1 (bitwise greedy at temp<=0)."""
-    denv = dataclasses.replace(env, seq_parallel=False)
+def _paged_prefill_hidden(cfg: ModelConfig, denv: AxisEnv, params, pools,
+                          tokens: jax.Array, base: jax.Array,
+                          n_valid: jax.Array, table_row: jax.Array, *,
+                          page_size: int, flags: RunFlags = DEFAULT_FLAGS):
+    """Shared chunked-prefill body: the chunk -> (final-norm hidden
+    states (C, d), pools)."""
     x = emb.embed_tokens(cfg, denv, params["embed"], tokens)  # (C, d)
     ffn = _ffn_kind(cfg, cfg.n_layers - 1)
     valid = L.paged_valid_mask(
@@ -822,7 +813,27 @@ def paged_prefill_chunk(cfg: ModelConfig, env: AxisEnv, params, pools,
                                        valid=valid)
             new_pools.append(p)
         pools = new_pools
-    x = L.apply_norm(cfg, denv, params["final_norm"], x)
+    return L.apply_norm(cfg, denv, params["final_norm"], x), pools
+
+
+def paged_prefill_chunk(cfg: ModelConfig, env: AxisEnv, params, pools,
+                        tokens: jax.Array, base: jax.Array,
+                        n_valid: jax.Array, table_row: jax.Array, *,
+                        page_size: int, flags: RunFlags = DEFAULT_FLAGS,
+                        sample=None):
+    """Prefill one chunk of one request's prompt into its pages.
+
+    tokens (C,) the chunk (tail past n_valid is padding); base (scalar)
+    tokens already written; table_row (n_lp,) the request's page table.
+    Returns (next (scalar int32) — the token after the last valid chunk
+    position, meaningful only on the request's final chunk — and the
+    updated pools).  `sample=(seed, temperature, top_p, top_k)` scalars
+    switches the returned token from greedy to the shared-key-schedule
+    draw at position base + n_valid - 1 (bitwise greedy at temp<=0)."""
+    denv = dataclasses.replace(env, seq_parallel=False)
+    x, pools = _paged_prefill_hidden(cfg, denv, params, pools, tokens, base,
+                                     n_valid, table_row,
+                                     page_size=page_size, flags=flags)
     last = jax.lax.dynamic_slice_in_dim(
         x, jnp.clip(n_valid - 1, 0, x.shape[0] - 1), 1, axis=0)
     logits = emb.lm_logits(cfg, denv, params["embed"], last)
@@ -836,6 +847,30 @@ def paged_prefill_chunk(cfg: ModelConfig, env: AxisEnv, params, pools,
         temperature=one(temp, jnp.float32), top_p=one(top_p, jnp.float32),
         top_k=one(top_k, jnp.int32), stream=emb.STREAM_SAMPLE)
     return nxt[0].astype(jnp.int32), pools
+
+
+def paged_prefill_logits(cfg: ModelConfig, env: AxisEnv, params, pools,
+                         tokens: jax.Array, base: jax.Array,
+                         n_valid: jax.Array, table_row: jax.Array, *,
+                         page_size: int, flags: RunFlags = DEFAULT_FLAGS):
+    """`paged_prefill_chunk` returning every chunk position's vocab-local
+    fp32 logits (C, V_loc) in place of the next token."""
+    denv = dataclasses.replace(env, seq_parallel=False)
+    x, pools = _paged_prefill_hidden(cfg, denv, params, pools, tokens, base,
+                                     n_valid, table_row,
+                                     page_size=page_size, flags=flags)
+    return emb.lm_logits(cfg, denv, params["embed"], x), pools
+
+
+def paged_decode_logits(cfg: ModelConfig, env: AxisEnv, params, pools,
+                        token: jax.Array, pos: jax.Array, table: jax.Array,
+                        active: jax.Array, *, page_size: int,
+                        flags: RunFlags = DEFAULT_FLAGS):
+    """`paged_decode_step` returning the vocab-local fp32 logits
+    (B, V_loc) in place of the next tokens."""
+    denv = dataclasses.replace(env, seq_parallel=False)
+    return _paged_decode_logits(cfg, denv, params, pools, token, pos, table,
+                                active, page_size=page_size, flags=flags)
 
 
 def decode_step(cfg: ModelConfig, env: AxisEnv, params, caches,

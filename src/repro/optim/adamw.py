@@ -30,10 +30,18 @@ class AdamWConfig:
 
 
 def init_opt_state(params) -> Dict[str, Any]:
+    """Zero moments shaped and placed like `params`.  The step count is
+    placed replicated on the params' mesh, where the train step's output
+    puts it: a count on another placement has another type under jit, so
+    the step's first call would trace once more than the rest."""
     zeros = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
+    count = jnp.zeros((), jnp.int32)
+    sh = getattr(jax.tree.leaves(params)[0], "sharding", None)
+    if isinstance(sh, jax.sharding.NamedSharding):
+        count = jax.device_put(count, jax.sharding.NamedSharding(sh.mesh, P()))
     return {"m": zeros,
             "v": jax.tree.map(jnp.zeros_like, zeros),
-            "count": jnp.zeros((), jnp.int32)}
+            "count": count}
 
 
 def opt_state_specs(param_specs) -> Dict[str, Any]:

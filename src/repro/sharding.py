@@ -39,15 +39,10 @@ from jax.sharding import PartitionSpec as P
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map with replication checking off: newer jax
-    exposes jax.shard_map(check_vma=...), older jax has
-    jax.experimental.shard_map.shard_map(check_rep=...)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """`jax.shard_map` with replication checking off (the model code
+    combines partial sums with explicit collectives)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)

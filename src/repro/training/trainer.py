@@ -122,7 +122,11 @@ class Trainer:
                 spike_guard=cfg.spike, donate=cfg.donate)
         self.params = runner.init_params(cfg.seed)
         self.opt_state = adamw.init_opt_state(self.params)
-        self.guard_state = spikes_lib.init_guard_state(cfg.spike)
+        # on the mesh, where the step's outputs put it: the first step
+        # then traces with the same input types as every later one
+        guard = spikes_lib.init_guard_state(cfg.spike)
+        self.guard_state = self._reshard(guard,
+                                         sharding.replicated_specs(guard))
         self.rng = jax.random.PRNGKey(cfg.seed)
         self.step = 0                  # next step index to execute
         self.history: List[Dict[str, float]] = []

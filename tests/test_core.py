@@ -172,6 +172,77 @@ def test_moe_fused_grads_match_ragged(moe_cfg):
                                    rtol=1e-4, atol=1e-4)
 
 
+_RAGGED_DOT = jax.lax.ragged_dot
+
+
+def _dead_rows(lhs, group_sizes):
+    return (jnp.arange(lhs.shape[0]) >= jnp.sum(group_sizes))[:, None]
+
+
+@jax.custom_vjp
+def _ragged_dot_unwritten(lhs, rhs, group_sizes):
+    """ragged_dot as it behaves on TPU: rows past sum(group_sizes) of the
+    output and of the lhs cotangent are left unwritten (here: 1e3)."""
+    return jnp.where(_dead_rows(lhs, group_sizes), 1e3,
+                     _RAGGED_DOT(lhs, rhs, group_sizes)).astype(lhs.dtype)
+
+
+def _ragged_dot_unwritten_fwd(lhs, rhs, group_sizes):
+    return (_ragged_dot_unwritten(lhs, rhs, group_sizes),
+            (lhs, rhs, group_sizes))
+
+
+def _ragged_dot_unwritten_bwd(res, g):
+    lhs, rhs, group_sizes = res
+    _, pull = jax.vjp(lambda a, b: _RAGGED_DOT(a, b, group_sizes), lhs, rhs)
+    dl, dr = pull(g)
+    return (jnp.where(_dead_rows(lhs, group_sizes), 1e3, dl).astype(dl.dtype),
+            dr, np.zeros(group_sizes.shape, jax.dtypes.float0))
+
+
+_ragged_dot_unwritten.defvjp(_ragged_dot_unwritten_fwd,
+                             _ragged_dot_unwritten_bwd)
+
+
+@pytest.mark.parametrize("fn", ["grouped_ffn", "fused_ragged_ref"])
+def test_ragged_ffn_ignores_rows_past_groups(moe_cfg, fn, monkeypatch):
+    """Capacity rows past sum(group_sizes) (EP padding, tp>1 non-local
+    slots) must give zero output and no gradient, even where ragged_dot
+    leaves those rows unwritten as on TPU.  Unmasked, their garbage
+    reached the input gradient through the gather of clamped token ids."""
+    cfg = dataclasses.replace(moe_cfg, compute_dtype="float32")
+    rs = np.random.RandomState(9)
+    E, d, ff, T, cap = 4, 32, 16, 12, 24
+    x = jnp.asarray(rs.randn(T, d), jnp.float32)
+    w1, w3 = (jnp.asarray(rs.randn(E, d, ff) * 0.2, jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(rs.randn(E, ff, d) * 0.2, jnp.float32)
+    sizes = jnp.asarray([5, 0, 7, 3], jnp.int32)          # 15 of 24 rows
+    tok = jnp.asarray(rs.randint(0, T, cap), jnp.int32)
+    gate = jnp.asarray(rs.rand(cap), jnp.float32)
+
+    def loss(x, w1, w2, w3):
+        if fn == "grouped_ffn":
+            y = moe_lib.grouped_ffn(cfg, w1, w2, w3, jnp.take(x, tok, axis=0),
+                                    sizes)
+        else:
+            y = moe_lib._fused_ragged_ref(cfg.mlp_act, x, w1, w2, w3, tok,
+                                          gate, sizes)
+        return jnp.sum(y * y), y
+
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3), has_aux=True)
+    want, y_want = grad(x, w1, w2, w3)
+    monkeypatch.setattr(jax.lax, "ragged_dot", _ragged_dot_unwritten)
+    got, y_got = grad(x, w1, w2, w3)
+    if fn == "grouped_ffn":
+        np.testing.assert_array_equal(np.asarray(y_got[15:]), 0.0)
+    np.testing.assert_allclose(np.asarray(y_got), np.asarray(y_want),
+                               rtol=1e-5, atol=1e-5)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_normhead_scale_invariance():
     w = jnp.asarray(np.random.RandomState(5).randn(16, 8), jnp.float32)
     wn = normalize_rows(w)

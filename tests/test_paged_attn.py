@@ -35,11 +35,11 @@ def _gathered_ref(q, k_pool, v_pool, table, mask, cdt):
     math exactly: grouped einsum scores, softmax vs the global row max,
     p rounded to the compute dtype for the PV contraction."""
     B, Qn, Hp, hd = q.shape
-    n_pages, ps_loc, KV, _ = k_pool.shape
-    S_g = table.shape[1] * ps_loc
+    KV = k_pool.shape[1]
     g = Hp // KV
-    k_g = kops.paged_gather(k_pool, table).reshape(B, S_g, KV, hd)
-    v_g = kops.paged_gather(v_pool, table).reshape(B, S_g, KV, hd)
+    k_g = kops.paged_gather(k_pool, table)
+    v_g = kops.paged_gather(v_pool, table)
+    S_g = k_g.shape[1]
     q_g = q.reshape(B, Qn, KV, g, hd)
     s = jnp.einsum("bqkgd,bskd->bqkgs", q_g, k_g,
                    preferred_element_type=jnp.float32) * hd ** -0.5
@@ -64,8 +64,8 @@ def _make_case(rng, *, B, Qn, n_lp, ps_loc, Hp, KV, hd, page_counts,
     filled with large garbage so any masking hole shows up loudly."""
     n_pages = n_pages or (1 + sum(page_counts))
     q = jnp.asarray(rng.normal(size=(B, Qn, Hp, hd)), dtype)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps_loc, KV, hd)), dtype)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps_loc, KV, hd)), dtype)
+    kp = jnp.asarray(rng.normal(size=(n_pages, KV, ps_loc, hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(n_pages, KV, ps_loc, hd)), dtype)
     kp = kp.at[0].set(100.0)
     vp = vp.at[0].set(-100.0)
     table = np.zeros((B, n_lp), np.int32)
@@ -230,6 +230,49 @@ def test_engine_fused_vs_gathered_spec_decode(cfg_params):
     for e in (ef, eg):
         assert e.prefill_traces == 1 and e.draft_traces == 1
         assert e.verify_traces == 1
+
+
+def _path_logits(cfg, params, flags, tokens, table, page):
+    """Prefill logits (C, V) + one decode step's logits (1, V) through
+    `Runner.make_paged_logits` on one serving path, and the token the
+    path's own prefill step emits for the chunk."""
+    C = tokens.shape[0]
+    runner = api.Runner(cfg, make_local_mesh(1, 1), fsdp=False,
+                        seq_parallel=False, max_seq=64, flags=flags)
+    pre_fn, dec_fn = runner.make_paged_logits(page)
+    prefill_logits, pools = jax.jit(pre_fn)(
+        params, runner.init_paged_pools(4, page), tokens, jnp.int32(0),
+        jnp.int32(C), table[0])
+    nxt, _ = jax.jit(runner.make_paged_prefill(page))(
+        params, runner.init_paged_pools(4, page), tokens, jnp.int32(0),
+        jnp.int32(C), table[0])
+    decode_logits, _ = jax.jit(dec_fn)(
+        params, pools, nxt[None], jnp.asarray([C], jnp.int32), table,
+        jnp.asarray([True]))
+    logits = jnp.concatenate([prefill_logits, decode_logits])
+    return np.asarray(logits[:, :cfg.vocab_size]), int(nxt)
+
+
+def test_paged_logits_kernel_vs_reference_paths(cfg_params):
+    """`Runner.make_paged_logits` — the comparison the chip smoke runs
+    at full width: a prefill chunk and a decode step through the kernel
+    path (fused MoE FFN + fused paged attention) and the reference path
+    (ragged MoE + gathered) agree to bf16 rounding, and each logits twin
+    picks the token its serving step emits."""
+    cfg, params = cfg_params
+    C, page = 8, 16
+    rs = np.random.RandomState(3)
+    tokens = jnp.asarray(rs.randint(0, cfg.vocab_size, C), jnp.int32)
+    table = jnp.asarray([[1, 0, 0, 0]], jnp.int32)
+    a, tok_a = _path_logits(cfg, params, M.RunFlags(
+        moe_dispatch="fused", paged_attn="fused"), tokens, table, page)
+    b, tok_b = _path_logits(cfg, params, M.RunFlags(
+        moe_dispatch="ragged", paged_attn="gathered"), tokens, table, page)
+    assert tok_a == int(np.argmax(a[C - 1]))
+    assert tok_b == int(np.argmax(b[C - 1]))
+    assert np.all(np.isfinite(a)) and a.shape == (C + 1, cfg.vocab_size)
+    # the same 2e-2 relative-L2 bound the chip smoke applies
+    assert np.linalg.norm(a - b) / np.linalg.norm(b) < 2e-2
 
 
 # ---------------------------------------------------------------------------
