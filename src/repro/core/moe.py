@@ -244,64 +244,68 @@ def _ep_moe_ffn(cfg, env: AxisEnv, params, x, w1, w2, w3, *,
         train=train, ep=True)
 
     # --- 2. bucket slots by destination shard -----------------------------
-    S = T_loc * m.top_k
-    cap = ep_capacity(cfg, env, T_loc)
-    flat_i = top_i.reshape(-1)                     # (S,) global expert ids
-    flat_w = top_w.reshape(-1)
-    dest = flat_i // e_loc                         # owning shard per slot
-    lkey = flat_i - dest * e_loc                   # local expert there
-    order = jnp.argsort(dest)                      # stable: token order
-    sorted_dest = jnp.take(dest, order)
-    # per-destination counts/offsets via binary search over the sorted
-    # keys (O(tp log S), no (S, tp) one-hot intermediate — same pattern
-    # as kernels/ops._align_groups)
-    ids = jnp.arange(tp)
-    offsets = jnp.searchsorted(sorted_dest, ids, side="left")
-    counts = (jnp.searchsorted(sorted_dest, ids, side="right")
-              - offsets).astype(jnp.int32)
-    pos = offsets[:, None] + jnp.arange(cap)[None, :]         # (tp, cap)
-    pos_valid = (jnp.arange(cap)[None, :]
-                 < jnp.minimum(counts, cap)[:, None])
-    slot = jnp.take(order, jnp.clip(pos, 0, S - 1))           # (tp, cap)
-    tok_send = slot // m.top_k                     # owned-token per slot
-    x_send = jnp.take(x_loc, tok_send.reshape(-1), axis=0
-                      ).reshape(tp, cap, d).astype(cdt)
-    key_send = jnp.where(pos_valid, jnp.take(lkey, slot),
-                         e_loc).astype(jnp.int32)
+    with jax.named_scope("dispatch"):
+        S = T_loc * m.top_k
+        cap = ep_capacity(cfg, env, T_loc)
+        flat_i = top_i.reshape(-1)             # (S,) global expert ids
+        flat_w = top_w.reshape(-1)
+        dest = flat_i // e_loc                 # owning shard per slot
+        lkey = flat_i - dest * e_loc                   # local expert there
+        order = jnp.argsort(dest)                      # stable: token order
+        sorted_dest = jnp.take(dest, order)
+        # per-destination counts/offsets via binary search over the sorted
+        # keys (O(tp log S), no (S, tp) one-hot intermediate — same pattern
+        # as kernels/ops._align_groups)
+        ids = jnp.arange(tp)
+        offsets = jnp.searchsorted(sorted_dest, ids, side="left")
+        counts = (jnp.searchsorted(sorted_dest, ids, side="right")
+                  - offsets).astype(jnp.int32)
+        pos = offsets[:, None] + jnp.arange(cap)[None, :]         # (tp, cap)
+        pos_valid = (jnp.arange(cap)[None, :]
+                     < jnp.minimum(counts, cap)[:, None])
+        slot = jnp.take(order, jnp.clip(pos, 0, S - 1))           # (tp, cap)
+        tok_send = slot // m.top_k                     # owned-token per slot
+        x_send = jnp.take(x_loc, tok_send.reshape(-1), axis=0
+                          ).reshape(tp, cap, d).astype(cdt)
+        key_send = jnp.where(pos_valid, jnp.take(lkey, slot),
+                             e_loc).astype(jnp.int32)
 
-    # --- 3. token + count exchange (bf16 payload, int32 keys) -------------
-    x_recv = kops.ep_all_to_all(x_send, axis_name=env.tp_axis)
-    key_recv = env.all_to_all_tp(key_send)         # int: no grad needed
+        # --- 3. token + count exchange (bf16 payload, int32 keys) ---------
+        x_recv = kops.ep_all_to_all(x_send, axis_name=env.tp_axis)
+        key_recv = env.all_to_all_tp(key_send)         # int: no grad needed
 
     # --- 4. local expert FFN over received rows ---------------------------
-    R = tp * cap
-    keys = key_recv.reshape(-1)                    # (R,) in [0, e_loc]
-    order2 = jnp.argsort(keys)                     # stable; invalid last
-    skey = jnp.take(keys, order2)
-    valid2 = skey < e_loc
-    eids = jnp.arange(e_loc)
-    group_sizes = (jnp.searchsorted(skey, eids, side="right")
-                   - jnp.searchsorted(skey, eids, side="left")
-                   ).astype(jnp.int32)
-    xr = x_recv.reshape(R, d)
-    ones = valid2.astype(cdt)                      # gate=1: gates stay home
-    if kops.interpret_default():
-        y_r = fused_ffn(cfg.mlp_act, xr, w1, w2, w3, order2, ones,
-                        group_sizes)
-    else:
-        xs = jnp.take(xr, order2, axis=0)
-        out = grouped_ffn(cfg, w1, w2, w3, xs, group_sizes)
-        y_r = jnp.zeros((R, d), jnp.float32).at[order2].add(
-            out.astype(jnp.float32) * ones.astype(jnp.float32)[:, None])
+    with jax.named_scope("experts"):
+        R = tp * cap
+        keys = key_recv.reshape(-1)                    # (R,) in [0, e_loc]
+        order2 = jnp.argsort(keys)                     # stable; invalid last
+        skey = jnp.take(keys, order2)
+        valid2 = skey < e_loc
+        eids = jnp.arange(e_loc)
+        group_sizes = (jnp.searchsorted(skey, eids, side="right")
+                       - jnp.searchsorted(skey, eids, side="left")
+                       ).astype(jnp.int32)
+        xr = x_recv.reshape(R, d)
+        ones = valid2.astype(cdt)              # gate=1: gates stay home
+        if kops.interpret_default():
+            y_r = fused_ffn(cfg.mlp_act, xr, w1, w2, w3, order2, ones,
+                            group_sizes)
+        else:
+            xs = jnp.take(xr, order2, axis=0)
+            out = grouped_ffn(cfg, w1, w2, w3, xs, group_sizes)
+            y_r = jnp.zeros((R, d), jnp.float32).at[order2].add(
+                out.astype(jnp.float32) * ones.astype(jnp.float32)[:, None])
 
     # --- 5. combine exchange + gated scatter into the owned slice ---------
-    y_back = kops.ep_all_to_all(y_r.astype(cdt).reshape(tp, cap, d),
-                                axis_name=env.tp_axis)
-    gates = jnp.where(pos_valid, jnp.take(flat_w, slot), 0.0).astype(cdt)
-    y_loc = jnp.zeros((T_loc, d), cdt).at[tok_send.reshape(-1)].add(
-        y_back.reshape(R, d) * gates.reshape(R)[:, None])
-    y = jax.lax.dynamic_update_slice_in_dim(
-        jnp.zeros((T, d), cdt), y_loc, r * T_loc, axis=0)
+    with jax.named_scope("combine"):
+        y_back = kops.ep_all_to_all(y_r.astype(cdt).reshape(tp, cap, d),
+                                    axis_name=env.tp_axis)
+        gates = jnp.where(pos_valid, jnp.take(flat_w, slot), 0.0).astype(cdt)
+        y_loc = jnp.zeros((T_loc, d), cdt).at[tok_send.reshape(-1)].add(
+            y_back.reshape(R, d) * gates.reshape(R)[:, None])
+        y = jax.lax.dynamic_update_slice_in_dim(
+            jnp.zeros((T, d), cdt), y_loc, r * T_loc, axis=0)
+
     n_kept = jnp.sum(pos_valid)
     return y, aux, metrics, n_kept, jnp.int32(S)
 
@@ -315,6 +319,7 @@ def expert_capacity(cfg, env: AxisEnv, n_tokens: int) -> int:
     return min(pad_to_multiple(max(c, 8), 8), n_tokens * m.top_k)
 
 
+@jax.named_scope("moe_ffn")
 def moe_ffn(cfg, env: AxisEnv, params, x: jax.Array, *,
             step: Optional[jax.Array] = None,
             rng: Optional[jax.Array] = None,
@@ -382,10 +387,13 @@ def moe_ffn(cfg, env: AxisEnv, params, x: jax.Array, *,
                 f"dispatch='ep' needs T ({T}) divisible by tp ({env.tp})")
         dispatch = "batched"
 
-    w1 = env.gather_fsdp(params["we1"], 1, dtype=cdt)
-    w2 = env.gather_fsdp(params["we2"], 2, dtype=cdt)
-    w3 = (env.gather_fsdp(params["we3"], 1, dtype=cdt)
-          if "we3" in params else None)
+    # the f32 -> bf16 weight casts count with the expert GEMMs, wherever
+    # XLA places them
+    with jax.named_scope("experts"):
+        w1 = env.gather_fsdp(params["we1"], 1, dtype=cdt)
+        w2 = env.gather_fsdp(params["we2"], 2, dtype=cdt)
+        w3 = (env.gather_fsdp(params["we3"], 1, dtype=cdt)
+              if "we3" in params else None)
 
     if dispatch == "ep":
         y, aux, metrics, n_kept, n_local = _ep_moe_ffn(
@@ -398,32 +406,40 @@ def moe_ffn(cfg, env: AxisEnv, params, x: jax.Array, *,
         cfg, env, params["router"], x, step=step, rng=rng, train=train)
 
     # ---- local dispatch: sort token-slots by (local) expert --------------
-    r = env.tp_index()
-    lo = r * e_loc
-    flat_i = top_i.reshape(-1)                     # (T*k,)
-    flat_w = top_w.reshape(-1)
-    local_key = flat_i - lo
-    is_local = (local_key >= 0) & (local_key < e_loc)
-    sort_key = jnp.where(is_local, local_key, e_loc)   # non-local last
-    order = jnp.argsort(sort_key)                  # stable
+    with jax.named_scope("dispatch"):
+        r = env.tp_index()
+        lo = r * e_loc
+        flat_i = top_i.reshape(-1)                 # (T*k,)
+        flat_w = top_w.reshape(-1)
+        local_key = flat_i - lo
+        is_local = (local_key >= 0) & (local_key < e_loc)
+        sort_key = jnp.where(is_local, local_key, e_loc)  # non-local last
+        order = jnp.argsort(sort_key)              # stable
 
     if dispatch in ("ragged", "fused"):
-        sel = order[:cap]                          # (cap,) slot indices
-        tok = sel // m.top_k                       # token per slot
-        skey = sort_key[sel]                       # sorted expert keys
-        valid = skey < e_loc
-        # rows per local expert (only rows that made it into the buffer)
-        group_sizes = jnp.sum(
-            jax.nn.one_hot(jnp.where(valid, skey, e_loc), e_loc + 1,
-                           dtype=jnp.int32)[:, :e_loc], axis=0)
-        gates = (flat_w[sel] * valid).astype(cdt)
+        with jax.named_scope("dispatch"):
+            sel = order[:cap]                      # (cap,) slot indices
+            tok = sel // m.top_k                   # token per slot
+            skey = sort_key[sel]                   # sorted expert keys
+            valid = skey < e_loc
+            # rows per local expert (only rows that made it into the
+            # buffer)
+            group_sizes = jnp.sum(
+                jax.nn.one_hot(jnp.where(valid, skey, e_loc), e_loc + 1,
+                               dtype=jnp.int32)[:, :e_loc], axis=0)
+            gates = (flat_w[sel] * valid).astype(cdt)
         if dispatch == "fused":
-            y = fused_ffn(cfg.mlp_act, x.astype(cdt), w1, w2, w3, tok,
-                          gates, group_sizes).astype(cdt)
+            with jax.named_scope("experts"):
+                y = fused_ffn(cfg.mlp_act, x.astype(cdt), w1, w2, w3, tok,
+                              gates, group_sizes).astype(cdt)
         else:
-            xs = jnp.take(x, tok, axis=0).astype(cdt)  # (cap, d) gather
-            out = grouped_ffn(cfg, w1, w2, w3, xs, group_sizes)  # (cap, d)
-            y = jnp.zeros((T, d), cdt).at[tok].add(out * gates[:, None])
+            with jax.named_scope("dispatch"):
+                xs = jnp.take(x, tok, axis=0).astype(cdt)  # (cap, d)
+            with jax.named_scope("experts"):
+                out = grouped_ffn(cfg, w1, w2, w3, xs, group_sizes)
+            with jax.named_scope("combine"):
+                y = jnp.zeros((T, d), cdt).at[tok].add(
+                    out * gates[:, None])
         n_kept = jnp.sum(valid)
     else:
         # per-expert-capacity batched dispatch: expert e's rows live at
@@ -432,26 +448,32 @@ def moe_ffn(cfg, env: AxisEnv, params, x: jax.Array, *,
         # batched einsum — equal MXU tiles per expert, no E_loc x dense
         # waste, and the combine stays a scatter-add.
         c_e = expert_capacity(cfg, env, T)
-        counts = jnp.sum(
-            jax.nn.one_hot(jnp.where(is_local, local_key, e_loc), e_loc + 1,
-                           dtype=jnp.int32)[:, :e_loc], axis=0)   # (E_loc,)
-        offsets = jnp.cumsum(counts) - counts
-        slot_idx = offsets[:, None] + jnp.arange(c_e)[None, :]    # (E,C)
-        slot_valid = jnp.arange(c_e)[None, :] < jnp.minimum(counts, c_e)[:, None]
-        slot = jnp.take(order, jnp.clip(slot_idx, 0, order.shape[0] - 1))
-        tok_e = slot // m.top_k                                   # (E,C)
-        xs = jnp.take(x, tok_e.reshape(-1), axis=0).astype(cdt)
-        xs = xs.reshape(e_loc, c_e, d)
-        h = jnp.einsum("ecd,edf->ecf", xs, w1)
-        if cfg.mlp_act in L.GATED_ACTS:
-            h = L._act(cfg.mlp_act, h) * jnp.einsum("ecd,edf->ecf", xs, w3)
-        else:
-            h = L._act(cfg.mlp_act, h)
-        out = jnp.einsum("ecf,efd->ecd", h, w2)                   # (E,C,d)
-        gates = (jnp.take(flat_w, slot.reshape(-1)).reshape(e_loc, c_e)
-                 * slot_valid).astype(cdt)
-        y = jnp.zeros((T, d), cdt).at[tok_e.reshape(-1)].add(
-            (out * gates[..., None]).reshape(-1, d))
+        with jax.named_scope("dispatch"):
+            counts = jnp.sum(
+                jax.nn.one_hot(jnp.where(is_local, local_key, e_loc),
+                               e_loc + 1, dtype=jnp.int32)[:, :e_loc],
+                axis=0)                                       # (E_loc,)
+            offsets = jnp.cumsum(counts) - counts
+            slot_idx = offsets[:, None] + jnp.arange(c_e)[None, :]  # (E,C)
+            slot_valid = (jnp.arange(c_e)[None, :]
+                          < jnp.minimum(counts, c_e)[:, None])
+            slot = jnp.take(order, jnp.clip(slot_idx, 0, order.shape[0] - 1))
+            tok_e = slot // m.top_k                               # (E,C)
+            xs = jnp.take(x, tok_e.reshape(-1), axis=0).astype(cdt)
+            xs = xs.reshape(e_loc, c_e, d)
+        with jax.named_scope("experts"):
+            h = jnp.einsum("ecd,edf->ecf", xs, w1)
+            if cfg.mlp_act in L.GATED_ACTS:
+                h = (L._act(cfg.mlp_act, h)
+                     * jnp.einsum("ecd,edf->ecf", xs, w3))
+            else:
+                h = L._act(cfg.mlp_act, h)
+            out = jnp.einsum("ecf,efd->ecd", h, w2)               # (E,C,d)
+        with jax.named_scope("combine"):
+            gates = (jnp.take(flat_w, slot.reshape(-1)).reshape(e_loc, c_e)
+                     * slot_valid).astype(cdt)
+            y = jnp.zeros((T, d), cdt).at[tok_e.reshape(-1)].add(
+                (out * gates[..., None]).reshape(-1, d))
         n_kept = jnp.sum(jnp.minimum(counts, c_e))
 
     n_local = jnp.sum(is_local)
@@ -473,6 +495,7 @@ def _moe_tail(cfg, env: AxisEnv, params, x, y, aux, metrics, n_kept,
 
     # ---- shared expert (Eq. 2): dense TP FFN fused into the partial ------
     if m.n_shared_experts > 0:
-        y = y + L.apply_mlp(cfg, env, params["shared"], x.astype(cdt))
+        with jax.named_scope("shared_experts"):
+            y = y + L.apply_mlp(cfg, env, params["shared"], x.astype(cdt))
 
     return y, aux, metrics

@@ -53,6 +53,7 @@ def stochastic_warmup_logits(logits: jax.Array, step: jax.Array,
     return alpha * logits + (1.0 - alpha) * (mu + sigma * eps)
 
 
+@jax.named_scope("router")
 def route(cfg, env: AxisEnv, params, x: jax.Array, *,
           step: Optional[jax.Array] = None,
           rng: Optional[jax.Array] = None,

@@ -33,6 +33,7 @@ def init_embedding(key, cfg, env: AxisEnv):
     return params, specs
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg, env: AxisEnv, params, ids: jax.Array) -> jax.Array:
     """ids (T,) replicated over tp -> SP activations (T_sp, d) via
     masked local lookup + reduce-scatter over tp."""
@@ -48,6 +49,7 @@ def embed_tokens(cfg, env: AxisEnv, params, ids: jax.Array) -> jax.Array:
     return env.sp_scatter(partial)
 
 
+@jax.named_scope("lm_head")
 def lm_logits(cfg, env: AxisEnv, params, x: jax.Array) -> jax.Array:
     """x (T, d) -> vocab-local logits (T, V_loc) fp32 (NormHead per cfg)."""
     w = params["table"] if cfg.tie_embeddings else params["lm_head"]
@@ -82,6 +84,7 @@ def sharded_xent(cfg, env: AxisEnv, logits_loc: jax.Array, labels: jax.Array
     return total / jnp.maximum(n_total, 1.0), n_valid
 
 
+@jax.named_scope("lm_head")
 def sharded_argmax(env: AxisEnv, logits_loc: jax.Array) -> jax.Array:
     """Greedy sampling over tp-sharded vocab.  logits (T, V_loc) -> (T,)."""
     v_loc = logits_loc.shape[-1]
@@ -163,6 +166,7 @@ def transform_logits(full_logits: jax.Array, temperature: jax.Array,
                                1e-30)
 
 
+@jax.named_scope("lm_head")
 def sampled_probs(cfg, env: AxisEnv, logits_loc: jax.Array,
                   temperature: jax.Array, top_p: jax.Array,
                   top_k: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -185,6 +189,7 @@ def sampled_probs(cfg, env: AxisEnv, logits_loc: jax.Array,
     return greedy, probs
 
 
+@jax.named_scope("lm_head")
 def sharded_sample(cfg, env: AxisEnv, logits_loc: jax.Array, *,
                    seeds: jax.Array, pos: jax.Array, temperature: jax.Array,
                    top_p: jax.Array, top_k: jax.Array,

@@ -397,6 +397,7 @@ def attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return jnp.swapaxes(out_T, 1, 2)                      # (B,Sq,H,hd)
 
 
+@jax.named_scope("attention")
 def apply_attention(cfg, env: AxisEnv, params: Params, x: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     kv_source: Optional[jax.Array] = None,
@@ -462,6 +463,7 @@ def init_decode_cache(cfg, env: AxisEnv, batch_local: int, seq_len: int,
     return {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
 
 
+@jax.named_scope("attention")
 def decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
                      cache: Dict[str, jax.Array], pos: jax.Array, *,
                      window: Optional[int] = None,
@@ -570,6 +572,7 @@ def init_paged_kv_pool(cfg, n_pages: int, page_size: int
     return {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
 
 
+@jax.named_scope("attention")
 def paged_valid_mask(table, pos, *, page_size: int, ps_loc: int,
                      env: AxisEnv):
     """Once-per-tick paged-attention validity mask (B, Q, S_g).
@@ -720,6 +723,7 @@ def _paged_attention_core(cfg, env: AxisEnv, ad: AttnDims, q_all, pool,
     return _paged_scores_combine(cfg, env, ad, q_all, k_g, v_g, valid, cdt)
 
 
+@jax.named_scope("attention")
 def paged_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
                            pool: Dict[str, jax.Array], pos: jax.Array,
                            table: jax.Array, active: jax.Array, *,
@@ -777,6 +781,7 @@ def paged_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     return partial, pool
 
 
+@jax.named_scope("attention")
 def paged_prefill_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
                             pool: Dict[str, jax.Array], base: jax.Array,
                             n_valid: jax.Array, table_row: jax.Array, *,
@@ -838,6 +843,7 @@ def paged_prefill_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     return partial, pool
 
 
+@jax.named_scope("attention")
 def paged_verify_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
                            pool: Dict[str, jax.Array], pos: jax.Array,
                            table: jax.Array, active: jax.Array, *,

@@ -318,6 +318,28 @@ def param_specs(cfg: ModelConfig, env: AxisEnv, max_seq: int):
 # ---- forward (train / prefill) ---------------------------------------------
 
 
+def _final_norm(cfg, env: AxisEnv, params, x):
+    """The last norm before the LM head, timed with it (scope `lm_head`)."""
+    with jax.named_scope("lm_head"):
+        return L.apply_norm(cfg, env, params["final_norm"], x)
+
+
+def _serve_blocks(cfg: ModelConfig, params):
+    """The stacked layer weights a serve step's layer loop reads, with the
+    routed experts' weights cast to the compute dtype before the loop.
+    XLA hoists that cast out of the loop anyway, and the op it hoists
+    carries no `op_name`; cast here, under `moe_ffn/experts`, it keeps
+    its scope in the profiler trace."""
+    blocks = params["blocks"]
+    if "moe" not in blocks:
+        return blocks
+    cdt = jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("moe_ffn"), jax.named_scope("experts"):
+        moe = {k: (v.astype(cdt) if k in ("we1", "we2", "we3") else v)
+               for k, v in blocks["moe"].items()}
+    return {**blocks, "moe": moe}
+
+
 def _run_blocks(cfg, env, params, x_sp, *, B, S, step, rng, train, flags,
                 want_cache=False, enc_out=None):
     aux0 = jnp.zeros((), jnp.float32)
@@ -410,7 +432,7 @@ def forward(cfg: ModelConfig, env: AxisEnv, params, batch, *,
     x_sp, aux, metrics, caches = _run_blocks(
         cfg, env, params, x_sp, B=B, S=S, step=step, rng=rng, train=train,
         flags=flags, want_cache=want_cache, enc_out=enc_out)
-    x_sp = L.apply_norm(cfg, env, params["final_norm"], x_sp)
+    x_sp = _final_norm(cfg, env, params, x_sp)
     x = env.sp_gather(x_sp)                    # (T, d)
     return x, aux, metrics, caches
 
@@ -556,7 +578,7 @@ def _paged_decode_logits(cfg: ModelConfig, denv: AxisEnv, params, pools,
                                          flags=flags, valid=valid)
             return x, pool
 
-        x, pools = jax.lax.scan(body, x, (params["blocks"], pools))
+        x, pools = jax.lax.scan(body, x, (_serve_blocks(cfg, params), pools))
     else:
         new_pools = []
         for i, lp in enumerate(params["blocks"]):
@@ -566,7 +588,7 @@ def _paged_decode_logits(cfg: ModelConfig, denv: AxisEnv, params, pools,
                                       valid=valid)
             new_pools.append(p)
         pools = new_pools
-    x = L.apply_norm(cfg, denv, params["final_norm"], x)
+    x = _final_norm(cfg, denv, params, x)
     return emb.lm_logits(cfg, denv, params["embed"], x), pools
 
 
@@ -704,7 +726,7 @@ def paged_verify_step(cfg: ModelConfig, env: AxisEnv, params, pools,
                                          flags=flags, valid=valid)
             return x, pool
 
-        x, pools = jax.lax.scan(body, x, (params["blocks"], pools))
+        x, pools = jax.lax.scan(body, x, (_serve_blocks(cfg, params), pools))
     else:
         new_pools = []
         for i, lp in enumerate(params["blocks"]):
@@ -715,7 +737,7 @@ def paged_verify_step(cfg: ModelConfig, env: AxisEnv, params, pools,
                                       valid=valid)
             new_pools.append(p)
         pools = new_pools
-    x = L.apply_norm(cfg, denv, params["final_norm"], x)
+    x = _final_norm(cfg, denv, params, x)
     logits = emb.lm_logits(cfg, denv, params["embed"], x)  # (B*K1, v_loc)
 
     rep = lambda a: jnp.repeat(a, K1, axis=0)
@@ -802,7 +824,7 @@ def _paged_prefill_hidden(cfg: ModelConfig, denv: AxisEnv, params, pools,
                                           flags=flags, valid=valid)
             return x, pool
 
-        x, pools = jax.lax.scan(body, x, (params["blocks"], pools))
+        x, pools = jax.lax.scan(body, x, (_serve_blocks(cfg, params), pools))
     else:
         new_pools = []
         for i, lp in enumerate(params["blocks"]):
@@ -813,7 +835,7 @@ def _paged_prefill_hidden(cfg: ModelConfig, denv: AxisEnv, params, pools,
                                        valid=valid)
             new_pools.append(p)
         pools = new_pools
-    return L.apply_norm(cfg, denv, params["final_norm"], x), pools
+    return _final_norm(cfg, denv, params, x), pools
 
 
 def paged_prefill_chunk(cfg: ModelConfig, env: AxisEnv, params, pools,
@@ -900,7 +922,8 @@ def decode_step(cfg: ModelConfig, env: AxisEnv, params, caches,
                                     kind=kind, ffn=ffn, flags=flags)
             return x, cache
 
-        x, caches = jax.lax.scan(body, x, (params["blocks"], caches))
+        x, caches = jax.lax.scan(body, x,
+                                 (_serve_blocks(cfg, params), caches))
     else:
         new_caches = []
         for i, lp in enumerate(params["blocks"]):
@@ -909,7 +932,7 @@ def decode_step(cfg: ModelConfig, env: AxisEnv, params, caches,
                                 flags=flags)
             new_caches.append(c)
         caches = new_caches
-    x = L.apply_norm(cfg, denv, params["final_norm"], x)
+    x = _final_norm(cfg, denv, params, x)
     logits = emb.lm_logits(cfg, denv, params["embed"], x)
     if sample is None:
         return emb.sharded_argmax(denv, logits).astype(jnp.int32), caches

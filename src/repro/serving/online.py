@@ -85,8 +85,9 @@ occupancy gauges + per-tick counter-track series), a
 `telemetry.RequestLog` recording the full request lifecycle
 (enqueue -> admit -> prefill chunks -> first token -> decode ->
 preempt/requeue -> complete/shed, with tick indices and timestamps),
-and an `XPUTimer` spanning the scheduler phases of every tick — all of
-it host-side bookkeeping under the zero-host-sync contract, exportable
+and an `XPUTimer` spanning the scheduler phases of every tick (also
+`engine.*` annotations on the profiler's clock) — all of it host-side
+bookkeeping under the zero-host-sync contract, exportable
 to Perfetto via `telemetry.write_chrome_trace`.  `overload="slo"`
 closes the loop: a `telemetry.SLOTracker` over the windowed histograms
 vetoes admission when the configured TTFT/ITL p99 deadlines would be
@@ -391,7 +392,7 @@ class OnlineEngine:
         self.rlog = (request_log if request_log is not None
                      else RequestLog(cfg.trace_ring))
         self.timer = (timer if timer is not None
-                      else XPUTimer(registry=self.registry))
+                      else XPUTimer(registry=self.registry, prefix="engine."))
         if self.timer.registry is None:
             self.timer.registry = self.registry
         # SLOTracker first: it sizes the shared latency-histogram windows
@@ -711,36 +712,47 @@ class OnlineEngine:
         """Run one prefill chunk for the oldest prefilling slot; returns
         True when it made progress (False: nothing to prefill, or the
         grow deferred under decode-priority)."""
-        slot = self._prefill_target()
-        if slot is None:
-            return False
+        with self._span("prefill.prepare"):
+            slot = self._prefill_target()
+            if slot is None:
+                return False
+            rid = int(self.slot_rid[slot])
+            r = self.reqs[rid]
+            C = self.cfg.prefill_chunk
+            n_valid = min(C, len(r.fed) - r.prefill_pos)
+            # decode-priority: prefill never steals pages from in-flight
+            # decode slots — if eviction can't cover the grow, defer the
+            # chunk until decodes release naturally
+            if not self._make_room(rid, r.prefill_pos + n_valid,
+                                   allow_preempt=(self.policy
+                                                  != "decode-priority")):
+                return False
+            self.table[slot] = self.alloc.table_row(rid, self.cfg.max_pages)
+            chunk = np.zeros((C,), np.int32)
+            chunk[:n_valid] = r.fed[r.prefill_pos:r.prefill_pos + n_valid]
+            step_args = (jnp.asarray(chunk), jnp.int32(r.prefill_pos),
+                         jnp.int32(n_valid), jnp.asarray(self.table[slot]),
+                         jnp.int32(self.seeds[slot]),
+                         jnp.float32(self.temps[slot]),
+                         jnp.float32(self.topps[slot]),
+                         jnp.int32(self.topks[slot]))
+        with self._span("prefill.dispatch"):
+            if self.spec:
+                nxt, self.pools, self.dpools = self._prefill(
+                    self.params, self.dparams, self.pools, self.dpools,
+                    *step_args)
+            else:
+                nxt, self.pools = self._prefill(self.params, self.pools,
+                                                *step_args)
+        with self._span("prefill.commit"):
+            return self._commit_prefill(slot, n_valid, nxt)
+
+    def _commit_prefill(self, slot: int, n_valid: int, nxt) -> bool:
+        """Bookkeeping of the chunk just dispatched; when it completes the
+        prompt, the first token (its `device_get` is the phase
+        `prefill.sync`)."""
         rid = int(self.slot_rid[slot])
         r = self.reqs[rid]
-        C = self.cfg.prefill_chunk
-        n_valid = min(C, len(r.fed) - r.prefill_pos)
-        # decode-priority: prefill never steals pages from in-flight
-        # decode slots — if eviction can't cover the grow, defer the
-        # chunk until decodes release naturally
-        if not self._make_room(rid, r.prefill_pos + n_valid,
-                               allow_preempt=(self.policy
-                                              != "decode-priority")):
-            return False
-        self.table[slot] = self.alloc.table_row(rid, self.cfg.max_pages)
-        chunk = np.zeros((C,), np.int32)
-        chunk[:n_valid] = r.fed[r.prefill_pos:r.prefill_pos + n_valid]
-        step_args = (jnp.asarray(chunk), jnp.int32(r.prefill_pos),
-                     jnp.int32(n_valid), jnp.asarray(self.table[slot]),
-                     jnp.int32(self.seeds[slot]),
-                     jnp.float32(self.temps[slot]),
-                     jnp.float32(self.topps[slot]),
-                     jnp.int32(self.topks[slot]))
-        if self.spec:
-            nxt, self.pools, self.dpools = self._prefill(
-                self.params, self.dparams, self.pools, self.dpools,
-                *step_args)
-        else:
-            nxt, self.pools = self._prefill(self.params, self.pools,
-                                            *step_args)
         r.prefill_pos += n_valid
         self.rlog.record("prefill_chunk", rid, slot=slot, tick=self.ticks,
                          arg=n_valid)
@@ -768,7 +780,8 @@ class OnlineEngine:
             self.alloc.register_prefix(rid, r.prefix_key,
                                        min(r.prefix_len, len(r.prompt)))
         if not r.out:
-            tok = int(jax.device_get(nxt))
+            with self._span("prefill.sync"):
+                tok = int(jax.device_get(nxt))
             r.out.append(tok)
             r.first_token_t = t
             r.token_times.append(t)
@@ -783,27 +796,37 @@ class OnlineEngine:
         return True
 
     # -- decode ---------------------------------------------------------------
-    def _decode_tick(self, now: float):
-        # grow every decode slot to hold its next position, oldest first
-        # (the youngest is the preferred preemption victim, so growing in
-        # age order never evicts a slot we already grew this tick)
+    def _grow_slots(self, extra: int):
+        """Grow every decode slot to hold `extra` more positions, oldest
+        first (the youngest is the preferred preemption victim, so
+        growing in age order never evicts a slot already grown this
+        tick)."""
         for slot in sorted(np.flatnonzero(self.active),
                            key=lambda s: self.slot_seq[s]):
             slot = int(slot)
             if not self.active[slot]:
                 continue                # preempted by an earlier grow
             rid = int(self.slot_rid[slot])
-            self._make_room(rid, int(self.lens[slot]) + 1)
+            self._make_room(rid, int(self.lens[slot]) + extra)
             self.table[slot] = self.alloc.table_row(rid, self.cfg.max_pages)
-        if not self.active.any():
-            return
-        nxt, self.pools = self._decode(
-            self.params, self.pools, jnp.asarray(self.tok),
-            jnp.asarray(self.lens), jnp.asarray(self.table),
-            jnp.asarray(self.active), jnp.asarray(self.seeds),
-            jnp.asarray(self.temps), jnp.asarray(self.topps),
-            jnp.asarray(self.topks))
-        nxt = np.asarray(jax.device_get(nxt))
+
+    def _decode_tick(self, now: float):
+        with self._span("decode.prepare"):
+            self._grow_slots(1)
+            if not self.active.any():
+                return
+            args = (jnp.asarray(self.tok), jnp.asarray(self.lens),
+                    jnp.asarray(self.table), jnp.asarray(self.active),
+                    jnp.asarray(self.seeds), jnp.asarray(self.temps),
+                    jnp.asarray(self.topps), jnp.asarray(self.topks))
+        with self._span("decode.dispatch"):
+            nxt, self.pools = self._decode(self.params, self.pools, *args)
+        with self._span("decode.sync"):
+            nxt = np.asarray(jax.device_get(nxt))
+        with self._span("decode.commit"):
+            self._commit_decode(nxt)
+
+    def _commit_decode(self, nxt: np.ndarray):
         t = time.perf_counter()
         for slot in np.flatnonzero(self.active):
             slot = int(slot)
@@ -833,33 +856,35 @@ class OnlineEngine:
         pages straight back (LIFO: a regrow reacquires the identical
         pages, keeping page tables deterministic)."""
         K = self.cfg.spec_k
-        # grow every slot to hold its k+1 candidate rows, oldest first
-        for slot in sorted(np.flatnonzero(self.active),
-                           key=lambda s: self.slot_seq[s]):
-            slot = int(slot)
-            if not self.active[slot]:
-                continue                # preempted by an earlier grow
-            rid = int(self.slot_rid[slot])
-            self._make_room(rid, int(self.lens[slot]) + K + 1)
-            self.table[slot] = self.alloc.table_row(rid, self.cfg.max_pages)
-        if not self.active.any():
-            return
-        sample_args = (jnp.asarray(self.seeds), jnp.asarray(self.temps),
-                       jnp.asarray(self.topps), jnp.asarray(self.topks))
-        table = jnp.asarray(self.table)
-        active = jnp.asarray(self.active)
-        pos0 = jnp.asarray(self.lens)
-        drafts, dprobs, self.dpools = self._draft(
-            self.dparams, self.dpools, jnp.asarray(self.tok), pos0,
-            table, active, *sample_args)
-        tokens = jnp.concatenate(
-            [jnp.asarray(self.tok)[:, None], drafts.astype(jnp.int32)],
-            axis=1)                     # (B, k+1): pending token + drafts
-        n_acc, out, self.pools = self._verify(
-            self.params, self.pools, tokens, pos0, table, active, dprobs,
-            *sample_args)
-        n_acc = np.asarray(jax.device_get(n_acc))
-        out = np.asarray(jax.device_get(out))
+        # the decode.* phases: propose + verify is the dispatch
+        with self._span("decode.prepare"):
+            self._grow_slots(K + 1)     # each slot's k+1 candidate rows
+            if not self.active.any():
+                return
+            sample_args = (jnp.asarray(self.seeds), jnp.asarray(self.temps),
+                           jnp.asarray(self.topps), jnp.asarray(self.topks))
+            table = jnp.asarray(self.table)
+            active = jnp.asarray(self.active)
+            pos0 = jnp.asarray(self.lens)
+            tok = jnp.asarray(self.tok)
+        with self._span("decode.dispatch"):
+            drafts, dprobs, self.dpools = self._draft(
+                self.dparams, self.dpools, tok, pos0, table, active,
+                *sample_args)
+            tokens = jnp.concatenate(
+                [tok[:, None], drafts.astype(jnp.int32)],
+                axis=1)                 # (B, k+1): pending token + drafts
+            n_acc, out, self.pools = self._verify(
+                self.params, self.pools, tokens, pos0, table, active,
+                dprobs, *sample_args)
+        with self._span("decode.sync"):
+            n_acc = np.asarray(jax.device_get(n_acc))
+            out = np.asarray(jax.device_get(out))
+        with self._span("decode.commit"):
+            self._commit_spec(n_acc, out)
+
+    def _commit_spec(self, n_acc: np.ndarray, out: np.ndarray):
+        K = self.cfg.spec_k
         t = time.perf_counter()
         for slot in np.flatnonzero(self.active):
             slot = int(slot)
@@ -952,29 +977,28 @@ class OnlineEngine:
         now = time.perf_counter() if now is None else now
         self.ticks += 1
         t_start = time.perf_counter()
-        step_span = "spec" if self.spec else "decode"
-        with self.timer.span("tick"), self._tick_guard():
-            with self.timer.span("admit"):
+        with self._span("tick"), self._tick_guard():
+            with self._span("admit"):
                 self._admit(now)
             step = self._spec_tick if self.spec else self._decode_tick
             if self.policy == "decode-priority":
-                with self.timer.span(step_span):
-                    step(now)
-                with self.timer.span("prefill"):
-                    self._prefill_tick(now)
+                step(now)
+                self._prefill_tick(now)
             elif self.policy == "prefill-priority":
-                with self.timer.span("prefill"):
-                    while self._prefill_tick(now):
-                        pass
-                with self.timer.span(step_span):
-                    step(now)
+                while self._prefill_tick(now):
+                    pass
+                step(now)
             else:                            # fcfs
-                with self.timer.span("prefill"):
-                    self._prefill_tick(now)
-                with self.timer.span(step_span):
-                    step(now)
+                self._prefill_tick(now)
+                step(now)
+            with self._span("counters"):
+                self._sample_counters()
         self._m_tick.observe((time.perf_counter() - t_start) * 1e3)
-        self._sample_counters()
+
+    def _span(self, name: str):
+        """A timer span of this tick's phase `name`, tagged with the tick
+        index that its `RequestLog` events carry."""
+        return self.timer.span(name, tick=self.ticks)
 
     def _sample_counters(self):
         """Per-tick host-scalar samples -> gauges + Perfetto counter

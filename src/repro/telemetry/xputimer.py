@@ -2,9 +2,9 @@
 
 TPU/JAX adaptation (see DESIGN.md §3): CUDA-event interception has no JAX
 analogue visible to user code, so we keep the *design* — selective tracing
-of critical spans, pooled pre-allocated event records, compressed logs
-(only span id + timestamps), asynchronous aggregation — at the host level
-around jitted steps, plus a diagnostic engine with the paper's two modules:
+of critical spans, compressed logs (only span id + timestamps) in a
+pre-allocated ring — at the host level around jitted steps, plus a
+diagnostic engine with the paper's two modules:
 
   * error diagnosis: every span failure is attributed O(1) via the span
     registry (no log search);
@@ -23,6 +23,14 @@ so Prometheus scrapes and ``trace_export`` see the same data as
 ``diagnose()`` without a second instrumentation pass.  Publishing is
 host-side float math only — the zero-host-sync contract in
 docs/observability.md applies.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` named
+``<prefix><name>`` (``engine.`` for the serving engine, ``train.`` for
+the trainer), carrying the span's keyword arguments (the engine passes
+``tick=``).  While a profiler trace is being taken the spans land on its
+host plane, on the same clock as the device ops, so each idle gap of the
+device falls inside a named phase; with the profiler off an annotation
+costs one native call.
 """
 from __future__ import annotations
 
@@ -31,9 +39,10 @@ import threading
 import time
 from collections import defaultdict, deque
 from contextlib import contextmanager
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # compressed record: (span_id: u16, t_start_us: u64, dur_us: u32) = 14 bytes
 _RECORD_BYTES = 14
@@ -56,40 +65,24 @@ class SpanStats:
         self.durations.append(dur_us)
 
 
-class EventPool:
-    """Reusable pre-allocated event records (paper: 'event pool
-    management to reuse pre-allocated CUDA events')."""
-
-    def __init__(self, size: int = 1024):
-        self._free: Deque[list] = deque([None, 0.0, 0.0] for _ in range(size))
-        self.allocated = size
-
-    def get(self) -> list:
-        if self._free:
-            return self._free.popleft()
-        self.allocated += 1
-        return [None, 0.0, 0.0]
-
-    def put(self, ev: list):
-        self._free.append(ev)
-
-
 class XPUTimer:
     """Selective tracing: only registered/used span names are recorded.
 
     `traced_apis` mirrors the TRACED_PYTHON_API env-var mechanism — when
     non-empty, spans not in the set are no-ops (zero overhead path).
+    `prefix` names the spans' profiler annotations (`prefix + name`); the
+    ring, `stats` and the registry mirror keep the bare name.
     """
 
     def __init__(self, traced_apis: Optional[List[str]] = None,
-                 ring_size: int = 65536, registry=None):
+                 ring_size: int = 65536, registry=None, prefix: str = ""):
         self.traced = set(traced_apis) if traced_apis else None
+        self.prefix = prefix
         # optional MetricsRegistry mirror (see module docstring)
         self.registry = registry
         self._reg_hists: Dict[str, Any] = {}
         self._ids: Dict[str, int] = {}
         self._names: List[str] = []
-        self.pool = EventPool()
         # compressed ring buffer: fixed dtype, no python objects
         self.ring = np.zeros(ring_size, dtype=[("sid", "u2"),
                                                ("t0", "u8"),
@@ -101,7 +94,6 @@ class XPUTimer:
         self.gauges: Dict[str, float] = {}
         self.errors: List[Dict[str, Any]] = []
         self._lock = threading.Lock()
-        self._bg_queue: Deque[Tuple[int, float, float]] = deque()
 
     def _sid(self, name: str) -> int:
         if name not in self._ids:
@@ -110,14 +102,16 @@ class XPUTimer:
         return self._ids[name]
 
     @contextmanager
-    def span(self, name: str):
+    def span(self, name: str, **args):
+        """Time the body as span `name`; `args` (host scalars) go to the
+        profiler annotation only."""
         if self.traced is not None and name not in self.traced:
             yield
             return
-        ev = self.pool.get()
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation(self.prefix + name, **args):
+                yield
         except Exception as e:
             # O(1) error attribution: the failing span is known directly
             self.errors.append({"span": name, "time": time.time(),
@@ -137,7 +131,6 @@ class XPUTimer:
                 if self.head >= len(self.ring):
                     self.wrapped = True
                 self.stats[name].add(dur_us)
-                self.pool.put(ev)
             self._publish_span(name, dur_us)
 
     def _publish_span(self, name: str, dur_us: float):

@@ -87,7 +87,8 @@ class Trainer:
         # loss/lr gauges — everything from values already on the host
         # (the drained floats), never an extra device sync
         self.registry = registry if registry is not None else MetricsRegistry()
-        self.timer = timer or XPUTimer(registry=self.registry)
+        self.timer = timer or XPUTimer(registry=self.registry,
+                                       prefix="train.")
         if self.timer.registry is None:
             self.timer.registry = self.registry
         self._m_loss = self.registry.gauge(

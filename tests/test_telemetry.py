@@ -4,10 +4,13 @@ zero-host-sync contract), RequestLog ring + per-rid queries, XPUTimer
 thread safety and memory accounting, SLOTracker gating, Chrome-trace
 structural validity from a real engine run, the /metrics HTTP endpoint,
 and the instrumented engine's compile/transfer contract under churn."""
+import dataclasses
 import json
+import re
 import threading
 import urllib.request
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,6 +19,7 @@ from repro import api
 from repro.analysis.contracts import compile_guard, transfer_guard
 from repro.configs.base import get_smoke_config
 from repro.launch.mesh import make_local_mesh
+from repro.models.model import RunFlags
 from repro.serving.online import OnlineConfig, OnlineEngine, OnlineRequest
 from repro.telemetry import (
     EVENTS, MetricsRegistry, MetricsServer, RequestLog, SLOConfig,
@@ -417,7 +421,10 @@ def test_chrome_trace_from_real_run(runner_params, tmp_path):
     names = {e["name"] for e in events}
     # scheduler-phase tracks from the timer ring
     meta_names = {e["args"]["name"] for e in events if e["ph"] == "M"}
-    assert {"tick", "prefill", "decode", "admit"} <= meta_names
+    assert {"tick", "admit", "prefill.prepare", "prefill.dispatch",
+            "prefill.commit", "prefill.sync", "decode.prepare",
+            "decode.dispatch", "decode.sync", "decode.commit",
+            "counters"} <= meta_names
     # per-slot prefill/decode spans with rids
     x_names = {e["name"] for e in events if e["ph"] == "X" and e["pid"] == 2}
     assert any(s.startswith("prefill r") for s in x_names)
@@ -435,6 +442,92 @@ def test_chrome_trace_from_real_run(runner_params, tmp_path):
                               if e["ph"] == "M"}, names
     # timestamps were rebased near zero
     assert min(e["ts"] for e in events if e["ph"] != "M") == 0
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock: engine phase spans and the programs' named scopes
+# ---------------------------------------------------------------------------
+
+ENGINE_PHASES = {"engine.admit", "engine.prefill.prepare",
+                 "engine.prefill.dispatch", "engine.prefill.commit",
+                 "engine.prefill.sync",
+                 "engine.decode.prepare", "engine.decode.dispatch",
+                 "engine.decode.sync", "engine.decode.commit",
+                 "engine.counters"}
+
+
+def test_engine_phases_land_on_the_profiler_host_plane(runner_params,
+                                                       tmp_path):
+    """Each tick's XPUTimer spans appear in a jax.profiler trace as
+    `engine.*` annotations nested in that tick's `engine.tick`, tagged
+    with the tick index its RequestLog events carry."""
+    from jax.profiler import ProfileData
+    runner, params = runner_params
+    eng = OnlineEngine(runner, params, OnlineConfig(
+        max_slots=4, max_context=32, page_size=8, prefill_chunk=4))
+    for i in range(3):
+        eng.submit(OnlineRequest(rid=i, prompt=np.arange(6, dtype=np.int32)
+                                 + i, max_new=6))
+    for _ in range(3):                # compiles, and fills the slots
+        eng.tick()
+    jax.profiler.start_trace(str(tmp_path))
+    first = eng.ticks + 1
+    for _ in range(3):
+        eng.tick()
+    jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans = [(int(e.start_ns), int(e.start_ns + e.duration_ns), e.name,
+              dict(e.stats).get("tick"))
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("engine.")]
+    ticks = {t: (a, b) for a, b, n, t in spans if n == "engine.tick"}
+    assert sorted(ticks) == [first, first + 1, first + 2]
+    phases = [(a, b, n, t) for a, b, n, t in spans if n != "engine.tick"]
+    assert {n for _, _, n, _ in phases} >= ENGINE_PHASES - {
+        "engine.prefill.sync"}
+    for a, b, n, t in phases:
+        lo, hi = ticks[t]
+        assert lo <= a and b <= hi, (n, t)
+    # the ring keeps the bare names, as before
+    assert {"tick", "decode.sync", "counters"} <= set(eng.timer.span_names())
+
+
+@pytest.mark.parametrize("flags,scopes", [
+    (RunFlags(moe_dispatch="ragged", paged_attn="gathered"),
+     {"embed", "attention", "moe_ffn/router", "moe_ffn/dispatch",
+      "moe_ffn/experts", "moe_ffn/combine", "moe_ffn/shared_experts",
+      "lm_head"}),
+    (RunFlags(moe_dispatch="fused", paged_attn="fused"),
+     {"embed", "attention", "moe_ffn/router", "moe_ffn/dispatch",
+      "moe_ffn/experts", "moe_ffn/shared_experts", "lm_head"}),
+], ids=["ragged-gathered", "fused"])
+def test_the_decode_program_names_its_layers(runner_params, flags, scopes):
+    """The compiled decode step keeps the model's named scopes in its
+    ops' `op_name`, which the TPU profiler reports as `tf_op`."""
+    runner, params = runner_params
+    runner = dataclasses.replace(runner, flags=flags)
+    eng = OnlineEngine(runner, params, OnlineConfig(
+        max_slots=4, max_context=32, page_size=8, prefill_chunk=4))
+    args = [jnp.asarray(a) for a in (eng.tok, eng.lens, eng.table,
+                                     eng.active, eng.seeds, eng.temps,
+                                     eng.topps, eng.topks)]
+    hlo = eng._decode.lower(eng.params, eng.pools, *args).compile().as_text()
+    names = {"attention", "moe_ffn", "router", "dispatch", "experts",
+             "combine", "shared_experts", "lm_head", "embed"}
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
+        path = []
+        for part in op_name.split("/"):
+            if part in names and part not in path:
+                path.append(part)
+        if path:
+            found.add("/".join(path))
+    assert scopes <= found, sorted(found)
+    # no other layer nests inside a scope
+    assert all(p.split("/")[0] in ("embed", "attention", "moe_ffn",
+                                   "lm_head") for p in found), found
 
 
 def test_chrome_trace_sources_optional():
