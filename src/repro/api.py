@@ -69,6 +69,26 @@ class Runner:
             is_leaf=lambda x: isinstance(x, P))
         return jax.jit(init_fn, out_shardings=shardings)()
 
+    def serve_params(self, params):
+        """The tree the serve steps read, built once per engine: each
+        leaf that every serve read casts to the compute dtype
+        (`M.cast_on_read`) is cast here, on the device and with its own
+        sharding, so the steps read bitwise the values they would cast
+        themselves and make no cast.  Every other leaf, and any leaf
+        already in the compute dtype, is the caller's array, not a copy.
+        `params` itself is left as it is."""
+        cdt = jnp.dtype(self.cfg.compute_dtype)
+        leaves, treedef = jax.tree.flatten(params)
+        want = jax.tree.leaves(M.cast_on_read(self.cfg, params))
+        idx = [i for i, (a, w) in enumerate(zip(leaves, want))
+               if w and a.dtype != cdt]
+        if idx:
+            cast = jax.jit(lambda xs: [x.astype(cdt) for x in xs],
+                           out_shardings=[leaves[i].sharding for i in idx])
+            for i, y in zip(idx, cast([leaves[i] for i in idx])):
+                leaves[i] = y
+        return jax.tree.unflatten(treedef, leaves)
+
     def abstract_params(self):
         """ShapeDtypeStructs with shardings attached (dry-run path)."""
         shardings = jax.tree.map(

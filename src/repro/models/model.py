@@ -324,12 +324,38 @@ def _final_norm(cfg, env: AxisEnv, params, x):
         return L.apply_norm(cfg, env, params["final_norm"], x)
 
 
+# Weight leaves that every serve read passes through a cast to the
+# compute dtype (`env.gather_fsdp(w, axis, dtype=cdt)`): the attention
+# projections, the dense and shared MLPs and the routed experts.  Norm
+# scales, the router and the LM head are read in f32.
+_CAST_ON_READ = frozenset(
+    {"wq", "wk", "wv", "wo", "w1", "w2", "w3", "we1", "we2", "we3"})
+
+
+def cast_on_read(cfg: ModelConfig, params):
+    """Tree of bools over `params`: True where every read of the leaf by
+    the serve steps casts it to the compute dtype, so casting it once
+    beforehand gives those reads bitwise the values they make today.
+    The embedding table qualifies only when untied: a tied LM head reads
+    it in f32."""
+    def rule(path, _):
+        keys = [p.key for p in path if isinstance(p, jax.tree_util.DictKey)]
+        if keys[0] == "embed":
+            return keys[-1] == "table" and not cfg.tie_embeddings
+        return keys[-1] in _CAST_ON_READ
+    return jax.tree_util.tree_map_with_path(rule, params)
+
+
 def _serve_blocks(cfg: ModelConfig, params):
     """The stacked layer weights a serve step's layer loop reads, with the
     routed experts' weights cast to the compute dtype before the loop.
     XLA hoists that cast out of the loop anyway, and the op it hoists
     carries no `op_name`; cast here, under `moe_ffn/experts`, it keeps
-    its scope in the profiler trace."""
+    its scope in the profiler trace.  The online engine passes the tree
+    of `api.Runner.serve_params`, whose experts are already in the
+    compute dtype, so there the cast is no op; it stays for callers that
+    pass f32 weights straight to a step (the dense `make_decode_step`,
+    parity tests, `make_paged_logits` comparisons)."""
     blocks = params["blocks"]
     if "moe" not in blocks:
         return blocks

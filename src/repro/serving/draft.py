@@ -14,8 +14,8 @@ Two implementations:
   * **SelfDrafter** — truncated-layer self-draft (AquilaMoE-style reuse,
     no new weights): the draft model is the first `draft_layers` blocks
     of the target plus its own embedding / final norm / LM head.  Params
-    are views into the target's (stacked leaves sliced, or the block
-    list truncated), so HBM cost is only the drafter KV pool.
+    come from the target's serve tree (stacked leaves sliced, or the
+    block list truncated), so they are already in the compute dtype.
     `draft_layers == n_layers` degenerates to an exact copy of the
     target — q == p bitwise, every draft accepted — which is the upper
     bound the benchmarks calibrate against.
@@ -23,7 +23,8 @@ Two implementations:
   * **ConfigDrafter** — any small paged-compatible config sharing the
     target's vocab (e.g. an adapted `h2o_danube_1_8b` smoke config)
     behind the same interface.  Params are loaded by the caller or
-    randomly initialized (`init_seed`); `adapt_drafter_config` rewrites
+    randomly initialized (`init_seed`), then cast as the target's are
+    (`api.Runner.serve_params`); `adapt_drafter_config` rewrites
     a foreign config to be pageable (swa -> attn) and vocab-aligned.
 
 Acceptance-rate guidance lives in docs/serving.md — the short version:
@@ -101,7 +102,7 @@ class ConfigDrafter:
         drunner = _draft_runner(self.cfg, runner)
         dparams = (self.params if self.params is not None
                    else drunner.init_params(self.init_seed))
-        return drunner, dparams
+        return drunner, drunner.serve_params(dparams)
 
 
 def adapt_drafter_config(cfg: ModelConfig,

@@ -271,6 +271,9 @@ class OnlineEngine:
                 "telemetry.SLOConfig(ttft_p99_ms=...)")
         self.cfg = cfg
         self.runner = runner
+        # matmul weights in the compute dtype, cast once here rather than
+        # in every step; the engine keeps no reference to their originals
+        params = runner.serve_params(params)
         self.params = params
         # resolved paged-attention backend (RunFlags.paged_attn "auto"
         # settles at engine build time) — surfaced in load reports so a
@@ -424,6 +427,12 @@ class OnlineEngine:
         self._g_pages = reg.gauge(
             "serve_pages_in_use", "KV pool pages held (requests+cache)")
         self._g_slots = reg.gauge("serve_slots_active", "occupied slots")
+        by_dtype: Dict[str, int] = {}
+        for a in jax.tree.leaves(self.params):
+            by_dtype[str(a.dtype)] = by_dtype.get(str(a.dtype), 0) + a.nbytes
+        for dt, n in by_dtype.items():
+            reg.gauge("serve_weight_bytes", "bytes of the weights the serve "
+                      "steps read, by dtype", dtype=dt).set(n)
         # per-tick samples -> Perfetto counter tracks (trace_export)
         self._s_pages = reg.series("page_pool_occupancy")
         self._s_queue = reg.series("queue_depth")

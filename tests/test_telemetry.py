@@ -386,6 +386,25 @@ def test_engine_slo_gate_sheds_under_pressure(runner_params):
     assert all(r.done for r in warm)
 
 
+def test_engine_publishes_serve_weight_bytes(runner_params):
+    """`serve_weight_bytes{dtype=...}` holds the bytes of the tree the
+    serve steps read, by dtype, set once when the engine is built: the
+    matmul weights in bf16, norms, router and NormHead head in f32."""
+    runner, params = runner_params
+    eng = OnlineEngine(runner, params, OnlineConfig(
+        max_slots=4, max_context=32, page_size=8, prefill_chunk=4))
+    want = {}
+    for a in jax.tree.leaves(eng.params):
+        want[str(a.dtype)] = want.get(str(a.dtype), 0) + a.size * a.itemsize
+    assert set(want) == {"bfloat16", "float32"}
+    for dt, n in want.items():
+        assert eng.registry.get("serve_weight_bytes", dtype=dt).value == n
+    total = sum(a.size * a.itemsize for a in jax.tree.leaves(params))
+    assert want["bfloat16"] == (total - want["float32"]) // 2
+    assert 'serve_weight_bytes{dtype="bfloat16"}' in \
+        eng.registry.render_prometheus()
+
+
 def test_engine_rejects_slo_overload_without_config(runner_params):
     runner, params = runner_params
     with pytest.raises(ValueError, match="slo"):
