@@ -631,18 +631,18 @@ def globalize_shapes(shape_tree, spec_tree, mesh_sizes):
 def paged_cache_specs(cfg, env: AxisEnv):
     """PartitionSpecs for the paged KV pools (serving/online.py).
 
-    Pool layout per layer: (n_pages, KV, page_size, hd) with the page_size
-    dim sharded over tp (each rank stores ps_loc = page_size/tp offsets of
-    every page); uniform archs carry a leading layer dim.  Pages are
-    slot-agnostic and may be referenced by several page tables at once —
-    refcounted prefix sharing and the cross-request radix cache retain a
-    page across requests; the pools themselves never change shape for it."""
-    lead = 1 if (cfg.uniform_blocks and not cfg.is_encoder_decoder) else 0
-    spec = P(*([None] * lead), None, None, env.tp_axis, None)
-    one = {"self": {"k": spec, "v": spec}}
-    if lead:
-        return one
-    return [one for _ in range(cfg.n_layers)]
+    Pool layout per layer: (n_pages, KV, page_size, width) — K and V of
+    each head, or one latent row for latent attention — with the
+    page_size dim sharded over tp (each rank stores ps_loc = page_size/tp
+    offsets of every page), behind the leading layer dim of each stack
+    (`M.init_paged_caches`).  Pages are slot-agnostic and may be
+    referenced by several page tables at once — refcounted prefix sharing
+    and the cross-request radix cache retain a page across requests; the
+    pools themselves never change shape for it."""
+    spec = P(None, None, None, env.tp_axis, None)
+    pools = jax.eval_shape(
+        lambda: M.init_paged_caches(cfg, env, 2, env.tp))
+    return jax.tree.map(lambda _: spec, pools)
 
 
 def cache_partition_specs(cfg, env: AxisEnv, cache_tree, b):
@@ -650,11 +650,13 @@ def cache_partition_specs(cfg, env: AxisEnv, cache_tree, b):
 
     Local cache layouts (built inside shard_map with local sizes):
       attn k/v   (B_loc, S_loc, KV, hd)   -> P(b, tp, None, None)
+      mla kv     (B_loc, S_loc, 1, lw)    -> P(b, tp, None, None)
       rwkv wkv   (B_loc, H_loc, hd, hd)   -> P(b, tp, None, None)
       rwkv last_x / cmix_prev (B_loc, d)  -> P(b, None)
       rglru h    (B_loc, dr_loc)          -> P(b, tp)
       rglru conv (B_loc, 3, dr_loc)       -> P(b, None, tp)
-    Uniform-arch caches carry a leading layer dim (None).
+    Uniform-arch caches carry a leading layer dim (None), per stack
+    (`M.layer_stacks`).
     """
     tp = env.tp_axis
     lead = 1 if (cfg.uniform_blocks and not cfg.is_encoder_decoder) else 0
@@ -663,8 +665,8 @@ def cache_partition_specs(cfg, env: AxisEnv, cache_tree, b):
         out = {}
         for k, v in layer_cache.items():
             if k in ("self", "cross"):
-                out[k] = {"k": P(*([None] * lead), b, tp, None, None),
-                          "v": P(*([None] * lead), b, tp, None, None)}
+                out[k] = {name: P(*([None] * lead), b, tp, None, None)
+                          for name in v}
             elif k == "rwkv":
                 out[k] = {"wkv": P(*([None] * lead), b, tp, None, None),
                           "last_x": P(*([None] * lead), b, None)}
@@ -676,5 +678,7 @@ def cache_partition_specs(cfg, env: AxisEnv, cache_tree, b):
         return out
 
     if lead:
-        return one_layer_spec(cache_tree)
+        return M.from_stacks(cfg, {
+            key: one_layer_spec(c)
+            for key, c in M.per_stack(cfg, cache_tree).items()})
     return [one_layer_spec(c) for c in cache_tree]

@@ -45,6 +45,44 @@ class MoEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
+    keys and values are expanded per head from one shared latent
+    `c_kv` of width `kv_lora_rank`, and a single rotary key of width
+    `qk_rope_head_dim` is shared by every head.  Queries are projected
+    straight from the hidden state (no query latent)."""
+
+    kv_lora_rank: int              # latent width (c_kv)
+    qk_nope_head_dim: int          # per-head query/key width without rope
+    qk_rope_head_dim: int          # rotary width (one shared key)
+    v_head_dim: int                # per-head value width
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Width of one cached row: c_kv then the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 publishes
+    it: frequencies blended between interpolated (÷ factor) and
+    original by a linear ramp over the rotary pairs, and the softmax
+    scaled by mscale(mscale_all_dim)^2."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """A composable decoder (or encoder-decoder) transformer description."""
 
@@ -65,12 +103,15 @@ class ModelConfig:
     #   "swa"    sliding-window attention (window = attn_window)
     #   "rglru"  RG-LRU recurrent block (RecurrentGemma)
     #   "rwkv"   RWKV6 time-mix block (attention free)
+    #   "mla"    causal multi-head latent attention (needs `mla`)
     block_pattern: Tuple[str, ...] = ("attn",)
     attn_window: Optional[int] = None  # sliding/local attention window
     mlp_act: str = "swiglu"            # swiglu | squared_relu | gelu
     norm_type: str = "rmsnorm"         # rmsnorm | layernorm
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YaRN] = None
     use_rope: bool = True
+    mla: Optional[MLAConfig] = None
     tie_embeddings: bool = False
 
     moe: Optional[MoEConfig] = None
@@ -95,6 +136,9 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if ("mla" in self.block_pattern) != (self.mla is not None):
+            raise ValueError(f"{self.arch_id}: an 'mla' block needs `mla` "
+                             f"dims, and `mla` needs an 'mla' block")
 
     # -- derived ------------------------------------------------------------
     @property
@@ -111,7 +155,7 @@ class ModelConfig:
     @property
     def sub_quadratic(self) -> bool:
         """True if no block requires O(S^2) full attention (long_500k gate)."""
-        return all(k != "attn" for k in self.block_pattern)
+        return all(k not in ("attn", "mla") for k in self.block_pattern)
 
     def param_count(self) -> int:
         """Analytic total parameter count (embeddings included once)."""
@@ -133,6 +177,12 @@ class ModelConfig:
             if kind in ("attn", "swa"):
                 n += (self.n_heads + 2 * self.n_kv_heads) * hd * d
                 n += self.n_heads * hd * d
+            elif kind == "mla":
+                a = self.mla
+                n += d * self.n_heads * a.qk_head_dim + d * a.latent_width
+                n += a.kv_lora_rank * (1 + self.n_heads * (
+                    a.qk_nope_head_dim + a.v_head_dim))
+                n += self.n_heads * a.v_head_dim * d
             elif kind == "rglru":
                 dr = _rglru_dim(d)
                 n += 2 * d * dr + dr * d + 3 * dr + 2 * dr * (dr // _RGLRU_BLOCKS)
@@ -214,6 +264,7 @@ ARCH_IDS = [
     "nemotron-4-15b",
     "ling-lite",
     "ling-plus",
+    "deepseek-v2-lite",
 ]
 
 _MODULE_FOR = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

@@ -17,16 +17,20 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import AxisEnv, fsdp_spec, pad_to_multiple
 
 Params = Dict[str, jax.Array]
 Specs = Dict[str, P]
+
+RMS_NORM_EPS = 1e-6  # every configuration's, DeepSeek-V2-Lite's included
 
 
 # ---------------------------------------------------------------------------
@@ -67,9 +71,14 @@ def apply_norm(cfg, env: AxisEnv, params: Params, x: jax.Array) -> jax.Array:
         var = jnp.var(xf, axis=-1, keepdims=True)
         out = (xf - mu) * jax.lax.rsqrt(var + 1e-5) * scale + bias
     else:  # rmsnorm
-        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        out = xf * jax.lax.rsqrt(ms + 1e-6) * scale
+        out = rms_norm(xf, scale)
     return out.astype(x.dtype)
+
+
+def rms_norm(xf: jax.Array, scale: jax.Array) -> jax.Array:
+    """RMSNorm of f32 `xf` over its last axis, times `scale`."""
+    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return xf * jax.lax.rsqrt(ms + RMS_NORM_EPS) * scale
 
 
 # ---------------------------------------------------------------------------
@@ -83,6 +92,46 @@ def rope_angles(positions: jax.Array, head_dim: int, theta: float):
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_inv_freq(cfg, dim: int) -> np.ndarray:
+    """Rotary frequencies (dim/2,) for `dim` rotated columns:
+    theta^(-2i/dim), or with `cfg.rope_scaling` (YaRN) the blend
+    inv = f/factor * (1 - mask) + f * mask, where mask = 1 - a linear
+    ramp over the pairs from floor(corr(beta_fast)) to
+    ceil(corr(beta_slow)), corr(b) = dim ln(L0 / (2 pi b)) / (2 ln theta)
+    clamped to [0, dim - 1], L0 the original context."""
+    half = dim // 2
+    f = cfg.rope_theta ** (-2.0 * np.arange(half) / dim)
+    y = cfg.rope_scaling
+    if y is None:
+        return f.astype(np.float32)
+
+    def corr(b):
+        return (dim * math.log(y.original_max_position_embeddings
+                               / (b * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(corr(y.beta_fast)), 0)
+    high = min(math.ceil(corr(y.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    mask = 1.0 - ramp
+    return (f / y.factor * (1.0 - mask) + f * mask).astype(np.float32)
+
+
+def scaled_rope_angles(cfg, positions: jax.Array, dim: int):
+    """cos/sin (..., dim/2) at `rope_inv_freq`, times YaRN's
+    mscale(mscale) / mscale(mscale_all_dim) when it is not 1."""
+    ang = positions.astype(jnp.float32)[..., None] * rope_inv_freq(cfg, dim)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    y = cfg.rope_scaling
+    m = (1.0 if y is None else _yarn_mscale(y.factor, y.mscale)
+         / _yarn_mscale(y.factor, y.mscale_all_dim))
+    return (cos, sin) if m == 1.0 else (cos * m, sin * m)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -241,13 +290,15 @@ def _pair_mask(qi, ki, bq, bk, causal, window, q_offset):
     return mask
 
 
-def _flash_fwd(qT, kT, vT, pairs, *, bq, bk, nq, causal, window, q_offset):
-    """Returns (out_T (B,H,Sq,hd) f32 normalized, m (nq,B,H,bq), l)."""
-    B, H, Sq, hd = qT.shape
-    scale = hd ** -0.5
+def _flash_fwd(qT, kT, vT, pairs, *, bq, bk, nq, causal, window, q_offset,
+               scale):
+    """Returns (out_T (B,H,Sq,hv) f32 normalized, m (nq,B,H,bq), l); hv is
+    the value width, which may differ from the query/key width."""
+    B, H, Sq, _ = qT.shape
+    hv = vT.shape[-1]
     m0 = jnp.full((nq, B, H, bq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((nq, B, H, bq), jnp.float32)
-    a0 = jnp.zeros((nq, B, H, bq, hd), jnp.float32)
+    a0 = jnp.zeros((nq, B, H, bq, hv), jnp.float32)
 
     def step(carry, pair):
         m, l, acc = carry
@@ -277,14 +328,15 @@ def _flash_fwd(qT, kT, vT, pairs, *, bq, bk, nq, causal, window, q_offset):
 
     (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), pairs)
     out = acc / jnp.maximum(l, 1e-20)[..., None]          # (nq,B,H,bq,hd)
-    out_T = jnp.moveaxis(out, 0, 2).reshape(B, H, Sq, hd)
+    out_T = jnp.moveaxis(out, 0, 2).reshape(B, H, Sq, hv)
     return out_T, m, l
 
 
 @functools.lru_cache(maxsize=None)
 def _flash_attention(bq: int, bk: int, nq: int, nk: int,
                      pairs_key: Tuple[Tuple[int, ...], Tuple[int, ...]],
-                     causal: bool, window: Optional[int], q_offset: int):
+                     causal: bool, window: Optional[int], q_offset: int,
+                     scale: float):
     """custom_vjp flash attention specialized to a static schedule.
 
     Residuals are only (q, k, v, out, m, l) — the backward *recomputes* the
@@ -302,19 +354,18 @@ def _flash_attention(bq: int, bk: int, nq: int, nk: int,
     def attn(qT, kT, vT):
         out_T, _, _ = _flash_fwd(qT, kT, vT, pairs, bq=bq, bk=bk, nq=nq,
                                  causal=causal, window=window,
-                                 q_offset=q_offset)
+                                 q_offset=q_offset, scale=scale)
         return out_T.astype(qT.dtype)
 
     def fwd(qT, kT, vT):
         out_T, m, l = _flash_fwd(qT, kT, vT, pairs, bq=bq, bk=bk, nq=nq,
                                  causal=causal, window=window,
-                                 q_offset=q_offset)
+                                 q_offset=q_offset, scale=scale)
         return out_T.astype(qT.dtype), (qT, kT, vT, out_T, m, l)
 
     def bwd(res, g):
         qT, kT, vT, out_T, m, l = res
-        B, H, Sq, hd = qT.shape
-        scale = hd ** -0.5
+        B, H, Sq, _ = qT.shape
         gf = g.astype(jnp.float32)
         # D = rowsum(dout * out) per query
         D = jnp.sum(gf * out_T, axis=-1)                  # (B,H,Sq)
@@ -370,11 +421,13 @@ def _flash_attention(bq: int, bk: int, nq: int, nk: int,
 def attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
                    causal: bool, window: Optional[int],
                    q_offset: int = 0, schedule: str = "causal",
-                   block_target: int = 1024) -> jax.Array:
+                   block_target: int = 1024,
+                   scale: Optional[float] = None) -> jax.Array:
     """Blockwise (flash-structured) attention in pure JAX.
 
-    q (B, Sq, H, hd); k, v (B, Sk, H, hd)  [kv already expanded to H heads]
-    Returns (B, Sq, H, hd).  Memory is O(S * block) instead of O(S^2) in
+    q, k (B, Sq|Sk, H, hd); v (B, Sk, H, hv)  [kv already expanded to H
+    heads]; scores scaled by `scale` (default hd^-1/2).
+    Returns (B, Sq, H, hv).  Memory is O(S * block) instead of O(S^2) in
     BOTH directions: the custom_vjp recomputes block probabilities in the
     backward pass, so 32k-sequence steps are lowerable.  The (q_block,
     k_block) schedule is enumerated statically: 'causal' visits only
@@ -391,7 +444,8 @@ def attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qs_idx, ks_idx = _schedule_pairs(nq, nk, bq, bk, schedule,
                                      window if schedule == "window" else None)
     fn = _flash_attention(bq, bk, nq, nk, (tuple(qs_idx), tuple(ks_idx)),
-                          causal, window, q_offset)
+                          causal, window, q_offset,
+                          hd ** -0.5 if scale is None else scale)
     out_T = fn(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
                jnp.swapaxes(v, 1, 2))
     return jnp.swapaxes(out_T, 1, 2)                      # (B,Sq,H,hd)
@@ -596,30 +650,34 @@ def paged_valid_mask(table, pos, *, page_size: int, ps_loc: int,
     return pvalid[:, None, :] & (gpos[None, None, :] <= pos[:, :, None])
 
 
-def _paged_write(pool, k_new, v_new, pos, page_table, owns, *,
+def _paged_write(pool, rows, pos, page_table, owns, *,
                  page_size: int, env: AxisEnv, cdt):
-    """Scatter per-lane KV rows into their pages.
+    """Scatter per-lane cache rows into their pages.
 
-    pos (...,) int32 global positions; page_table broadcastable lookup of
-    the physical page per lane (already resolved by the caller); owns
-    (...,) bool — lanes that are inactive, unallocated, or whose in-page
-    offset belongs to another tp rank write to scratch page 0 instead.
+    rows {pool leaf: (..., KV, hd) new rows}; pos (...,) int32 global
+    positions; page_table broadcastable lookup of the physical page per
+    lane (already resolved by the caller); owns (...,) bool — lanes that
+    are inactive, unallocated, or whose in-page offset belongs to another
+    tp rank write to scratch page 0 instead.
     """
-    ps_loc = pool["k"].shape[2]
+    ps_loc = next(iter(pool.values())).shape[2]
     r = env.tp_index()
     o = pos % page_size
     dest = jnp.where(owns, page_table, 0)
     o_loc = jnp.clip(o - r * ps_loc, 0, ps_loc - 1)
-    return {"k": pool["k"].at[dest, :, o_loc].set(k_new.astype(cdt)),
-            "v": pool["v"].at[dest, :, o_loc].set(v_new.astype(cdt))}
+    return {name: pool[name].at[dest, :, o_loc].set(new.astype(cdt))
+            for name, new in rows.items()}
 
 
 def _paged_scores_combine(cfg, env: AxisEnv, ad: AttnDims, q_all, k_g, v_g,
-                          valid, cdt):
+                          valid, cdt, scale: Optional[float] = None):
     """Query-batched attention tail over ONE shared cache view: masked
-    scores + online-softmax (num, den) psum over tp + normalize.
+    scores (times `scale`, default hd^-1/2) + online-softmax (num, den)
+    psum over tp + normalize.
 
-    q_all (B, Q, Hp, hd); k_g/v_g (B, S, KV, hd) — read once, every
+    q_all (B, Q, Hp, hd); k_g (B, S, KV, hd), v_g (B, S, KV, hv) — the
+    value width may differ (latent attention reads the first columns of
+    its one cached row as values) — read once, every
     query contracts against the same view via batched einsums (no
     per-query broadcast_to copy);  valid (B, Q, S).  Fast path: when no
     head padding happened and heads group evenly onto kv heads, q
@@ -627,8 +685,10 @@ def _paged_scores_combine(cfg, env: AxisEnv, ad: AttnDims, q_all, k_g, v_g,
     no expanded KV copy ever hits HBM (big decode-bandwidth win, see
     EXPERIMENTS.md §Perf); p stays in compute dtype for the PV
     contraction (flash-kernel convention) so no f32 copy of the
-    cache-sized V materializes either.  Returns (B, Q, Hp, hd)."""
+    cache-sized V materializes either.  Returns (B, Q, Hp, hv)."""
     hd = ad.head_dim
+    hv = v_g.shape[-1]
+    scale = hd ** -0.5 if scale is None else scale
     B, Qn, S_g = valid.shape
     grouped = (ad.n_heads == ad.heads_padded
                and ad.heads_padded % ad.n_kv == 0)
@@ -636,7 +696,7 @@ def _paged_scores_combine(cfg, env: AxisEnv, ad: AttnDims, q_all, k_g, v_g,
         g = ad.heads_padded // ad.n_kv
         q_g = q_all.reshape(B, Qn, ad.n_kv, g, hd)
         s = jnp.einsum("bqkgd,bskd->bqkgs", q_g, k_g,
-                       preferred_element_type=jnp.float32) * hd ** -0.5
+                       preferred_element_type=jnp.float32) * scale
         s = s.reshape(B, Qn, ad.heads_padded, S_g)
     else:
         group = max(ad.n_heads // ad.n_kv, 1)
@@ -644,7 +704,7 @@ def _paged_scores_combine(cfg, env: AxisEnv, ad: AttnDims, q_all, k_g, v_g,
                             ad.n_kv - 1)
         k_exp = jnp.take(k_g, hp_kv, axis=2)
         s = jnp.einsum("bqhd,bshd->bqhs", q_all, k_exp,
-                       preferred_element_type=jnp.float32) * hd ** -0.5
+                       preferred_element_type=jnp.float32) * scale
     s = jnp.where(valid[:, :, None, :], s, -jnp.inf)
     m_loc = jnp.max(s, axis=-1)
     m = env.pmax_tp(m_loc)
@@ -655,7 +715,7 @@ def _paged_scores_combine(cfg, env: AxisEnv, ad: AttnDims, q_all, k_g, v_g,
         p_g = p_c.reshape(B, Qn, ad.n_kv, ad.heads_padded // ad.n_kv, S_g)
         num = jnp.einsum("bqkgs,bskd->bqkgd", p_g, v_g,
                          preferred_element_type=jnp.float32)
-        num = num.reshape(B, Qn, ad.heads_padded, hd)
+        num = num.reshape(B, Qn, ad.heads_padded, hv)
     else:
         group = max(ad.n_heads // ad.n_kv, 1)
         hp_kv = jnp.minimum(jnp.arange(ad.heads_padded) // group,
@@ -766,7 +826,7 @@ def paged_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     lp = jnp.clip(pos // page_size, 0, n_lp - 1)
     pp = jnp.take_along_axis(table, lp[:, None], axis=1)[:, 0]
     owns = active & (pp > 0) & ((pos % page_size) // ps_loc == r)
-    pool = _paged_write(pool, k_new, v_new, pos, pp, owns,
+    pool = _paged_write(pool, {"k": k_new, "v": v_new}, pos, pp, owns,
                         page_size=page_size, env=env, cdt=cdt)
 
     if valid is None:
@@ -826,7 +886,7 @@ def paged_prefill_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     pp = jnp.take(table_row, lp)                             # (C,)
     owns = ((jnp.arange(C) < n_valid) & (pp > 0)
             & ((posq % page_size) // ps_loc == r))
-    pool = _paged_write(pool, k_new, v_new, posq, pp, owns,
+    pool = _paged_write(pool, {"k": k_new, "v": v_new}, posq, pp, owns,
                         page_size=page_size, env=env, cdt=cdt)
 
     if valid is None:
@@ -890,9 +950,9 @@ def paged_verify_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     pp = jnp.take_along_axis(table, lp, axis=1)            # (B, Q)
     owns = (active[:, None] & (pp > 0)
             & ((pos % page_size) // ps_loc == r))
-    pool = _paged_write(pool, k_new.reshape(B, Q, ad.n_kv, hd),
-                        v_new.reshape(B, Q, ad.n_kv, hd), pos, pp, owns,
-                        page_size=page_size, env=env, cdt=cdt)
+    pool = _paged_write(pool, {"k": k_new.reshape(B, Q, ad.n_kv, hd),
+                               "v": v_new.reshape(B, Q, ad.n_kv, hd)},
+                        pos, pp, owns, page_size=page_size, env=env, cdt=cdt)
 
     if valid is None:
         valid = paged_valid_mask(table, pos, page_size=page_size,
@@ -906,3 +966,255 @@ def paged_verify_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     local = jax.lax.dynamic_slice_in_dim(attn, lo, ad.local_heads, axis=1)
     partial = local.reshape(B * Q, ad.local_heads * hd) @ wo
     return partial, pool
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2 MLA)
+# ---------------------------------------------------------------------------
+#
+# Per token: q = W_q x, split per head into q_nope and q_pe;
+# [c_kv | k_pe] = W_kva x; c_kv = RMSNorm(c_kv) (learned scale); RoPE
+# (YaRN) on q_pe and on the one k_pe all heads share; [k_nope | v] =
+# W_kvb c_kv per head; score = (q_nope.k_nope + q_pe.k_pe) * s with
+# s = qk_head_dim^-1/2 * mscale^2; out = W_o (softmax . v).
+#
+# The cache holds one latent row per token, [c_kv | k_pe]: a single KV
+# "head" of width kv_lora_rank + qk_rope_head_dim, whose first
+# kv_lora_rank columns are the values.  Decode and chunked prefill take
+# the absorbed form: W_UK (the key half of W_kvb) folds into the query,
+# scores are taken against the latent rows directly (multi-query
+# attention through `_paged_scores_combine`), the output is taken in
+# latent space and then multiplied by W_UV.  Per-head K and V are never
+# materialised for the cache.  Training expands them (`kv_expand`).
+#
+# Scopes under `attention`: `q_proj` (W_q, rope on q_pe), `kv_compress`
+# (W_kva, the c_kv norm, rope on k_pe, the cache write), `absorb` (W_UK
+# into the query, W_UV out of latent space), `latent_attn` (the cache
+# view, scores, softmax, values) and `o_proj`.
+
+
+def mla_dims(cfg, env: AxisEnv) -> AttnDims:
+    """Query heads as the latent path holds them: one shared KV head of
+    the latent row's width."""
+    hp = pad_to_multiple(cfg.n_heads, env.tp)
+    return AttnDims(cfg.n_heads, 1, hp, hp // env.tp,
+                    cfg.mla.latent_width)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """qk_head_dim^-1/2, times mscale(mscale_all_dim)^2 under YaRN."""
+    s = cfg.mla.qk_head_dim ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        s *= _yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return s
+
+
+def init_mla(key, cfg, env: AxisEnv) -> Tuple[Params, Specs]:
+    a, ad = cfg.mla, mla_dims(cfg, env)
+    d, hp = cfg.d_model, ad.heads_padded
+    dt = jnp.dtype(cfg.param_dtype)
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    out_scale = 0.02 / max(cfg.n_layers, 1) ** 0.5
+    params = {
+        "wq": dense_init(kq, (d, hp * a.qk_head_dim), dt),
+        "wkv_a": dense_init(ka, (d, a.latent_width), dt),
+        "kv_norm": {"scale": jnp.ones((a.kv_lora_rank,), dt)},
+        "wkv_b": dense_init(kb, (a.kv_lora_rank,
+                                 hp * (a.qk_nope_head_dim + a.v_head_dim)),
+                            dt),
+        "wo": dense_init(ko, (hp * a.v_head_dim, d), dt, out_scale),
+    }
+    specs = {
+        "wq": fsdp_spec(env, 2, 0, 1),          # column: heads sharded
+        "wkv_a": fsdp_spec(env, 2, 0, None),    # replicated over tp
+        "kv_norm": {"scale": fsdp_spec(env, 1, 0)},
+        "wkv_b": fsdp_spec(env, 2, 0, 1),       # column: heads sharded
+        "wo": fsdp_spec(env, 2, 1, 0),          # row: heads sharded
+    }
+    return params, specs
+
+
+def _mla_query(cfg, env: AxisEnv, params: Params, x, pos):
+    """x (..., d) at positions pos (...) -> q_nope (..., Hl, nope) and the
+    rotated q_pe (..., Hl, rope), local heads, compute dtype."""
+    a, ad = cfg.mla, mla_dims(cfg, env)
+    cdt = jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("q_proj"):
+        wq = env.gather_fsdp(params["wq"], 0, dtype=cdt)
+        q = (x @ wq).reshape(*x.shape[:-1], ad.local_heads, a.qk_head_dim)
+        cos, sin = scaled_rope_angles(cfg, pos, a.qk_rope_head_dim)
+        return (q[..., :a.qk_nope_head_dim],
+                apply_rope(q[..., a.qk_nope_head_dim:], cos, sin))
+
+
+def _mla_latent(cfg, env: AxisEnv, params: Params, x, pos):
+    """x (..., d) at positions pos (...) -> the cached latent row
+    (..., kv_lora_rank + rope): normed c_kv, then the rotated k_pe."""
+    a = cfg.mla
+    cdt = jnp.dtype(cfg.compute_dtype)
+    r = a.kv_lora_rank
+    with jax.named_scope("kv_compress"):
+        wa = env.gather_fsdp(params["wkv_a"], 0, dtype=cdt)
+        kv = x @ wa
+        scale = env.gather_fsdp(params["kv_norm"]["scale"], 0
+                                ).astype(jnp.float32)
+        c = rms_norm(kv[..., :r].astype(jnp.float32), scale).astype(cdt)
+        cos, sin = scaled_rope_angles(cfg, pos, a.qk_rope_head_dim)
+        k_pe = apply_rope(kv[..., None, r:], cos, sin)[..., 0, :]
+        return jnp.concatenate([c, k_pe.astype(cdt)], axis=-1)
+
+
+def _mla_wkv_b(cfg, env: AxisEnv, params: Params):
+    """W_kvb as (kv_lora_rank, Hl, nope + v) in the compute dtype."""
+    a, ad = cfg.mla, mla_dims(cfg, env)
+    w = env.gather_fsdp(params["wkv_b"], 0, dtype=jnp.dtype(cfg.compute_dtype))
+    return w.reshape(a.kv_lora_rank, ad.local_heads,
+                     a.qk_nope_head_dim + a.v_head_dim)
+
+
+def _mla_out(cfg, env: AxisEnv, params: Params, o):
+    """o (..., Hl, v) -> partial (..., d) through W_o (row parallel)."""
+    with jax.named_scope("o_proj"):
+        wo = env.gather_fsdp(params["wo"], 1,
+                             dtype=jnp.dtype(cfg.compute_dtype))
+        return o.reshape(*o.shape[:-2], -1) @ wo
+
+
+def _mla_attend(cfg, env: AxisEnv, params: Params, q_nope, q_pe, view,
+                valid):
+    """Absorbed latent attention over cached rows.
+
+    q_nope (B, Q, Hl, nope), q_pe (B, Q, Hl, rope) local heads; view
+    (B, S, 1, latent_width) latent rows (this rank's slice of S); valid
+    (B, Q, S).  Every rank scores all heads against its rows and the
+    (num, den) pair is summed over tp, as for grouped-query attention.
+    Returns (B, Q, Hl, v) in the compute dtype."""
+    a, ad = cfg.mla, mla_dims(cfg, env)
+    cdt = jnp.dtype(cfg.compute_dtype)
+    r, nope = a.kv_lora_rank, a.qk_nope_head_dim
+    w = _mla_wkv_b(cfg, env, params)
+    with jax.named_scope("absorb"):
+        q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w[..., :nope])
+        q_all = env.all_gather_tp(jnp.concatenate([q_lat, q_pe], -1),
+                                  axis=2)                  # (B, Q, Hp, lw)
+    with jax.named_scope("latent_attn"):
+        o_lat = _paged_scores_combine(cfg, env, ad, q_all, view,
+                                      view[..., :r], valid, cdt,
+                                      scale=mla_softmax_scale(cfg))
+        lo = env.tp_index() * ad.local_heads
+        o_lat = jax.lax.dynamic_slice_in_dim(o_lat, lo, ad.local_heads,
+                                             axis=2)
+    with jax.named_scope("absorb"):
+        return jnp.einsum("bqhr,rhv->bqhv", o_lat, w[..., nope:])
+
+
+@jax.named_scope("attention")
+def apply_mla(cfg, env: AxisEnv, params: Params, x: jax.Array, *,
+              schedule: str = "causal", block_target: int = 1024,
+              return_cache: bool = False):
+    """Training/prefill latent attention in the expanded form: per-head K
+    and V from c_kv, through the flash `attention_core`.  x (B, S, d)
+    full per dp-shard.  Returns partial (B, S, d) and, when asked, the
+    tp-local S-slice of the latent cache {"kv": (B, S_loc, 1, lw)}."""
+    a, ad = cfg.mla, mla_dims(cfg, env)
+    B, S, _ = x.shape
+    pos = jnp.arange(S)
+    q_nope, q_pe = _mla_query(cfg, env, params, x, pos)
+    lat = _mla_latent(cfg, env, params, x, pos)            # (B, S, lw)
+    r, nope = a.kv_lora_rank, a.qk_nope_head_dim
+    with jax.named_scope("kv_expand"):
+        kv = jnp.einsum("bsr,rhc->bshc", lat[..., :r],
+                        _mla_wkv_b(cfg, env, params))
+        k_pe = jnp.broadcast_to(lat[:, :, None, r:],
+                                (B, S, ad.local_heads, a.qk_rope_head_dim))
+        k = jnp.concatenate([kv[..., :nope], k_pe], -1)
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    o = attention_core(q, k, kv[..., nope:], causal=True, window=None,
+                       schedule=schedule, block_target=block_target,
+                       scale=mla_softmax_scale(cfg))
+    partial = _mla_out(cfg, env, params, o)
+    if not return_cache:
+        return partial, None
+    s_loc = S // env.tp
+    rows = jax.lax.dynamic_slice_in_dim(lat, env.tp_index() * s_loc, s_loc,
+                                        axis=1)
+    return partial, {"kv": rows[:, :, None]}
+
+
+def init_mla_cache(cfg, env: AxisEnv, batch_local: int, seq_len: int
+                   ) -> Dict[str, jax.Array]:
+    """Dense decode cache of latent rows, S-sharded over tp."""
+    s_loc = max(seq_len // env.tp, 1)
+    return {"kv": jnp.zeros((batch_local, s_loc, 1, cfg.mla.latent_width),
+                            jnp.dtype(cfg.compute_dtype))}
+
+
+@jax.named_scope("attention")
+def mla_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
+                         cache: Dict[str, jax.Array], pos: jax.Array):
+    """Single-token latent decode against the dense S-sharded cache: x
+    (B_loc, d), pos the scalar position.  Writes the token's latent row
+    (owning rank only), then the absorbed form over the cache.  Returns
+    (partial (B_loc, d), cache)."""
+    B = x.shape[0]
+    s_loc = cache["kv"].shape[1]
+    r = env.tp_index()
+    q_nope, q_pe = _mla_query(cfg, env, params, x[:, None], pos[None])
+    row = _mla_latent(cfg, env, params, x, pos)            # (B, lw)
+    with jax.named_scope("kv_compress"):
+        local = jnp.clip(pos - r * s_loc, 0, s_loc - 1)
+        owns = (pos >= r * s_loc) & (pos < (r + 1) * s_loc)
+        cur = jax.lax.dynamic_slice_in_dim(cache["kv"], local, 1, axis=1)
+        new = jnp.where(owns, row[:, None, None], cur)
+        cache = {"kv": jax.lax.dynamic_update_slice_in_dim(
+            cache["kv"], new, local, axis=1)}
+    valid = r * s_loc + jnp.arange(s_loc) <= pos
+    o = _mla_attend(cfg, env, params, q_nope, q_pe, cache["kv"],
+                    jnp.broadcast_to(valid, (B, 1, s_loc)))
+    return _mla_out(cfg, env, params, o[:, 0]), cache
+
+
+def init_paged_mla_pool(cfg, n_pages: int, page_size: int
+                        ) -> Dict[str, jax.Array]:
+    """GLOBAL latent page pool of one layer: one row of
+    kv_lora_rank + qk_rope_head_dim values per token, (n_pages, 1,
+    page_size, lw), laid out and sharded as a one-head KV pool."""
+    return {"kv": jnp.zeros((n_pages, 1, page_size, cfg.mla.latent_width),
+                            jnp.dtype(cfg.compute_dtype))}
+
+
+@jax.named_scope("attention")
+def paged_mla_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
+                        pool: Dict[str, jax.Array], pos: jax.Array,
+                        table: jax.Array, live: jax.Array, *,
+                        page_size: int, valid: Optional[jax.Array] = None):
+    """Latent attention for Q consecutive tokens per row against the
+    latent page pool: decode (Q = 1 per slot), a prefill chunk (one row
+    of C) and spec-decode verify (Q = k + 1 per slot) alike.
+
+    x (B, Q, d) replicated over tp; pos (B, Q) positions; table (B, n_lp)
+    page tables; live (B, Q) lanes whose rows are real (masked lanes
+    write to scratch page 0).  `valid` is the once-per-tick (B, Q, S_g)
+    mask from `paged_valid_mask`.  Returns (partial (B*Q, d), pool)."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    B, Q, _ = x.shape
+    n_lp = table.shape[1]
+    ps_loc = pool["kv"].shape[2]
+    q_nope, q_pe = _mla_query(cfg, env, params, x, pos)
+    row = _mla_latent(cfg, env, params, x, pos)            # (B, Q, lw)
+    with jax.named_scope("kv_compress"):
+        lp = jnp.clip(pos // page_size, 0, n_lp - 1)
+        pp = jnp.take_along_axis(table, lp, axis=1)         # (B, Q)
+        owns = (live & (pp > 0)
+                & ((pos % page_size) // ps_loc == env.tp_index()))
+        pool = _paged_write(pool, {"kv": row[..., None, :]}, pos, pp, owns,
+                            page_size=page_size, env=env, cdt=cdt)
+    if valid is None:
+        valid = paged_valid_mask(table, pos, page_size=page_size,
+                                 ps_loc=ps_loc, env=env)
+    from repro.kernels import ops as kops
+    with jax.named_scope("latent_attn"):
+        view = kops.paged_gather(pool["kv"], table)       # (B, S_g, 1, lw)
+    o = _mla_attend(cfg, env, params, q_nope, q_pe, view, valid)
+    return _mla_out(cfg, env, params, o).reshape(B * Q, -1), pool

@@ -60,6 +60,43 @@ def _ffn_kind(cfg: ModelConfig, layer: int) -> str:
     return "mlp"
 
 
+def layer_stacks(cfg: ModelConfig) -> Tuple[Tuple[str, str, int], ...]:
+    """The stacked layer groups of a uniform-pattern decoder, in depth
+    order: (params key, FFN kind, layers).  An MoE model's leading dense
+    layers (`first_dense_layers`) are a stack of their own,
+    `dense_blocks`, so that each stack scans one layer kind; the rest is
+    `blocks`."""
+    n_d = min(cfg.moe.first_dense_layers, cfg.n_layers) if cfg.moe else 0
+    out = (("dense_blocks", "mlp", n_d),) if n_d else ()
+    if cfg.n_layers > n_d:
+        out += (("blocks", _ffn_kind(cfg, cfg.n_layers - 1),
+                 cfg.n_layers - n_d),)
+    return out
+
+
+def per_stack(cfg: ModelConfig, tree) -> Dict[str, Any]:
+    """{stack key: its stacked caches} of a uniform model's caches or
+    pools, which are the stacked tree itself when there is one stack and
+    a dict by stack key when there are more."""
+    st = layer_stacks(cfg)
+    return tree if len(st) > 1 else {st[0][0]: tree}
+
+
+def from_stacks(cfg: ModelConfig, by_stack: Dict[str, Any]):
+    """Inverse of `per_stack`."""
+    st = layer_stacks(cfg)
+    return by_stack if len(st) > 1 else by_stack[st[0][0]]
+
+
+def _stacked(cfg: ModelConfig, layer_tree):
+    """`layer_tree` (one layer's caches or pools) broadcast over the
+    layers of every stack."""
+    return from_stacks(cfg, {
+        key: jax.tree.map(lambda x, n=n: jnp.broadcast_to(x, (n,) + x.shape),
+                          layer_tree)
+        for key, _, n in layer_stacks(cfg)})
+
+
 # ---------------------------------------------------------------------------
 # per-layer init
 # ---------------------------------------------------------------------------
@@ -73,6 +110,8 @@ def init_block(key, cfg: ModelConfig, env: AxisEnv, kind: str,
     params["norm1"], specs["norm1"] = L.init_norm(cfg, env)
     if kind in ("attn", "swa"):
         params["attn"], specs["attn"] = L.init_attention(ks[0], cfg, env)
+    elif kind == "mla":
+        params["attn"], specs["attn"] = L.init_mla(ks[0], cfg, env)
     elif kind == "rwkv":
         params["tmix"], specs["tmix"] = rwkv_lib.init_time_mix(ks[0], cfg, env)
     elif kind == "rglru":
@@ -126,6 +165,13 @@ def block_forward(cfg: ModelConfig, env: AxisEnv, params, x_sp, *,
             cache["self"] = kv
         partial = partial.reshape(B * S, d)
         state_out = None
+    elif kind == "mla":
+        partial, kv = L.apply_mla(
+            cfg, env, params["attn"], hB, schedule=flags.attn_schedule,
+            block_target=flags.attn_block, return_cache=want_cache)
+        if want_cache:
+            cache["self"] = kv
+        partial = partial.reshape(B * S, d)
     elif kind == "rwkv":
         partial, state_out = rwkv_lib.time_mix(cfg, env, params["tmix"], hB,
                                                chunk=flags.rwkv_chunk)
@@ -172,9 +218,15 @@ def block_forward(cfg: ModelConfig, env: AxisEnv, params, x_sp, *,
             dispatch=flags.moe_dispatch)
         x_sp = x_sp + env.sp_scatter(partial)
     else:
-        partial = L.apply_mlp(cfg, env, params["mlp"], h)
+        partial = _dense_ffn(cfg, env, params["mlp"], h)
         x_sp = x_sp + env.sp_scatter(partial)
     return x_sp, aux, metrics, (cache if want_cache else None)
+
+
+@jax.named_scope("dense_ffn")
+def _dense_ffn(cfg, env: AxisEnv, params, h):
+    """A layer's dense FFN (an MoE model's leading dense layers)."""
+    return L.apply_mlp(cfg, env, params, h)
 
 
 # ---------------------------------------------------------------------------
@@ -190,6 +242,9 @@ def block_decode(cfg, env: AxisEnv, params, x, cache, pos, *, kind: str,
         window = cfg.attn_window if kind == "swa" else None
         partial, cache["self"] = L.decode_attention(
             cfg, env, params["attn"], h, cache["self"], pos, window=window)
+    elif kind == "mla":
+        partial, cache["self"] = L.mla_decode_attention(
+            cfg, env, params["attn"], h, cache["self"], pos)
     elif kind == "rwkv":
         partial, cache["rwkv"] = rwkv_lib.time_mix_decode(
             cfg, env, params["tmix"], h, cache["rwkv"])
@@ -216,7 +271,7 @@ def block_decode(cfg, env: AxisEnv, params, x, cache, pos, *, kind: str,
                                         dispatch=flags.moe_dispatch)
         x = x + env.psum_tp(partial)
     else:
-        x = x + env.psum_tp(L.apply_mlp(cfg, env, params["mlp"], h))
+        x = x + env.psum_tp(_dense_ffn(cfg, env, params["mlp"], h))
     return x, cache
 
 
@@ -226,6 +281,8 @@ def init_block_cache(cfg, env: AxisEnv, kind: str, B_loc: int, seq_len: int,
     if kind in ("attn", "swa"):
         window = cfg.attn_window if kind == "swa" else None
         cache["self"] = L.init_decode_cache(cfg, env, B_loc, seq_len, window)
+    elif kind == "mla":
+        cache["self"] = L.init_mla_cache(cfg, env, B_loc, seq_len)
     elif kind == "rwkv":
         cache["rwkv"] = rwkv_lib.init_decode_state(cfg, env, B_loc)
         cache["cmix_prev"] = jnp.zeros((B_loc, cfg.d_model),
@@ -288,9 +345,10 @@ def init_model(key, cfg: ModelConfig, env: AxisEnv, max_seq: int):
         specs["blocks"] = dec_specs
     elif cfg.uniform_blocks:
         kind = cfg.block_pattern[0]
-        ffn = _ffn_kind(cfg, cfg.n_layers - 1)
-        params["blocks"], specs["blocks"] = stacked(
-            lambda k: init_block(k, cfg, env, kind, ffn), cfg.n_layers, ks[3])
+        for key, ffn, n in layer_stacks(cfg):
+            params[key], specs[key] = stacked(
+                lambda k, ffn=ffn: init_block(k, cfg, env, kind, ffn), n,
+                ks[3] if key == "blocks" else ks[5])
     else:
         blocks, bspecs = [], []
         for i in range(cfg.n_layers):
@@ -329,7 +387,8 @@ def _final_norm(cfg, env: AxisEnv, params, x):
 # projections, the dense and shared MLPs and the routed experts.  Norm
 # scales, the router and the LM head are read in f32.
 _CAST_ON_READ = frozenset(
-    {"wq", "wk", "wv", "wo", "w1", "w2", "w3", "we1", "we2", "we3"})
+    {"wq", "wk", "wv", "wo", "wkv_a", "wkv_b", "w1", "w2", "w3", "we1",
+     "we2", "we3"})
 
 
 def cast_on_read(cfg: ModelConfig, params):
@@ -346,9 +405,10 @@ def cast_on_read(cfg: ModelConfig, params):
     return jax.tree_util.tree_map_with_path(rule, params)
 
 
-def _serve_blocks(cfg: ModelConfig, params):
-    """The stacked layer weights a serve step's layer loop reads, with the
-    routed experts' weights cast to the compute dtype before the loop.
+def _serve_blocks(cfg: ModelConfig, params) -> Dict[str, Any]:
+    """{stack key: the stacked layer weights} that a serve step's layer
+    loops read (`layer_stacks`), with the routed experts' weights cast to
+    the compute dtype before the loop.
     XLA hoists that cast out of the loop anyway, and the op it hoists
     carries no `op_name`; cast here, under `moe_ffn/experts`, it keeps
     its scope in the profiler trace.  The online engine passes the tree
@@ -356,14 +416,33 @@ def _serve_blocks(cfg: ModelConfig, params):
     compute dtype, so there the cast is no op; it stays for callers that
     pass f32 weights straight to a step (the dense `make_decode_step`,
     parity tests, `make_paged_logits` comparisons)."""
-    blocks = params["blocks"]
-    if "moe" not in blocks:
-        return blocks
-    cdt = jnp.dtype(cfg.compute_dtype)
-    with jax.named_scope("moe_ffn"), jax.named_scope("experts"):
-        moe = {k: (v.astype(cdt) if k in ("we1", "we2", "we3") else v)
-               for k, v in blocks["moe"].items()}
-    return {**blocks, "moe": moe}
+    out = {}
+    for key, _, _ in layer_stacks(cfg):
+        blocks = out[key] = params[key]
+        if "moe" not in blocks:
+            continue
+        cdt = jnp.dtype(cfg.compute_dtype)
+        with jax.named_scope("moe_ffn"), jax.named_scope("experts"):
+            moe = {k: (v.astype(cdt) if k in ("we1", "we2", "we3") else v)
+                   for k, v in blocks["moe"].items()}
+        out[key] = {**blocks, "moe": moe}
+    return out
+
+
+def _scan_layers(cfg: ModelConfig, params, x, caches, layer):
+    """Run `layer(lp, x, cache, ffn) -> (x, cache)` over every layer of a
+    uniform model's serve tree, one `lax.scan` per stack in depth order
+    (`layer_stacks`).  Returns (x, caches)."""
+    blocks = _serve_blocks(cfg, params)
+    caches = per_stack(cfg, caches)
+    out = {}
+    for key, ffn, _ in layer_stacks(cfg):
+        def body(x, inp, ffn=ffn):
+            lp, cache = inp
+            return layer(lp, x, cache, ffn)
+
+        x, out[key] = jax.lax.scan(body, x, (blocks[key], caches[key]))
+    return x, from_stacks(cfg, out)
 
 
 def _run_blocks(cfg, env, params, x_sp, *, B, S, step, rng, train, flags,
@@ -371,25 +450,28 @@ def _run_blocks(cfg, env, params, x_sp, *, B, S, step, rng, train, flags,
     aux0 = jnp.zeros((), jnp.float32)
     if cfg.uniform_blocks and not cfg.is_encoder_decoder:
         kind = cfg.block_pattern[0]
-        ffn = _ffn_kind(cfg, cfg.n_layers - 1)
         keys = (jax.random.split(rng, cfg.n_layers) if rng is not None
                 else jnp.zeros((cfg.n_layers, 2), jnp.uint32))
+        carry, caches, metrics, first = (x_sp, aux0), {}, {}, 0
+        for key, ffn, n in layer_stacks(cfg):
+            def body(carry, inp, ffn=ffn):
+                x_sp, aux = carry
+                lp, lk = inp
+                x_sp, a, metrics, cache = block_forward(
+                    cfg, env, lp, x_sp, B=B, S=S, kind=kind, ffn=ffn,
+                    step=step, rng=(lk if rng is not None else None),
+                    train=train, flags=flags, want_cache=want_cache,
+                    enc_out=enc_out)
+                return (x_sp, aux + a), (cache, metrics)
 
-        def body(carry, inp):
-            x_sp, aux = carry
-            lp, lk = inp
-            x_sp, a, metrics, cache = block_forward(
-                cfg, env, lp, x_sp, B=B, S=S, kind=kind, ffn=ffn,
-                step=step, rng=(lk if rng is not None else None),
-                train=train, flags=flags, want_cache=want_cache,
-                enc_out=enc_out)
-            return (x_sp, aux + a), (cache, metrics)
-
-        body_fn = jax.checkpoint(body) if flags.remat else body
-        (x_sp, aux), (caches, metrics) = jax.lax.scan(
-            body_fn, (x_sp, aux0), (params["blocks"], keys))
+            body_fn = jax.checkpoint(body) if flags.remat else body
+            carry, (caches[key], mets) = jax.lax.scan(
+                body_fn, carry, (params[key], keys[first:first + n]))
+            metrics.update(mets)        # MoE stats; a dense stack has none
+            first += n
+        x_sp, aux = carry
         metrics = jax.tree.map(lambda v: jnp.mean(v, axis=0), metrics)
-        return x_sp, aux, metrics, caches
+        return x_sp, aux, metrics, from_stacks(cfg, caches)
     # loop path (mixed patterns / enc-dec)
     aux = aux0
     caches = []
@@ -511,9 +593,8 @@ def init_caches(cfg: ModelConfig, env: AxisEnv, B_loc: int, seq_len: int,
                 cross_len: int = 0):
     if cfg.uniform_blocks and not cfg.is_encoder_decoder:
         kind = cfg.block_pattern[0]
-        c0 = init_block_cache(cfg, env, kind, B_loc, seq_len, cross_len)
-        return jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (cfg.n_layers,) + x.shape), c0)
+        return _stacked(cfg, init_block_cache(cfg, env, kind, B_loc, seq_len,
+                                              cross_len))
     return [init_block_cache(cfg, env, cfg.block_kind(i), B_loc, seq_len,
                              cross_len)
             for i in range(cfg.n_layers)]
@@ -525,62 +606,72 @@ def init_caches(cfg: ModelConfig, env: AxisEnv, B_loc: int, seq_len: int,
 # dense (B, seq_len) decode caches with slot-agnostic page pools indexed by
 # per-slot page tables, so request admission/completion/preemption are pure
 # data updates on fixed-shape arrays — the jitted serve step compiles once.
-# Supported for decoder-only all-attention architectures (the Ling family);
-# recurrent-state blocks (rwkv/rglru), sliding windows, and enc-dec carry
-# per-slot state the page abstraction does not cover yet (ROADMAP).
+# Supported for decoder-only architectures whose every layer is full
+# attention ('attn', a KV pool) or latent attention ('mla', a latent pool
+# of one row per token); recurrent-state blocks (rwkv/rglru), sliding
+# windows, and enc-dec carry per-slot state the page abstraction does not
+# cover yet (ROADMAP).
 
 
 def check_paged_support(cfg: ModelConfig):
     kinds = {cfg.block_kind(i) for i in range(cfg.n_layers)}
-    if kinds != {"attn"} or cfg.is_encoder_decoder:
+    if kinds not in ({"attn"}, {"mla"}) or cfg.is_encoder_decoder:
         raise ValueError(
-            f"paged online serving supports decoder-only all-'attn' "
-            f"architectures; {cfg.arch_id} has blocks {sorted(kinds)}"
+            f"paged online serving supports decoder-only all-'attn' or "
+            f"all-'mla' architectures; {cfg.arch_id} has blocks "
+            f"{sorted(kinds)}"
             f"{' (encoder-decoder)' if cfg.is_encoder_decoder else ''}")
 
 
 def init_paged_caches(cfg: ModelConfig, env: AxisEnv, n_pages: int,
                       page_size: int):
-    """GLOBAL per-layer paged KV pools (page 0 is the engine's scratch
-    page).  Uniform archs carry a leading layer dim so the decode scan
-    matches `init_caches`; see `api.paged_cache_specs` for sharding."""
+    """GLOBAL paged pools of every layer (page 0 is the engine's scratch
+    page), stacked over the layers of each stack as `init_caches` is:
+    a KV pool {"k", "v"} per 'attn' layer, a latent pool {"kv"} per
+    'mla' layer.  See `api.paged_cache_specs` for sharding."""
     check_paged_support(cfg)
-    c0 = {"self": L.init_paged_kv_pool(cfg, n_pages, page_size)}
-    if cfg.uniform_blocks:
-        return jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (cfg.n_layers,) + x.shape), c0)
-    return [jax.tree.map(jnp.array, c0) for _ in range(cfg.n_layers)]
+    pool = (L.init_paged_mla_pool if cfg.mla is not None
+            else L.init_paged_kv_pool)(cfg, n_pages, page_size)
+    return _stacked(cfg, {"self": pool})
 
 
 def _pool_ps_loc(cfg, pools) -> int:
-    """Per-rank page row count of the serve-step pools (uniform pools
-    carry a leading layer dim)."""
-    pool0 = pools["self"] if cfg.uniform_blocks else pools[0]["self"]
-    k = pool0["k"]
-    return k.shape[3] if cfg.uniform_blocks else k.shape[2]
+    """Per-rank page row count of the serve-step pools (each leaf is
+    (layers, n_pages, KV, ps_loc, width))."""
+    return jax.tree.leaves(pools)[0].shape[3]
 
 
 def block_decode_paged(cfg, env: AxisEnv, params, x, pool, pos, table,
                        active, *, page_size: int, ffn: str,
                        flags: RunFlags = DEFAULT_FLAGS, valid=None):
-    """Paged analogue of `block_decode` ('attn' blocks only): x (B, d)
-    replicated over tp, pool the layer's page pool.  `valid` is the
-    once-per-tick layer-invariant page mask (layers.paged_valid_mask)."""
+    """Paged analogue of `block_decode`: x (B, d) replicated over tp, pool
+    the layer's page pool.  `valid` is the once-per-tick layer-invariant
+    page mask (layers.paged_valid_mask)."""
     h = L.apply_norm(cfg, env, params["norm1"], x)
-    partial, pool["self"] = L.paged_decode_attention(
-        cfg, env, params["attn"], h, pool["self"], pos, table, active,
-        page_size=page_size, paged_attn=flags.paged_attn, valid=valid)
+    if cfg.mla is not None:
+        partial, pool["self"] = L.paged_mla_attention(
+            cfg, env, params["attn"], h[:, None], pool["self"],
+            pos[:, None], table, active[:, None], page_size=page_size,
+            valid=valid)
+    else:
+        partial, pool["self"] = L.paged_decode_attention(
+            cfg, env, params["attn"], h, pool["self"], pos, table, active,
+            page_size=page_size, paged_attn=flags.paged_attn, valid=valid)
     x = x + env.psum_tp(partial)
+    return _ffn_sublayer(cfg, env, params, x, ffn, flags), pool
 
+
+def _ffn_sublayer(cfg, env: AxisEnv, params, x, ffn: str,
+                  flags: RunFlags):
+    """Second half of a serve-step layer: norm, then the MoE or dense FFN,
+    added to the residual."""
     h = L.apply_norm(cfg, env, params["norm2"], x)
     if ffn == "moe":
         partial, _, _ = moe_lib.moe_ffn(cfg, env, params["moe"], h,
                                         train=False,
                                         dispatch=flags.moe_dispatch)
-        x = x + env.psum_tp(partial)
-    else:
-        x = x + env.psum_tp(L.apply_mlp(cfg, env, params["mlp"], h))
-    return x, pool
+        return x + env.psum_tp(partial)
+    return x + env.psum_tp(_dense_ffn(cfg, env, params["mlp"], h))
 
 
 def _paged_decode_logits(cfg: ModelConfig, denv: AxisEnv, params, pools,
@@ -589,31 +680,15 @@ def _paged_decode_logits(cfg: ModelConfig, denv: AxisEnv, params, pools,
                          flags: RunFlags = DEFAULT_FLAGS):
     """Shared paged-decode body: one token per slot -> (logits, pools)."""
     x = emb.embed_tokens(cfg, denv, params["embed"], token)   # (B, d)
-    ffn = _ffn_kind(cfg, cfg.n_layers - 1)
     # page-validity mask is identical across layers: compute once per
     # tick here instead of per layer inside the attention entry points.
     valid = L.paged_valid_mask(table, pos[:, None], page_size=page_size,
                                ps_loc=_pool_ps_loc(cfg, pools), env=denv)
-
-    if cfg.uniform_blocks:
-        def body(x, inp):
-            lp, pool = inp
-            x, pool = block_decode_paged(cfg, denv, lp, x, pool, pos,
-                                         table, active,
-                                         page_size=page_size, ffn=ffn,
-                                         flags=flags, valid=valid)
-            return x, pool
-
-        x, pools = jax.lax.scan(body, x, (_serve_blocks(cfg, params), pools))
-    else:
-        new_pools = []
-        for i, lp in enumerate(params["blocks"]):
-            x, p = block_decode_paged(cfg, denv, lp, x, pools[i], pos,
-                                      table, active, page_size=page_size,
-                                      ffn=_ffn_kind(cfg, i), flags=flags,
-                                      valid=valid)
-            new_pools.append(p)
-        pools = new_pools
+    x, pools = _scan_layers(
+        cfg, params, x, pools,
+        lambda lp, x, pool, ffn: block_decode_paged(
+            cfg, denv, lp, x, pool, pos, table, active, page_size=page_size,
+            ffn=ffn, flags=flags, valid=valid))
     x = _final_norm(cfg, denv, params, x)
     return emb.lm_logits(cfg, denv, params["embed"], x), pools
 
@@ -700,21 +775,40 @@ def block_verify_paged(cfg, env: AxisEnv, params, x, pool, pos, table,
                        flags: RunFlags = DEFAULT_FLAGS, valid=None):
     """One layer of the k+1-token verify pass: x (B*Q, d)."""
     h = L.apply_norm(cfg, env, params["norm1"], x)
-    partial, pool["self"] = L.paged_verify_attention(
-        cfg, env, params["attn"], h.reshape(B, Q, -1), pool["self"], pos,
-        table, active, page_size=page_size, paged_attn=flags.paged_attn,
-        valid=valid)
-    x = x + env.psum_tp(partial)
-
-    h = L.apply_norm(cfg, env, params["norm2"], x)
-    if ffn == "moe":
-        partial, _, _ = moe_lib.moe_ffn(cfg, env, params["moe"], h,
-                                        train=False,
-                                        dispatch=flags.moe_dispatch)
-        x = x + env.psum_tp(partial)
+    if cfg.mla is not None:
+        partial, pool["self"] = L.paged_mla_attention(
+            cfg, env, params["attn"], h.reshape(B, Q, -1), pool["self"],
+            pos, table, jnp.broadcast_to(active[:, None], (B, Q)),
+            page_size=page_size, valid=valid)
     else:
-        x = x + env.psum_tp(L.apply_mlp(cfg, env, params["mlp"], h))
-    return x, pool
+        partial, pool["self"] = L.paged_verify_attention(
+            cfg, env, params["attn"], h.reshape(B, Q, -1), pool["self"],
+            pos, table, active, page_size=page_size,
+            paged_attn=flags.paged_attn, valid=valid)
+    x = x + env.psum_tp(partial)
+    return _ffn_sublayer(cfg, env, params, x, ffn, flags), pool
+
+
+def paged_verify_logits(cfg: ModelConfig, env: AxisEnv, params, pools,
+                        tokens: jax.Array, pos0: jax.Array, table: jax.Array,
+                        active: jax.Array, *, page_size: int,
+                        flags: RunFlags = DEFAULT_FLAGS):
+    """The verify pass of `paged_verify_step`: tokens (B, K+1) at
+    positions pos0 + 0..K through every layer, writing their KV.
+    Returns (logits (B*(K+1), v_loc) float32, pools)."""
+    denv = dataclasses.replace(env, seq_parallel=False)
+    B, K1 = tokens.shape
+    pos = pos0[:, None] + jnp.arange(K1)[None, :]          # (B, K1)
+    x = emb.embed_tokens(cfg, denv, params["embed"], tokens.reshape(-1))
+    valid = L.paged_valid_mask(table, pos, page_size=page_size,
+                               ps_loc=_pool_ps_loc(cfg, pools), env=denv)
+    x, pools = _scan_layers(
+        cfg, params, x, pools,
+        lambda lp, x, pool, ffn: block_verify_paged(
+            cfg, denv, lp, x, pool, pos, table, active, B=B, Q=K1,
+            page_size=page_size, ffn=ffn, flags=flags, valid=valid))
+    x = _final_norm(cfg, denv, params, x)
+    return emb.lm_logits(cfg, denv, params["embed"], x), pools
 
 
 def paged_verify_step(cfg: ModelConfig, env: AxisEnv, params, pools,
@@ -738,33 +832,9 @@ def paged_verify_step(cfg: ModelConfig, env: AxisEnv, params, pools,
     K = K1 - 1
     seeds, temp, top_p, top_k = sample
     pos = pos0[:, None] + jnp.arange(K1)[None, :]          # (B, K1)
-
-    x = emb.embed_tokens(cfg, denv, params["embed"], tokens.reshape(-1))
-    ffn = _ffn_kind(cfg, cfg.n_layers - 1)
-    valid = L.paged_valid_mask(table, pos, page_size=page_size,
-                               ps_loc=_pool_ps_loc(cfg, pools), env=denv)
-    if cfg.uniform_blocks:
-        def body(x, inp):
-            lp, pool = inp
-            x, pool = block_verify_paged(cfg, denv, lp, x, pool, pos,
-                                         table, active, B=B, Q=K1,
-                                         page_size=page_size, ffn=ffn,
-                                         flags=flags, valid=valid)
-            return x, pool
-
-        x, pools = jax.lax.scan(body, x, (_serve_blocks(cfg, params), pools))
-    else:
-        new_pools = []
-        for i, lp in enumerate(params["blocks"]):
-            x, p = block_verify_paged(cfg, denv, lp, x, pools[i], pos,
-                                      table, active, B=B, Q=K1,
-                                      page_size=page_size,
-                                      ffn=_ffn_kind(cfg, i), flags=flags,
-                                      valid=valid)
-            new_pools.append(p)
-        pools = new_pools
-    x = _final_norm(cfg, denv, params, x)
-    logits = emb.lm_logits(cfg, denv, params["embed"], x)  # (B*K1, v_loc)
+    logits, pools = paged_verify_logits(
+        cfg, env, params, pools, tokens, pos0, table, active,
+        page_size=page_size, flags=flags)                  # (B*K1, v_loc)
 
     rep = lambda a: jnp.repeat(a, K1, axis=0)
     greedy, probs = emb.sampled_probs(cfg, denv, logits, rep(temp),
@@ -812,21 +882,20 @@ def block_prefill_paged(cfg, env: AxisEnv, params, x, pool, base, n_valid,
                         flags: RunFlags = DEFAULT_FLAGS, valid=None):
     """One layer of chunked prefill for a single request: x (C, d)."""
     h = L.apply_norm(cfg, env, params["norm1"], x)
-    partial, pool["self"] = L.paged_prefill_attention(
-        cfg, env, params["attn"], h, pool["self"], base, n_valid,
-        table_row, page_size=page_size, paged_attn=flags.paged_attn,
-        valid=valid)
-    x = x + env.psum_tp(partial)
-
-    h = L.apply_norm(cfg, env, params["norm2"], x)
-    if ffn == "moe":
-        partial, _, _ = moe_lib.moe_ffn(cfg, env, params["moe"], h,
-                                        train=False,
-                                        dispatch=flags.moe_dispatch)
-        x = x + env.psum_tp(partial)
+    if cfg.mla is not None:
+        C = x.shape[0]
+        partial, pool["self"] = L.paged_mla_attention(
+            cfg, env, params["attn"], h[None], pool["self"],
+            (base + jnp.arange(C))[None], table_row[None],
+            (jnp.arange(C) < n_valid)[None], page_size=page_size,
+            valid=valid)
     else:
-        x = x + env.psum_tp(L.apply_mlp(cfg, env, params["mlp"], h))
-    return x, pool
+        partial, pool["self"] = L.paged_prefill_attention(
+            cfg, env, params["attn"], h, pool["self"], base, n_valid,
+            table_row, page_size=page_size, paged_attn=flags.paged_attn,
+            valid=valid)
+    x = x + env.psum_tp(partial)
+    return _ffn_sublayer(cfg, env, params, x, ffn, flags), pool
 
 
 def _paged_prefill_hidden(cfg: ModelConfig, denv: AxisEnv, params, pools,
@@ -836,31 +905,14 @@ def _paged_prefill_hidden(cfg: ModelConfig, denv: AxisEnv, params, pools,
     """Shared chunked-prefill body: the chunk -> (final-norm hidden
     states (C, d), pools)."""
     x = emb.embed_tokens(cfg, denv, params["embed"], tokens)  # (C, d)
-    ffn = _ffn_kind(cfg, cfg.n_layers - 1)
     valid = L.paged_valid_mask(
         table_row[None], (base + jnp.arange(tokens.shape[0]))[None],
         page_size=page_size, ps_loc=_pool_ps_loc(cfg, pools), env=denv)
-
-    if cfg.uniform_blocks:
-        def body(x, inp):
-            lp, pool = inp
-            x, pool = block_prefill_paged(cfg, denv, lp, x, pool, base,
-                                          n_valid, table_row,
-                                          page_size=page_size, ffn=ffn,
-                                          flags=flags, valid=valid)
-            return x, pool
-
-        x, pools = jax.lax.scan(body, x, (_serve_blocks(cfg, params), pools))
-    else:
-        new_pools = []
-        for i, lp in enumerate(params["blocks"]):
-            x, p = block_prefill_paged(cfg, denv, lp, x, pools[i], base,
-                                       n_valid, table_row,
-                                       page_size=page_size,
-                                       ffn=_ffn_kind(cfg, i), flags=flags,
-                                       valid=valid)
-            new_pools.append(p)
-        pools = new_pools
+    x, pools = _scan_layers(
+        cfg, params, x, pools,
+        lambda lp, x, pool, ffn: block_prefill_paged(
+            cfg, denv, lp, x, pool, base, n_valid, table_row,
+            page_size=page_size, ffn=ffn, flags=flags, valid=valid))
     return _final_norm(cfg, denv, params, x), pools
 
 
@@ -940,16 +992,11 @@ def decode_step(cfg: ModelConfig, env: AxisEnv, params, caches,
 
     if cfg.uniform_blocks and not cfg.is_encoder_decoder:
         kind = cfg.block_pattern[0]
-        ffn = _ffn_kind(cfg, cfg.n_layers - 1)
-
-        def body(x, inp):
-            lp, cache = inp
-            x, cache = block_decode(cfg, denv, lp, x, cache, pos,
-                                    kind=kind, ffn=ffn, flags=flags)
-            return x, cache
-
-        x, caches = jax.lax.scan(body, x,
-                                 (_serve_blocks(cfg, params), caches))
+        x, caches = _scan_layers(
+            cfg, params, x, caches,
+            lambda lp, x, cache, ffn: block_decode(
+                cfg, denv, lp, x, cache, pos, kind=kind, ffn=ffn,
+                flags=flags))
     else:
         new_caches = []
         for i, lp in enumerate(params["blocks"]):

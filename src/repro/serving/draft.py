@@ -68,14 +68,12 @@ class SelfDrafter:
                              f"[1, {cfg.n_layers}] for {cfg.arch_id}")
         dcfg = dataclasses.replace(cfg, n_layers=L)
         M.check_paged_support(dcfg)
-        blocks = params["blocks"]
-        if isinstance(blocks, list):
-            dblocks = blocks[:L]
-        else:                        # uniform arch: stacked leading layer dim
-            dblocks = jax.tree.map(lambda x: x[:L], blocks)
         dparams = {"embed": params["embed"],
-                   "final_norm": params["final_norm"],
-                   "blocks": dblocks}
+                   "final_norm": params["final_norm"]}
+        # the first L layers of the stacks, in depth order (a paged arch
+        # stacks its layers: M.layer_stacks)
+        for key, _, n in M.layer_stacks(dcfg):
+            dparams[key] = jax.tree.map(lambda x, n=n: x[:n], params[key])
         return _draft_runner(dcfg, runner), dparams
 
 

@@ -433,6 +433,11 @@ class OnlineEngine:
         for dt, n in by_dtype.items():
             reg.gauge("serve_weight_bytes", "bytes of the weights the serve "
                       "steps read, by dtype", dtype=dt).set(n)
+        pool_bytes = sum(a.nbytes for a in jax.tree.leaves(self.pools))
+        reg.gauge("serve_kv_bytes_per_token", "cache bytes one token holds "
+                  "over all layers: K and V, or one latent row",
+                  layout="latent" if runner.cfg.mla is not None else "kv"
+                  ).set(pool_bytes // (n_pages * cfg.page_size))
         # per-tick samples -> Perfetto counter tracks (trace_export)
         self._s_pages = reg.series("page_pool_occupancy")
         self._s_queue = reg.series("queue_depth")

@@ -20,6 +20,7 @@ progress as segments free.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
@@ -326,6 +327,13 @@ class PageAllocator:
         # node holds one refcount on its page
         self.radix_root = RadixNode(key=(), page=-1, parent=None,
                                     node_id=0)
+        self._node_of: Dict[int, RadixNode] = {}   # cached page -> node
+        # eviction candidates (last_used, node_id, node), checked when
+        # popped: an entry is pushed whenever a node's stamp changes or it
+        # may have become an unreferenced leaf, so every evictable node
+        # has a current entry and an eviction costs O(log n), not a sweep
+        # of the trie per page
+        self._lru: List[Tuple[int, int, RadixNode]] = []
         self._clock = 0                # LRU timestamp (bumped per op)
         self._next_node_id = 1
         self.stats = {"allocs": 0, "frees": 0, "prefix_hits": 0,
@@ -414,6 +422,7 @@ class PageAllocator:
                                   node_id=self._next_node_id)
                 self._next_node_id += 1
                 node.children[key] = child
+                self._node_of[pages[i]] = child
                 self.refcount[pages[i]] += 1
                 new += 1
                 self.stats["published"] += 1
@@ -423,13 +432,37 @@ class PageAllocator:
                 # addressed): keep the cached copy, the request's private
                 # duplicate recycles normally on release
                 self.stats["dedups"] += 1
-            child.last_used = self._clock
+            self._stamp(child)
             node = child
         return new
 
+    def _stamp(self, node: RadixNode):
+        node.last_used = self._clock
+        self._candidate(node)
+
+    def _candidate(self, node: RadixNode):
+        """Queue `node` for eviction under its current stamp; stale and
+        not-yet-evictable entries are dropped when popped.  The queue is
+        rebuilt from the evictable leaves when stale entries outnumber
+        the cached pages."""
+        heapq.heappush(self._lru, (node.last_used, node.node_id, node))
+        if len(self._lru) > 4 * len(self._node_of) + 1024:
+            self._lru = [(n.last_used, n.node_id, n)
+                         for n in self._node_of.values()
+                         if self._evictable(n)]
+            heapq.heapify(self._lru)
+
+    def _evictable(self, node: RadixNode) -> bool:
+        return (self._node_of.get(node.page) is node and not node.children
+                and self.refcount[node.page] == 1)
+
     def _drop_node(self, node: RadixNode):
-        del node.parent.children[node.key]
+        parent = node.parent
+        del parent.children[node.key]
+        del self._node_of[node.page]
         self._free_page_ref(node.page)
+        if parent is not self.radix_root and not parent.children:
+            self._candidate(parent)
 
     def evict_radix(self, n: int) -> int:
         """Evict up to `n` unreferenced cached pages, deterministic
@@ -439,17 +472,10 @@ class PageAllocator:
         nodes become evictable as their subtrees drain, so a cold chain
         dies tail-first while its hot prefix survives."""
         freed = 0
-        while freed < n:
-            best = None
-            for node in self._iter_radix():
-                if node.children or self.refcount[node.page] != 1:
-                    continue
-                if (best is None
-                        or (node.last_used, node.node_id)
-                        < (best.last_used, best.node_id)):
-                    best = node
-            if best is None:
-                return freed
+        while freed < n and self._lru:
+            stamp, _, best = heapq.heappop(self._lru)
+            if best.last_used != stamp or not self._evictable(best):
+                continue
             evicted_page = best.page
             self._drop_node(best)
             freed += 1
@@ -463,9 +489,11 @@ class PageAllocator:
         requests survive until those release).  Returns nodes dropped."""
         n = 0
         for node in list(self._iter_radix()):
+            del self._node_of[node.page]
             self._free_page_ref(node.page)
             n += 1
         self.radix_root.children.clear()
+        self._lru = []
         return n
 
     # -- admission ------------------------------------------------------------
@@ -495,7 +523,7 @@ class PageAllocator:
             self._clock += 1
             for node in matched:
                 self.refcount[node.page] += 1
-                node.last_used = self._clock
+                self._stamp(node)
             self.pages[rid] = [n.page for n in matched]
             self.shared_len[rid] = len(matched) * self.page_size
             if matched:
@@ -578,6 +606,8 @@ class PageAllocator:
             del self.refcount[p]
             self.free_list.append(p)
             self.stats["frees"] += 1
+        elif self.refcount[p] == 1 and p in self._node_of:
+            self._candidate(self._node_of[p])
 
     def release(self, rid: int, tokens=None):
         """Free a finished request's pages (shared prefix pages survive

@@ -21,7 +21,8 @@ B, S_PROMPT, S_MAX = 2, 16, 64
 
 
 @pytest.mark.parametrize("arch", ["phi3-mini-3.8b", "h2o-danube-1.8b",
-                                  "rwkv6-3b", "deepseek-moe-16b"])
+                                  "rwkv6-3b", "deepseek-moe-16b",
+                                  "deepseek-v2-lite"])
 def test_prefill_then_decode_matches_stepwise(arch):
     cfg = get_smoke_config(arch)
     mesh = make_local_mesh(1, 1)

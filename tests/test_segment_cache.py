@@ -356,14 +356,14 @@ def test_radix_eviction_mid_attach_adversarial():
 
 
 def _radix_churn(seed: int, n_ops: int = 300, n_pages: int = 17,
-                 page_size: int = 4):
+                 page_size: int = 4, allocator=PageAllocator):
     """One deterministic radix-churn run mirroring engine usage: admit
     with content tokens (hot prefixes collide), grow, emit, publish on
     release/preempt, sweep under pressure, occasional flush.  Invariants
     after EVERY op plus explicit page accounting.  Returns a trace for
     replay comparison."""
     rs = np.random.RandomState(seed)
-    a = PageAllocator(n_pages=n_pages, page_size=page_size)
+    a = allocator(n_pages=n_pages, page_size=page_size)
     hot = [list(rs.randint(0, 7, 8)), list(rs.randint(0, 7, 12)), []]
     live, trace, next_rid = {}, [], 0
 
@@ -434,6 +434,38 @@ def test_radix_churn_stress_and_replay():
         assert a.stats["evictions"] > 0, "churn never swept"
         _, trace2 = _radix_churn(seed)
         assert trace2 == trace, f"seed {seed} replay diverged"
+
+
+class _SweepAllocator(PageAllocator):
+    """The eviction rule written as a sweep: each page evicted is the
+    least-recently-used (node_id breaking ties) childless cached page no
+    one else references, found by walking the whole trie."""
+
+    def evict_radix(self, n: int) -> int:
+        freed = 0
+        while freed < n:
+            leaves = [node for node in self._iter_radix()
+                      if not node.children and self.refcount[node.page] == 1]
+            if not leaves:
+                break
+            best = min(leaves, key=lambda x: (x.last_used, x.node_id))
+            self._drop_node(best)
+            freed += 1
+            self.stats["evictions"] += 1
+        return freed
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_radix_eviction_order_is_the_sweeps(seed):
+    """The queued eviction picks the same pages, in the same order, as a
+    full sweep of the trie for every page: identical churn traces (page
+    tables, stats and free-list order) on pools small enough to evict
+    often."""
+    for n_pages in (9, 17):
+        _, fast = _radix_churn(seed, n_pages=n_pages)
+        _, sweep = _radix_churn(seed, n_pages=n_pages,
+                                allocator=_SweepAllocator)
+        assert fast == sweep
 
 
 def test_radix_ensure_capacity_evicts_before_failing():

@@ -72,7 +72,8 @@ def test_decode_steps(arch, mesh):
     assert ((tok >= 0) & (tok < cfg.vocab_size)).all(), tok
 
 
-@pytest.mark.parametrize("arch", ["deepseek-moe-16b", "ling-lite"])
+@pytest.mark.parametrize("arch", ["deepseek-moe-16b", "ling-lite",
+                                  "deepseek-v2-lite"])
 def test_loss_decreases(arch, mesh):
     """Overfit one tiny batch for a few steps — loss must drop."""
     cfg = get_smoke_config(arch)

@@ -12,6 +12,7 @@ import pytest
 from repro import api
 from repro.configs.base import get_smoke_config
 from repro.launch.mesh import make_local_mesh
+from repro.models.model import RunFlags
 from repro.serving.draft import (ConfigDrafter, SelfDrafter,
                                  adapt_drafter_config)
 from repro.serving.online import OnlineConfig, OnlineEngine, OnlineRequest
@@ -263,3 +264,41 @@ def test_page_allocator_trim():
     alloc.drop_prefix("sys")
     alloc.check_invariants()
     assert alloc.n_free == alloc.n_pages - alloc.reserved
+
+
+def _served(runner, params, prompts, drafter=None, **kw):
+    eng = OnlineEngine(runner, params, OnlineConfig(
+        max_slots=len(prompts), max_context=64, page_size=16,
+        prefill_chunk=8, **kw), drafter=drafter)
+    eng.submit_many([OnlineRequest(rid=i, prompt=p, max_new=6)
+                     for i, p in enumerate(prompts)])
+    eng.run(max_ticks=500)
+    return eng, [list(eng.reqs[i].out) for i in range(len(prompts))]
+
+
+@pytest.fixture(scope="module")
+def latent_case():
+    """DeepSeek-V2-Lite smoke: a dense first layer, then latent-attention
+    MoE layers over the latent page pool.  Experts through `ragged`, the
+    dispatch `auto` gives on a TPU."""
+    runner = api.Runner(get_smoke_config("deepseek-v2-lite"),
+                        make_local_mesh(1, 1), fsdp=False,
+                        seq_parallel=False, max_seq=64,
+                        flags=RunFlags(moe_dispatch="ragged"))
+    params = runner.init_params(0)
+    rs = np.random.RandomState(1)
+    prompts = [rs.randint(0, 512, n).astype(np.int32) for n in (5, 14)]
+    return runner, params, prompts, _served(runner, params, prompts)[1]
+
+
+@pytest.mark.parametrize("draft_layers", [1, 2])
+def test_latent_attention_spec_greedy_token_exact(latent_case, draft_layers):
+    """Greedy speculative decoding with a drafter cut to the dense layer
+    alone (1) or to the dense layer and one latent MoE layer (2) gives
+    the tokens of plain greedy decode."""
+    runner, params, prompts, want = latent_case
+    eng, got = _served(runner, params, prompts, spec_k=2,
+                       drafter=SelfDrafter(draft_layers=draft_layers))
+    assert got == want
+    assert eng.verify_traces == 1 and eng.decode_traces == 0
+    assert eng.spec_proposed > 0
