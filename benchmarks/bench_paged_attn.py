@@ -27,11 +27,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _decode_case(rs, *, B, n_lp, page_size, Hp, KV, hd, page_counts,
                  dtype=jnp.bfloat16):
     """Decode-shaped (Q=1) paged batch with an uneven allocation
-    profile; tp=1 so ps_loc == page_size."""
+    profile; tp=1 so ps_loc == page_size; the pools of one layer, as a
+    stack of one."""
     n_pages = 1 + sum(page_counts)
     q = jnp.asarray(rs.randn(B, 1, Hp, hd), dtype)
-    kp = jnp.asarray(rs.randn(n_pages, KV, page_size, hd), dtype)
-    vp = jnp.asarray(rs.randn(n_pages, KV, page_size, hd), dtype)
+    kp = jnp.asarray(rs.randn(1, n_pages, page_size, KV, hd), dtype)
+    vp = jnp.asarray(rs.randn(1, n_pages, page_size, KV, hd), dtype)
     table = np.zeros((B, n_lp), np.int32)
     nxt = 1
     pos = np.zeros((B,), np.int32)
@@ -53,10 +54,10 @@ def _gathered_attn(q, kp, vp, table, mask):
     grouped-einsum scores, softmax, PV contraction (the same math
     `_paged_scores_combine` runs per layer)."""
     B, Qn, Hp, hd = q.shape
-    KV = kp.shape[1]
+    KV = kp.shape[3]
     g = Hp // KV
-    k_g = ops.paged_gather(kp, table)
-    v_g = ops.paged_gather(vp, table)
+    k_g = ops.paged_gather(kp, table, 0)
+    v_g = ops.paged_gather(vp, table, 0)
     S_g = k_g.shape[1]
     s = jnp.einsum("bqkgd,bskd->bqkgs", q.reshape(B, Qn, KV, g, hd), k_g,
                    preferred_element_type=jnp.float32) * hd ** -0.5
@@ -74,10 +75,10 @@ def _gathered_attn(q, kp, vp, table, mask):
 
 @jax.jit
 def _fused_attn(q, kp, vp, table, mask):
-    m = ops.paged_attention_scores_max(q, kp, table, mask)
+    m = ops.paged_attention_scores_max(q, kp, table, mask, 0)
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
     num, den = ops.paged_attention_accumulate(q, kp, vp, table, mask,
-                                              m_safe)
+                                              m_safe, 0)
     return num / jnp.maximum(den, 1e-20)[..., None]
 
 

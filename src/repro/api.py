@@ -631,18 +631,20 @@ def globalize_shapes(shape_tree, spec_tree, mesh_sizes):
 def paged_cache_specs(cfg, env: AxisEnv):
     """PartitionSpecs for the paged KV pools (serving/online.py).
 
-    Pool layout per layer: (n_pages, KV, page_size, width) — K and V of
-    each head, or one latent row for latent attention — with the
-    page_size dim sharded over tp (each rank stores ps_loc = page_size/tp
-    offsets of every page), behind the leading layer dim of each stack
-    (`M.init_paged_caches`).  Pages are slot-agnostic and may be
-    referenced by several page tables at once — refcounted prefix sharing
-    and the cross-request radix cache retain a page across requests; the
-    pools themselves never change shape for it."""
-    spec = P(None, None, None, env.tp_axis, None)
+    Pool layout per layer: (n_pages, page_size, KV, hd) — K and V of
+    each head — or (n_pages, page_size, width), one latent row for latent
+    attention, with the page_size dim sharded over tp (each rank stores
+    ps_loc = page_size/tp offsets of every page), behind the leading
+    layer dim of each stack (`M.init_paged_caches`).  Pages are
+    slot-agnostic and may be referenced by several page tables at once —
+    refcounted prefix sharing and the cross-request radix cache retain a
+    page across requests; the pools themselves never change shape for
+    it."""
     pools = jax.eval_shape(
         lambda: M.init_paged_caches(cfg, env, 2, env.tp))
-    return jax.tree.map(lambda _: spec, pools)
+    return jax.tree.map(
+        lambda a: P(None, None, env.tp_axis, *(None,) * (a.ndim - 3)),
+        pools)
 
 
 def cache_partition_specs(cfg, env: AxisEnv, cache_tree, b):

@@ -146,17 +146,22 @@ def moe_fused_ffn(x, w1, w2, w3, tok, gate, group_sizes, *,
                              act=act, bf=bf_, interpret=interpret)
 
 
-def paged_gather(pool, table):
+def paged_gather(pool, table, layer):
     """Gather KV pages for paged-attention decode (serving/online.py).
 
-    pool (n_pages, KV, ps_loc, hd) is a device-resident page pool whose
-    in-page offset dim is the tp-local slice of the global page_size;
-    table (B, n_lp) holds the physical page id backing each logical page
-    (0 = the reserved scratch page, which doubles as the "unallocated"
-    sentinel — callers mask those positions).  Returns the dense
-    (B, n_lp * ps_loc, KV, hd) view of each slot's logical KV sequence
-    in logical-page order, the layout the standard decode-attention
-    einsums consume unchanged.
+    pool (L, n_pages, ps_loc, *row) is a device-resident page pool
+    stacked over the layers of one layer stack — a row (KV, hd) of a KV
+    pool or (lw,) of a latent pool — whose in-page offset dim is the
+    tp-local slice of the global page_size; layer (scalar int32)
+    picks the layer; table (B, n_lp) holds the physical page id backing
+    each logical page (0 = the reserved scratch page, which doubles as
+    the "unallocated" sentinel — callers mask those positions).  Returns
+    the dense (B, n_lp * ps_loc, *row) view of each slot's logical KV
+    sequence in logical-page order, the layout the standard
+    decode-attention einsums consume unchanged.  One gather reads the
+    pages straight out of the stack: no view of the layer's pool is
+    made first, and page ids are in bounds by construction, so the
+    gather needs no out-of-bounds fill.
 
     This materializes the full table-width view in HBM once per layer
     per tick.  It backs the "gathered" paged-attention mode (the parity
@@ -164,9 +169,8 @@ def paged_gather(pool, table):
     inside the attention kernel instead, so this view never exists.
     """
     B, n_lp = table.shape
-    _, KV, ps_loc, hd = pool.shape
-    g = jnp.take(pool, table, axis=0)           # (B, n_lp, KV, ps_loc, hd)
-    return g.swapaxes(2, 3).reshape(B, n_lp * ps_loc, KV, hd)
+    g = pool[layer, table]                      # (B, n_lp, ps_loc, *row)
+    return g.reshape((B, n_lp * pool.shape[2]) + pool.shape[3:])
 
 
 def _pa_group_q(q, KV):
@@ -197,18 +201,19 @@ def _pa_mask(mask, k_pool, table):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention_scores_max(q, k_pool, table, mask, *,
+def paged_attention_scores_max(q, k_pool, table, mask, layer, *,
                                interpret: bool | None = None):
     """Pass 1 of fused paged attention: per-rank row max of the masked
     scores, page table walked in-kernel (kernels/paged_attn.py).
 
     q (B, Q, Hp, hd): query-batched heads — Q=1 decode, Q=C chunked
-    prefill, Q=k+1 spec-decode verify;  k_pool (n_pages, KV, ps_loc, hd):
-    the layer's K page pool (ps_loc = this tp rank's row-slice of each
-    page);  table (B, n_lp) int32 physical page per logical page (0 =
-    scratch/unallocated — rows must be masked);  mask (B, Q, S_g) bool
-    with S_g = n_lp * ps_loc: page-valid & causal validity per query
-    (models/layers.py::paged_valid_mask).
+    prefill, Q=k+1 spec-decode verify;  k_pool (L, n_pages, ps_loc, KV,
+    hd): the K page pools of a layer stack (ps_loc = this tp rank's
+    row-slice of each page);  table (B, n_lp) int32 physical page per
+    logical page (0 = scratch/unallocated — rows must be masked);  mask
+    (B, Q, S_g) bool with S_g = n_lp * ps_loc: page-valid & causal
+    validity per query (models/layers.py::paged_valid_mask);  layer
+    (scalar int32) the layer whose pages the kernel reads.
 
     Requires the grouped GQA layout: Hp % KV == 0 with head h belonging
     to kv head h // (Hp // KV) — the same precondition as the gathered
@@ -222,16 +227,16 @@ def paged_attention_scores_max(q, k_pool, table, mask, *,
     exactly like the gathered oracle.
     """
     interpret = interpret_default() if interpret is None else interpret
-    KV = k_pool.shape[1]
+    KV = k_pool.shape[3]
     m = _pa.paged_attn_scores_max(
         _pa_group_q(q, KV), k_pool, table, _pa_mask(mask, k_pool, table),
-        interpret=interpret)
+        layer, interpret=interpret)
     return _pa_ungroup(m, q.shape[1], q.shape[2])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention_accumulate(q, k_pool, v_pool, table, mask, m_safe, *,
-                               interpret: bool | None = None):
+def paged_attention_accumulate(q, k_pool, v_pool, table, mask, m_safe,
+                               layer, *, interpret: bool | None = None):
     """Pass 2 of fused paged attention: accumulate softmax partials
     against the tp-GLOBAL safe max (kernels/paged_attn.py).
 
@@ -246,11 +251,12 @@ def paged_attention_accumulate(q, k_pool, v_pool, table, mask, m_safe, *,
     """
     interpret = interpret_default() if interpret is None else interpret
     _, Qn, Hp, _ = q.shape
-    KV = k_pool.shape[1]
+    KV = k_pool.shape[3]
     num, den = _pa.paged_attn_accumulate(
         _pa_group_q(q, KV), k_pool, v_pool, table,
         _pa_mask(mask, k_pool, table),
-        _pa_group_q(m_safe[..., None], KV)[..., 0], interpret=interpret)
+        _pa_group_q(m_safe[..., None], KV)[..., 0], layer,
+        interpret=interpret)
     return _pa_ungroup(num, Qn, Hp), _pa_ungroup(den, Qn, Hp)
 
 

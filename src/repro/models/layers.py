@@ -519,12 +519,14 @@ def init_decode_cache(cfg, env: AxisEnv, batch_local: int, seq_len: int,
 
 @jax.named_scope("attention")
 def decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
-                     cache: Dict[str, jax.Array], pos: jax.Array, *,
+                     cache: Dict[str, jax.Array], layer, pos: jax.Array, *,
                      window: Optional[int] = None,
                      cross: bool = False):
     """Single-token decode with an S-sharded cache and online-softmax psum.
 
-    x (B_loc, d) replicated over tp; cache k/v (B_loc, S_loc, KV, hd).
+    x (B_loc, d) replicated over tp; cache k/v (L, B_loc, S_loc, KV, hd)
+    stacked over a layer stack, of which this is layer `layer`: the new
+    row is written into the stack in place.
     Every tp rank computes *all* query heads (the per-token q vector is
     all-gathered — tiny), attends its S-slice, and the (num, den) pair is
     psum'ed over tp; this shards cache memory 1/tp with O(B*H*hd) traffic.
@@ -547,7 +549,7 @@ def decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     # assemble all padded heads on every rank (tiny: B x Hp x hd)
     q_all = env.all_gather_tp(q_local, axis=1)            # (B, Hp, hd)
 
-    s_loc = cache["k"].shape[1]
+    s_loc = cache["k"].shape[2]
     r = env.tp_index()
     if not cross:
         k_new = (x @ wk).reshape(B, ad.n_kv, hd)
@@ -561,10 +563,10 @@ def decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
         local_slot = jnp.clip(slot - r * s_loc, 0, s_loc - 1)
         owns = (slot >= r * s_loc) & (slot < (r + 1) * s_loc)
         def upd(buf, new):
-            cur = jax.lax.dynamic_slice_in_dim(buf, local_slot, 1, axis=1)
-            new = jnp.where(owns, new[:, None], cur)
-            return jax.lax.dynamic_update_slice_in_dim(buf, new, local_slot,
-                                                       axis=1)
+            at = (layer, 0, local_slot, 0, 0)
+            cur = jax.lax.dynamic_slice(buf, at, (1, B, 1) + buf.shape[3:])
+            new = jnp.where(owns, new[None, :, None], cur)
+            return jax.lax.dynamic_update_slice(buf, new, at)
         cache = {"k": upd(cache["k"], k_new.astype(cdt)),
                  "v": upd(cache["v"], v_new.astype(cdt))}
 
@@ -584,7 +586,7 @@ def decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     else:
         valid = kpos <= pos
     attn = _decode_scores_combine(
-        cfg, env, ad, q_all, cache["k"], cache["v"],
+        cfg, env, ad, q_all, cache["k"][layer], cache["v"][layer],
         jnp.broadcast_to(valid[None, :], (B, s_loc)), cdt)  # (B,Hp,hd)
 
     # row-parallel output projection on the local head slice
@@ -605,24 +607,35 @@ def expand_cache_from_prefill(prefill_cache):
 #
 # The online engine (serving/online.py) stores the decode KV cache as a
 # slot-agnostic *page pool* instead of a dense (B, S) tensor: pool k/v are
-# (n_pages, KV, ps_loc, hd) with the in-page offset dim sharded over tp
+# (n_pages, ps_loc, KV, hd) per layer (a latent pool (n_pages, ps_loc,
+# width), `latent_pool_width`), stacked over the layers of each layer
+# stack, with the in-page offset dim sharded over tp
 # (ps_loc = page_size // tp — rank r owns offsets [r*ps_loc, (r+1)*ps_loc)
 # of every page, preserving the dense path's 1/tp cache-memory sharding).
-# One kv head's (ps_loc, hd) page block is one fused-kernel DMA.
+# A page is its rows in position order, each row every head of one
+# token: the order in which a token's rows are scattered and in which a
+# page's rows are gathered into the (S, KV, hd) view, so neither has to
+# re-lay the pool out.  One page's (ps_loc, KV, hd) block is one
+# fused-kernel DMA.
 # A per-slot page table maps logical page -> physical page; admission,
 # completion, and preemption are pure table/mask updates, so the jitted
 # step never recompiles.  Physical page 0 is reserved as a scratch page:
 # masked lanes (inactive slots, non-owning ranks) land their writes there,
-# which keeps every pool update a plain vectorized scatter.
+# which keeps every pool update a plain vectorized scatter.  The serve
+# steps' layer loop carries the stacked pools (models/model.py
+# `_scan_layers`): each layer scatters its rows into the stack at its
+# layer index and gathers its pages from there, so no layer's pool is
+# ever sliced out of the stack, copied, or stacked back.
 
 
 def init_paged_kv_pool(cfg, n_pages: int, page_size: int
                        ) -> Dict[str, jax.Array]:
-    """GLOBAL paged KV pool for one attention layer (zeros).  The serving
-    Runner shards the page_size dim over tp via `api.paged_cache_specs`."""
+    """GLOBAL paged KV pool for one attention layer (zeros), (n_pages,
+    page_size, KV, hd).  The serving Runner shards the page_size dim over
+    tp via `api.paged_cache_specs`."""
     cdt = jnp.dtype(cfg.compute_dtype)
     hd = cfg.head_dim or cfg.d_model // cfg.n_heads
-    shape = (n_pages, cfg.n_kv_heads, page_size, hd)
+    shape = (n_pages, page_size, cfg.n_kv_heads, hd)
     return {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
 
 
@@ -650,22 +663,23 @@ def paged_valid_mask(table, pos, *, page_size: int, ps_loc: int,
     return pvalid[:, None, :] & (gpos[None, None, :] <= pos[:, :, None])
 
 
-def _paged_write(pool, rows, pos, page_table, owns, *,
+def _paged_write(pool, layer, rows, pos, page_table, owns, *,
                  page_size: int, env: AxisEnv, cdt):
-    """Scatter per-lane cache rows into their pages.
+    """Scatter per-lane cache rows into their pages of layer `layer` of
+    the stacked pools, in place.
 
-    rows {pool leaf: (..., KV, hd) new rows}; pos (...,) int32 global
-    positions; page_table broadcastable lookup of the physical page per
-    lane (already resolved by the caller); owns (...,) bool — lanes that
-    are inactive, unallocated, or whose in-page offset belongs to another
-    tp rank write to scratch page 0 instead.
+    rows {pool leaf: (..., KV, hd) or (..., lw) new rows}; pos (...,)
+    int32 global positions; page_table broadcastable lookup of the
+    physical page per lane (already resolved by the caller); owns (...,)
+    bool — lanes that are inactive, unallocated, or whose in-page offset
+    belongs to another tp rank write to scratch page 0 instead.
     """
     ps_loc = next(iter(pool.values())).shape[2]
     r = env.tp_index()
     o = pos % page_size
     dest = jnp.where(owns, page_table, 0)
     o_loc = jnp.clip(o - r * ps_loc, 0, ps_loc - 1)
-    return {name: pool[name].at[dest, :, o_loc].set(new.astype(cdt))
+    return {name: pool[name].at[layer, dest, o_loc].set(new.astype(cdt))
             for name, new in rows.items()}
 
 
@@ -750,18 +764,19 @@ def resolve_paged_attn(mode: str) -> str:
 
 
 def _paged_attention_core(cfg, env: AxisEnv, ad: AttnDims, q_all, pool,
-                          table, valid, cdt, *, paged_attn: str):
+                          layer, table, valid, cdt, *, paged_attn: str):
     """Shared query-batched paged-attention core for all three callers
     (decode Q=1, chunked prefill Q=C, spec-decode verify Q=k+1).
 
-    q_all (B, Q, Hp, hd); pool k/v (n_pages, KV, ps_loc, hd); table
-    (B, n_lp); valid (B, Q, S_g).  "fused" walks the page table inside
-    the Pallas kernel (kernels/paged_attn.py) and combines the local
-    (num, m, den) partials over tp here — the gathered (B, S_g, KV, hd)
-    view never touches HBM; "gathered" materializes it once per layer
-    via `ops.paged_gather` (the parity oracle).  The fused kernel needs
-    the grouped GQA layout; head-padded / unevenly-grouped archs fall
-    back to gathered.  Returns (B, Q, Hp, hd)."""
+    q_all (B, Q, Hp, hd); pool k/v (L, n_pages, ps_loc, KV, hd), read
+    at layer `layer`; table (B, n_lp); valid (B, Q, S_g).  "fused" walks
+    the page table inside the Pallas kernel (kernels/paged_attn.py) and
+    combines the local (num, m, den) partials over tp here — the
+    gathered (B, S_g, KV, hd) view never touches HBM; "gathered"
+    materializes it once per layer via `ops.paged_gather` (the parity
+    oracle).  The fused kernel needs the grouped GQA layout; head-padded
+    / unevenly-grouped archs fall back to gathered.  Returns
+    (B, Q, Hp, hd)."""
     from repro.kernels import ops as kops
     mode = resolve_paged_attn(paged_attn)
     grouped = (ad.n_heads == ad.heads_padded
@@ -771,27 +786,29 @@ def _paged_attention_core(cfg, env: AxisEnv, ad: AttnDims, q_all, pool,
         # the GLOBAL max inside the kernel and rounded to cdt there, so
         # every softmax term matches the gathered oracle at any tp.
         m_loc = kops.paged_attention_scores_max(q_all, pool["k"], table,
-                                                valid)
+                                                valid, layer)
         m = env.pmax_tp(m_loc)
         m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
         num, den = kops.paged_attention_accumulate(
-            q_all, pool["k"], pool["v"], table, valid, m_safe)
+            q_all, pool["k"], pool["v"], table, valid, m_safe, layer)
         num, den = env.psum_tp((num, den))
         return (num / jnp.maximum(den, 1e-20)[..., None]).astype(cdt)
-    k_g = kops.paged_gather(pool["k"], table)
-    v_g = kops.paged_gather(pool["v"], table)
+    k_g = kops.paged_gather(pool["k"], table, layer)
+    v_g = kops.paged_gather(pool["v"], table, layer)
     return _paged_scores_combine(cfg, env, ad, q_all, k_g, v_g, valid, cdt)
 
 
 @jax.named_scope("attention")
 def paged_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
-                           pool: Dict[str, jax.Array], pos: jax.Array,
+                           pool: Dict[str, jax.Array], layer,
+                           pos: jax.Array,
                            table: jax.Array, active: jax.Array, *,
                            page_size: int, paged_attn: str = "auto",
                            valid: Optional[jax.Array] = None):
     """Single-token decode against a paged KV pool.
 
-    x (B, d) replicated over tp (B = max_slots, a fixed shape); pos (B,)
+    x (B, d) replicated over tp (B = max_slots, a fixed shape); pool the
+    KV pools of a layer stack, of which this is layer `layer`; pos (B,)
     int32 position being written per slot; table (B, n_lp) physical page
     per logical page (0 = unallocated); active (B,) bool.  Writes the new
     token's KV into its page (masked to the owning rank + scratch page for
@@ -826,14 +843,15 @@ def paged_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     lp = jnp.clip(pos // page_size, 0, n_lp - 1)
     pp = jnp.take_along_axis(table, lp[:, None], axis=1)[:, 0]
     owns = active & (pp > 0) & ((pos % page_size) // ps_loc == r)
-    pool = _paged_write(pool, {"k": k_new, "v": v_new}, pos, pp, owns,
-                        page_size=page_size, env=env, cdt=cdt)
+    pool = _paged_write(pool, layer, {"k": k_new, "v": v_new}, pos, pp,
+                        owns, page_size=page_size, env=env, cdt=cdt)
 
     if valid is None:
         valid = paged_valid_mask(table, pos[:, None], page_size=page_size,
                                  ps_loc=ps_loc, env=env)
-    attn = _paged_attention_core(cfg, env, ad, q_all[:, None], pool, table,
-                                 valid, cdt, paged_attn=paged_attn)[:, 0]
+    attn = _paged_attention_core(cfg, env, ad, q_all[:, None], pool, layer,
+                                 table, valid, cdt,
+                                 paged_attn=paged_attn)[:, 0]
 
     lo = r * ad.local_heads
     local = jax.lax.dynamic_slice_in_dim(attn, lo, ad.local_heads, axis=1)
@@ -843,7 +861,8 @@ def paged_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
 
 @jax.named_scope("attention")
 def paged_prefill_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
-                            pool: Dict[str, jax.Array], base: jax.Array,
+                            pool: Dict[str, jax.Array], layer,
+                            base: jax.Array,
                             n_valid: jax.Array, table_row: jax.Array, *,
                             page_size: int, paged_attn: str = "auto",
                             valid: Optional[jax.Array] = None):
@@ -886,14 +905,14 @@ def paged_prefill_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     pp = jnp.take(table_row, lp)                             # (C,)
     owns = ((jnp.arange(C) < n_valid) & (pp > 0)
             & ((posq % page_size) // ps_loc == r))
-    pool = _paged_write(pool, {"k": k_new, "v": v_new}, posq, pp, owns,
-                        page_size=page_size, env=env, cdt=cdt)
+    pool = _paged_write(pool, layer, {"k": k_new, "v": v_new}, posq, pp,
+                        owns, page_size=page_size, env=env, cdt=cdt)
 
     if valid is None:
         valid = paged_valid_mask(table_row[None], posq[None],
                                  page_size=page_size, ps_loc=ps_loc,
                                  env=env)
-    attn = _paged_attention_core(cfg, env, ad, q_all[None], pool,
+    attn = _paged_attention_core(cfg, env, ad, q_all[None], pool, layer,
                                  table_row[None], valid, cdt,
                                  paged_attn=paged_attn)[0]
 
@@ -905,7 +924,8 @@ def paged_prefill_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
 
 @jax.named_scope("attention")
 def paged_verify_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
-                           pool: Dict[str, jax.Array], pos: jax.Array,
+                           pool: Dict[str, jax.Array], layer,
+                           pos: jax.Array,
                            table: jax.Array, active: jax.Array, *,
                            page_size: int, paged_attn: str = "auto",
                            valid: Optional[jax.Array] = None):
@@ -950,8 +970,9 @@ def paged_verify_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     pp = jnp.take_along_axis(table, lp, axis=1)            # (B, Q)
     owns = (active[:, None] & (pp > 0)
             & ((pos % page_size) // ps_loc == r))
-    pool = _paged_write(pool, {"k": k_new.reshape(B, Q, ad.n_kv, hd),
-                               "v": v_new.reshape(B, Q, ad.n_kv, hd)},
+    pool = _paged_write(pool, layer,
+                        {"k": k_new.reshape(B, Q, ad.n_kv, hd),
+                         "v": v_new.reshape(B, Q, ad.n_kv, hd)},
                         pos, pp, owns, page_size=page_size, env=env, cdt=cdt)
 
     if valid is None:
@@ -959,7 +980,7 @@ def paged_verify_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
                                  ps_loc=ps_loc, env=env)
     attn = _paged_attention_core(
         cfg, env, ad, q_all.reshape(B, Q, ad.heads_padded, hd), pool,
-        table, valid, cdt,
+        layer, table, valid, cdt,
         paged_attn=paged_attn).reshape(B * Q, ad.heads_padded, hd)
 
     lo = r * ad.local_heads
@@ -1086,7 +1107,8 @@ def _mla_attend(cfg, env: AxisEnv, params: Params, q_nope, q_pe, view,
     """Absorbed latent attention over cached rows.
 
     q_nope (B, Q, Hl, nope), q_pe (B, Q, Hl, rope) local heads; view
-    (B, S, 1, latent_width) latent rows (this rank's slice of S); valid
+    (B, S, 1, width) latent rows (this rank's slice of S), the
+    latent_width values of each followed by zeros up to `width`; valid
     (B, Q, S).  Every rank scores all heads against its rows and the
     (num, den) pair is summed over tp, as for grouped-query attention.
     Returns (B, Q, Hl, v) in the compute dtype."""
@@ -1094,14 +1116,19 @@ def _mla_attend(cfg, env: AxisEnv, params: Params, q_nope, q_pe, view,
     cdt = jnp.dtype(cfg.compute_dtype)
     r, nope = a.kv_lora_rank, a.qk_nope_head_dim
     w = _mla_wkv_b(cfg, env, params)
+    # a latent page pool's rows end in zeros (`latent_pool_width`): the
+    # query ends in as many, so each adds an exact zero to every score
+    width = view.shape[-1]
     with jax.named_scope("absorb"):
         q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w[..., :nope])
         q_all = env.all_gather_tp(jnp.concatenate([q_lat, q_pe], -1),
                                   axis=2)                  # (B, Q, Hp, lw)
+        q_all = jnp.pad(q_all, ((0, 0),) * 3
+                        + ((0, width - a.latent_width),))
     with jax.named_scope("latent_attn"):
-        o_lat = _paged_scores_combine(cfg, env, ad, q_all, view,
-                                      view[..., :r], valid, cdt,
-                                      scale=mla_softmax_scale(cfg))
+        o_lat = _paged_scores_combine(
+            cfg, env, dataclasses.replace(ad, head_dim=width), q_all, view,
+            view[..., :r], valid, cdt, scale=mla_softmax_scale(cfg))
         lo = env.tp_index() * ad.local_heads
         o_lat = jax.lax.dynamic_slice_in_dim(o_lat, lo, ad.local_heads,
                                              axis=2)
@@ -1152,50 +1179,69 @@ def init_mla_cache(cfg, env: AxisEnv, batch_local: int, seq_len: int
 
 @jax.named_scope("attention")
 def mla_decode_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
-                         cache: Dict[str, jax.Array], pos: jax.Array):
-    """Single-token latent decode against the dense S-sharded cache: x
-    (B_loc, d), pos the scalar position.  Writes the token's latent row
-    (owning rank only), then the absorbed form over the cache.  Returns
-    (partial (B_loc, d), cache)."""
+                         cache: Dict[str, jax.Array], layer,
+                         pos: jax.Array):
+    """Single-token latent decode against the dense S-sharded cache,
+    stacked over a layer stack (L, B_loc, S_loc, 1, lw), at layer
+    `layer`: x (B_loc, d), pos the scalar position.  Writes the token's
+    latent row (owning rank only) into the stack in place, then the
+    absorbed form over the layer's cache.  Returns (partial (B_loc, d),
+    cache)."""
     B = x.shape[0]
-    s_loc = cache["kv"].shape[1]
+    s_loc = cache["kv"].shape[2]
     r = env.tp_index()
     q_nope, q_pe = _mla_query(cfg, env, params, x[:, None], pos[None])
     row = _mla_latent(cfg, env, params, x, pos)            # (B, lw)
     with jax.named_scope("kv_compress"):
         local = jnp.clip(pos - r * s_loc, 0, s_loc - 1)
         owns = (pos >= r * s_loc) & (pos < (r + 1) * s_loc)
-        cur = jax.lax.dynamic_slice_in_dim(cache["kv"], local, 1, axis=1)
-        new = jnp.where(owns, row[:, None, None], cur)
-        cache = {"kv": jax.lax.dynamic_update_slice_in_dim(
-            cache["kv"], new, local, axis=1)}
+        at = (layer, 0, local, 0, 0)
+        cur = jax.lax.dynamic_slice(cache["kv"], at,
+                                    (1, B, 1) + cache["kv"].shape[3:])
+        new = jnp.where(owns, row[None, :, None, None], cur)
+        cache = {"kv": jax.lax.dynamic_update_slice(cache["kv"], new, at)}
     valid = r * s_loc + jnp.arange(s_loc) <= pos
-    o = _mla_attend(cfg, env, params, q_nope, q_pe, cache["kv"],
+    o = _mla_attend(cfg, env, params, q_nope, q_pe, cache["kv"][layer],
                     jnp.broadcast_to(valid, (B, 1, s_loc)))
     return _mla_out(cfg, env, params, o[:, 0]), cache
+
+
+# The TPU's lane width.  A pool whose rows are no multiple of it wide
+# (DeepSeek-V2's latent row is 576 values) is laid out pages-minor by
+# the compiler's default layout, which saves padding the rows but in
+# which no serve step can scatter a row or gather a page in place: each
+# step would copy the whole pool into a row-major layout and back.
+LANES = 128
+
+
+def latent_pool_width(cfg) -> int:
+    """Values a latent page pool holds per token: the latent row,
+    kv_lora_rank + qk_rope_head_dim, padded with zeros to whole lanes."""
+    return pad_to_multiple(cfg.mla.latent_width, LANES)
 
 
 def init_paged_mla_pool(cfg, n_pages: int, page_size: int
                         ) -> Dict[str, jax.Array]:
     """GLOBAL latent page pool of one layer: one row of
-    kv_lora_rank + qk_rope_head_dim values per token, (n_pages, 1,
-    page_size, lw), laid out and sharded as a one-head KV pool."""
-    return {"kv": jnp.zeros((n_pages, 1, page_size, cfg.mla.latent_width),
+    `latent_pool_width` values per token, (n_pages, page_size, width),
+    sharded as a KV pool."""
+    return {"kv": jnp.zeros((n_pages, page_size, latent_pool_width(cfg)),
                             jnp.dtype(cfg.compute_dtype))}
 
 
 @jax.named_scope("attention")
 def paged_mla_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
-                        pool: Dict[str, jax.Array], pos: jax.Array,
+                        pool: Dict[str, jax.Array], layer, pos: jax.Array,
                         table: jax.Array, live: jax.Array, *,
                         page_size: int, valid: Optional[jax.Array] = None):
     """Latent attention for Q consecutive tokens per row against the
     latent page pool: decode (Q = 1 per slot), a prefill chunk (one row
     of C) and spec-decode verify (Q = k + 1 per slot) alike.
 
-    x (B, Q, d) replicated over tp; pos (B, Q) positions; table (B, n_lp)
-    page tables; live (B, Q) lanes whose rows are real (masked lanes
-    write to scratch page 0).  `valid` is the once-per-tick (B, Q, S_g)
+    x (B, Q, d) replicated over tp; pool the latent pools of a layer
+    stack, read and written at layer `layer`; pos (B, Q) positions;
+    table (B, n_lp) page tables; live (B, Q) lanes whose rows are real
+    (masked lanes write to scratch page 0).  `valid` is the once-per-tick (B, Q, S_g)
     mask from `paged_valid_mask`.  Returns (partial (B*Q, d), pool)."""
     cdt = jnp.dtype(cfg.compute_dtype)
     B, Q, _ = x.shape
@@ -1204,17 +1250,20 @@ def paged_mla_attention(cfg, env: AxisEnv, params: Params, x: jax.Array,
     q_nope, q_pe = _mla_query(cfg, env, params, x, pos)
     row = _mla_latent(cfg, env, params, x, pos)            # (B, Q, lw)
     with jax.named_scope("kv_compress"):
+        row = jnp.pad(row, ((0, 0), (0, 0),
+                            (0, pool["kv"].shape[-1] - row.shape[-1])))
         lp = jnp.clip(pos // page_size, 0, n_lp - 1)
         pp = jnp.take_along_axis(table, lp, axis=1)         # (B, Q)
         owns = (live & (pp > 0)
                 & ((pos % page_size) // ps_loc == env.tp_index()))
-        pool = _paged_write(pool, {"kv": row[..., None, :]}, pos, pp, owns,
+        pool = _paged_write(pool, layer, {"kv": row}, pos, pp, owns,
                             page_size=page_size, env=env, cdt=cdt)
     if valid is None:
         valid = paged_valid_mask(table, pos, page_size=page_size,
                                  ps_loc=ps_loc, env=env)
     from repro.kernels import ops as kops
     with jax.named_scope("latent_attn"):
-        view = kops.paged_gather(pool["kv"], table)       # (B, S_g, 1, lw)
-    o = _mla_attend(cfg, env, params, q_nope, q_pe, view, valid)
+        view = kops.paged_gather(pool["kv"], table, layer)  # (B, S_g, w)
+    o = _mla_attend(cfg, env, params, q_nope, q_pe, view[:, :, None],
+                    valid)
     return _mla_out(cfg, env, params, o).reshape(B * Q, -1), pool

@@ -234,36 +234,53 @@ def _dense_ffn(cfg, env: AxisEnv, params, h):
 # ---------------------------------------------------------------------------
 
 
-def block_decode(cfg, env: AxisEnv, params, x, cache, pos, *, kind: str,
-                 ffn: str, flags: RunFlags = DEFAULT_FLAGS):
-    """x (B, d) replicated over tp; cache per-kind dict."""
+def _layer_of(stack, layer):
+    """Layer `layer` of a tree stacked over a layer stack."""
+    return jax.tree.map(lambda a: a[layer], stack)
+
+
+def _with_layer(stack, layer, tree):
+    """`stack` with layer `layer` replaced by `tree`, in place."""
+    return jax.tree.map(lambda a, v: a.at[layer].set(v), stack, tree)
+
+
+def block_decode(cfg, env: AxisEnv, params, x, cache, layer, pos, *,
+                 kind: str, ffn: str, flags: RunFlags = DEFAULT_FLAGS):
+    """x (B, d) replicated over tp; cache the per-kind dict of a layer
+    stack's caches, read and written at layer `layer`.  An attention
+    cache takes the token's new row in place; a recurrent state is
+    replaced whole."""
     h = L.apply_norm(cfg, env, params["norm1"], x)
     if kind in ("attn", "swa"):
         window = cfg.attn_window if kind == "swa" else None
         partial, cache["self"] = L.decode_attention(
-            cfg, env, params["attn"], h, cache["self"], pos, window=window)
+            cfg, env, params["attn"], h, cache["self"], layer, pos,
+            window=window)
     elif kind == "mla":
         partial, cache["self"] = L.mla_decode_attention(
-            cfg, env, params["attn"], h, cache["self"], pos)
+            cfg, env, params["attn"], h, cache["self"], layer, pos)
     elif kind == "rwkv":
-        partial, cache["rwkv"] = rwkv_lib.time_mix_decode(
-            cfg, env, params["tmix"], h, cache["rwkv"])
+        partial, state = rwkv_lib.time_mix_decode(
+            cfg, env, params["tmix"], h, _layer_of(cache["rwkv"], layer))
+        cache["rwkv"] = _with_layer(cache["rwkv"], layer, state)
     elif kind == "rglru":
-        partial, cache["rglru"] = rglru_lib.decode_step(
-            cfg, env, params["rec"], h, cache["rglru"])
+        partial, state = rglru_lib.decode_step(
+            cfg, env, params["rec"], h, _layer_of(cache["rglru"], layer))
+        cache["rglru"] = _with_layer(cache["rglru"], layer, state)
     x = x + env.psum_tp(partial)
 
     if "xattn" in params:
         h = L.apply_norm(cfg, env, params["norm_x"], x)
         partial, _ = L.decode_attention(cfg, env, params["xattn"], h,
-                                        cache["cross"], pos, cross=True)
+                                        cache["cross"], layer, pos,
+                                        cross=True)
         x = x + env.psum_tp(partial)
 
     h = L.apply_norm(cfg, env, params["norm2"], x)
     if kind == "rwkv":
         partial, gate = rwkv_lib.channel_mix(
-            cfg, env, params["cmix"], h, cache["cmix_prev"])
-        cache["cmix_prev"] = h
+            cfg, env, params["cmix"], h, cache["cmix_prev"][layer])
+        cache["cmix_prev"] = cache["cmix_prev"].at[layer].set(h)
         x = x + gate * env.psum_tp(partial)
     elif ffn == "moe":
         partial, _, _ = moe_lib.moe_ffn(cfg, env, params["moe"], h,
@@ -430,18 +447,24 @@ def _serve_blocks(cfg: ModelConfig, params) -> Dict[str, Any]:
 
 
 def _scan_layers(cfg: ModelConfig, params, x, caches, layer):
-    """Run `layer(lp, x, cache, ffn) -> (x, cache)` over every layer of a
-    uniform model's serve tree, one `lax.scan` per stack in depth order
-    (`layer_stacks`).  Returns (x, caches)."""
+    """Run `layer(lp, x, stack, i, ffn) -> (x, stack)` over every layer of
+    a uniform model's serve tree, one `lax.scan` per stack in depth order
+    (`layer_stacks`).  A stack's caches (dense caches or page pools, of
+    any kind) are the loop's carry, not its input and output: layer i
+    writes its new rows into the stack at index i and reads its rows
+    from there, so no layer's cache is sliced out of the stack, copied
+    or stacked back, and the stack leaves the loop in the buffer it came
+    in (donated by the serve steps).  Returns (x, caches)."""
     blocks = _serve_blocks(cfg, params)
     caches = per_stack(cfg, caches)
     out = {}
-    for key, ffn, _ in layer_stacks(cfg):
-        def body(x, inp, ffn=ffn):
-            lp, cache = inp
-            return layer(lp, x, cache, ffn)
+    for key, ffn, n in layer_stacks(cfg):
+        def body(carry, inp, ffn=ffn):
+            lp, i = inp
+            return layer(lp, *carry, i, ffn), None
 
-        x, out[key] = jax.lax.scan(body, x, (blocks[key], caches[key]))
+        (x, out[key]), _ = jax.lax.scan(body, (x, caches[key]),
+                                        (blocks[key], jnp.arange(n)))
     return x, from_stacks(cfg, out)
 
 
@@ -637,28 +660,30 @@ def init_paged_caches(cfg: ModelConfig, env: AxisEnv, n_pages: int,
 
 def _pool_ps_loc(cfg, pools) -> int:
     """Per-rank page row count of the serve-step pools (each leaf is
-    (layers, n_pages, KV, ps_loc, width))."""
-    return jax.tree.leaves(pools)[0].shape[3]
+    (layers, n_pages, ps_loc, ...))."""
+    return jax.tree.leaves(pools)[0].shape[2]
 
 
-def block_decode_paged(cfg, env: AxisEnv, params, x, pool, pos, table,
-                       active, *, page_size: int, ffn: str,
+def block_decode_paged(cfg, env: AxisEnv, params, x, pools, layer, pos,
+                       table, active, *, page_size: int, ffn: str,
                        flags: RunFlags = DEFAULT_FLAGS, valid=None):
-    """Paged analogue of `block_decode`: x (B, d) replicated over tp, pool
-    the layer's page pool.  `valid` is the once-per-tick layer-invariant
-    page mask (layers.paged_valid_mask)."""
+    """Paged analogue of `block_decode`: x (B, d) replicated over tp,
+    pools the page pools of the layer's stack, read and written at
+    `layer`.  `valid` is the once-per-tick layer-invariant page mask
+    (layers.paged_valid_mask)."""
     h = L.apply_norm(cfg, env, params["norm1"], x)
     if cfg.mla is not None:
-        partial, pool["self"] = L.paged_mla_attention(
-            cfg, env, params["attn"], h[:, None], pool["self"],
+        partial, pool = L.paged_mla_attention(
+            cfg, env, params["attn"], h[:, None], pools["self"], layer,
             pos[:, None], table, active[:, None], page_size=page_size,
             valid=valid)
     else:
-        partial, pool["self"] = L.paged_decode_attention(
-            cfg, env, params["attn"], h, pool["self"], pos, table, active,
-            page_size=page_size, paged_attn=flags.paged_attn, valid=valid)
+        partial, pool = L.paged_decode_attention(
+            cfg, env, params["attn"], h, pools["self"], layer, pos, table,
+            active, page_size=page_size, paged_attn=flags.paged_attn,
+            valid=valid)
     x = x + env.psum_tp(partial)
-    return _ffn_sublayer(cfg, env, params, x, ffn, flags), pool
+    return _ffn_sublayer(cfg, env, params, x, ffn, flags), {"self": pool}
 
 
 def _ffn_sublayer(cfg, env: AxisEnv, params, x, ffn: str,
@@ -686,9 +711,9 @@ def _paged_decode_logits(cfg: ModelConfig, denv: AxisEnv, params, pools,
                                ps_loc=_pool_ps_loc(cfg, pools), env=denv)
     x, pools = _scan_layers(
         cfg, params, x, pools,
-        lambda lp, x, pool, ffn: block_decode_paged(
-            cfg, denv, lp, x, pool, pos, table, active, page_size=page_size,
-            ffn=ffn, flags=flags, valid=valid))
+        lambda lp, x, stack, i, ffn: block_decode_paged(
+            cfg, denv, lp, x, stack, i, pos, table, active,
+            page_size=page_size, ffn=ffn, flags=flags, valid=valid))
     x = _final_norm(cfg, denv, params, x)
     return emb.lm_logits(cfg, denv, params["embed"], x), pools
 
@@ -770,23 +795,25 @@ def paged_draft_propose(cfg: ModelConfig, env: AxisEnv, params, pools,
     return drafts, draft_probs, pools
 
 
-def block_verify_paged(cfg, env: AxisEnv, params, x, pool, pos, table,
-                       active, *, B: int, Q: int, page_size: int, ffn: str,
-                       flags: RunFlags = DEFAULT_FLAGS, valid=None):
-    """One layer of the k+1-token verify pass: x (B*Q, d)."""
+def block_verify_paged(cfg, env: AxisEnv, params, x, pools, layer, pos,
+                       table, active, *, B: int, Q: int, page_size: int,
+                       ffn: str, flags: RunFlags = DEFAULT_FLAGS,
+                       valid=None):
+    """One layer of the k+1-token verify pass: x (B*Q, d); pools as in
+    `block_decode_paged`."""
     h = L.apply_norm(cfg, env, params["norm1"], x)
     if cfg.mla is not None:
-        partial, pool["self"] = L.paged_mla_attention(
-            cfg, env, params["attn"], h.reshape(B, Q, -1), pool["self"],
-            pos, table, jnp.broadcast_to(active[:, None], (B, Q)),
+        partial, pool = L.paged_mla_attention(
+            cfg, env, params["attn"], h.reshape(B, Q, -1), pools["self"],
+            layer, pos, table, jnp.broadcast_to(active[:, None], (B, Q)),
             page_size=page_size, valid=valid)
     else:
-        partial, pool["self"] = L.paged_verify_attention(
-            cfg, env, params["attn"], h.reshape(B, Q, -1), pool["self"],
-            pos, table, active, page_size=page_size,
+        partial, pool = L.paged_verify_attention(
+            cfg, env, params["attn"], h.reshape(B, Q, -1), pools["self"],
+            layer, pos, table, active, page_size=page_size,
             paged_attn=flags.paged_attn, valid=valid)
     x = x + env.psum_tp(partial)
-    return _ffn_sublayer(cfg, env, params, x, ffn, flags), pool
+    return _ffn_sublayer(cfg, env, params, x, ffn, flags), {"self": pool}
 
 
 def paged_verify_logits(cfg: ModelConfig, env: AxisEnv, params, pools,
@@ -804,8 +831,8 @@ def paged_verify_logits(cfg: ModelConfig, env: AxisEnv, params, pools,
                                ps_loc=_pool_ps_loc(cfg, pools), env=denv)
     x, pools = _scan_layers(
         cfg, params, x, pools,
-        lambda lp, x, pool, ffn: block_verify_paged(
-            cfg, denv, lp, x, pool, pos, table, active, B=B, Q=K1,
+        lambda lp, x, stack, i, ffn: block_verify_paged(
+            cfg, denv, lp, x, stack, i, pos, table, active, B=B, Q=K1,
             page_size=page_size, ffn=ffn, flags=flags, valid=valid))
     x = _final_norm(cfg, denv, params, x)
     return emb.lm_logits(cfg, denv, params["embed"], x), pools
@@ -877,25 +904,26 @@ def paged_verify_step(cfg: ModelConfig, env: AxisEnv, params, pools,
     return n_acc, out.astype(jnp.int32), pools
 
 
-def block_prefill_paged(cfg, env: AxisEnv, params, x, pool, base, n_valid,
-                        table_row, *, page_size: int, ffn: str,
+def block_prefill_paged(cfg, env: AxisEnv, params, x, pools, layer, base,
+                        n_valid, table_row, *, page_size: int, ffn: str,
                         flags: RunFlags = DEFAULT_FLAGS, valid=None):
-    """One layer of chunked prefill for a single request: x (C, d)."""
+    """One layer of chunked prefill for a single request: x (C, d); pools
+    as in `block_decode_paged`."""
     h = L.apply_norm(cfg, env, params["norm1"], x)
     if cfg.mla is not None:
         C = x.shape[0]
-        partial, pool["self"] = L.paged_mla_attention(
-            cfg, env, params["attn"], h[None], pool["self"],
+        partial, pool = L.paged_mla_attention(
+            cfg, env, params["attn"], h[None], pools["self"], layer,
             (base + jnp.arange(C))[None], table_row[None],
             (jnp.arange(C) < n_valid)[None], page_size=page_size,
             valid=valid)
     else:
-        partial, pool["self"] = L.paged_prefill_attention(
-            cfg, env, params["attn"], h, pool["self"], base, n_valid,
-            table_row, page_size=page_size, paged_attn=flags.paged_attn,
-            valid=valid)
+        partial, pool = L.paged_prefill_attention(
+            cfg, env, params["attn"], h, pools["self"], layer, base,
+            n_valid, table_row, page_size=page_size,
+            paged_attn=flags.paged_attn, valid=valid)
     x = x + env.psum_tp(partial)
-    return _ffn_sublayer(cfg, env, params, x, ffn, flags), pool
+    return _ffn_sublayer(cfg, env, params, x, ffn, flags), {"self": pool}
 
 
 def _paged_prefill_hidden(cfg: ModelConfig, denv: AxisEnv, params, pools,
@@ -910,8 +938,8 @@ def _paged_prefill_hidden(cfg: ModelConfig, denv: AxisEnv, params, pools,
         page_size=page_size, ps_loc=_pool_ps_loc(cfg, pools), env=denv)
     x, pools = _scan_layers(
         cfg, params, x, pools,
-        lambda lp, x, pool, ffn: block_prefill_paged(
-            cfg, denv, lp, x, pool, base, n_valid, table_row,
+        lambda lp, x, stack, i, ffn: block_prefill_paged(
+            cfg, denv, lp, x, stack, i, base, n_valid, table_row,
             page_size=page_size, ffn=ffn, flags=flags, valid=valid))
     return _final_norm(cfg, denv, params, x), pools
 
@@ -994,16 +1022,18 @@ def decode_step(cfg: ModelConfig, env: AxisEnv, params, caches,
         kind = cfg.block_pattern[0]
         x, caches = _scan_layers(
             cfg, params, x, caches,
-            lambda lp, x, cache, ffn: block_decode(
-                cfg, denv, lp, x, cache, pos, kind=kind, ffn=ffn,
+            lambda lp, x, stack, i, ffn: block_decode(
+                cfg, denv, lp, x, stack, i, pos, kind=kind, ffn=ffn,
                 flags=flags))
     else:
+        # each layer's caches as a stack of one
         new_caches = []
         for i, lp in enumerate(params["blocks"]):
-            x, c = block_decode(cfg, denv, lp, x, caches[i], pos,
-                                kind=cfg.block_kind(i), ffn=_ffn_kind(cfg, i),
-                                flags=flags)
-            new_caches.append(c)
+            x, c = block_decode(cfg, denv, lp, x,
+                                jax.tree.map(lambda a: a[None], caches[i]),
+                                0, pos, kind=cfg.block_kind(i),
+                                ffn=_ffn_kind(cfg, i), flags=flags)
+            new_caches.append(_layer_of(c, 0))
         caches = new_caches
     x = _final_norm(cfg, denv, params, x)
     logits = emb.lm_logits(cfg, denv, params["embed"], x)

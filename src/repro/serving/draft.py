@@ -6,7 +6,7 @@ draft_params)`` where the returned runner/params drive
 The engine gives the drafter the SAME page ids, page size, and pool
 count as the target, so admission, growth, preemption, prefix sharing,
 and trim all transfer to the drafter KV for free — the drafter pool is
-just a second set of (n_pages, KV, ps_loc, hd) tensors indexed by the
+just a second set of (L, n_pages, ps_loc, KV, hd) tensors indexed by the
 same tables.
 
 Two implementations:

@@ -18,6 +18,9 @@ Tolerances, as relative L2 of a position's logits, worst position:
   checked at float32.
 """
 import dataclasses
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -165,18 +168,22 @@ def test_absorbed_decode_equals_expanded_attention(case):
         return L.apply_mla(cfg, env, w, x)[0]
 
     def absorbed(env, w, x):
-        pool = L.init_paged_mla_pool(cfg, S // PAGE + 1, PAGE)
+        pool = jax.tree.map(lambda a: a[None],   # a stack of one layer
+                            L.init_paged_mla_pool(cfg, S // PAGE + 1, PAGE))
         pos = jnp.arange(S)[None]
         out, pool = L.paged_mla_attention(
-            cfg, env, w, x, pool, pos, table, jnp.ones((1, S), bool),
+            cfg, env, w, x, pool, 0, pos, table, jnp.ones((1, S), bool),
             page_size=PAGE)
-        return out, pool["kv"][1:, 0].reshape(S, -1)
+        return out, pool["kv"][0, 1:].reshape(S, -1)
 
     a = jax.jit(smap_env(expanded)[0])(w, x).reshape(S, -1)
     b, rows = jax.jit(smap_env(absorbed)[0])(w, x)
     assert float(jnp.linalg.norm(a - b) / jnp.linalg.norm(a)) < 1e-5
-    # the pool holds one latent row of kv_lora_rank + rope per token
-    assert rows.shape == (S, cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim)
+    # the pool holds one latent row of kv_lora_rank + rope per token,
+    # zero-padded to whole lanes
+    lw = cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim
+    assert rows.shape == (S, 128) and lw == 80
+    assert not bool(jnp.any(rows[:, lw:]))
 
 
 def test_ling_lite_decode_is_the_one_stack_scan():
@@ -194,17 +201,18 @@ def test_ling_lite_decode_is_the_one_stack_scan():
         denv = dataclasses.replace(env, seq_parallel=False)
         x = emb.embed_tokens(cfg, denv, params["embed"], token)
         valid = L.paged_valid_mask(table, pos[:, None], page_size=PAGE,
-                                   ps_loc=pools["self"]["k"].shape[3],
+                                   ps_loc=pools["self"]["k"].shape[2],
                                    env=denv)
 
-        def body(x, inp):
-            lp, pool = inp
-            return M.block_decode_paged(cfg, denv, lp, x, pool, pos, table,
-                                        active, page_size=PAGE, ffn="moe",
-                                        valid=valid)
+        def body(carry, inp):
+            lp, i = inp
+            return M.block_decode_paged(cfg, denv, lp, *carry, i, pos,
+                                        table, active, page_size=PAGE,
+                                        ffn="moe", valid=valid), None
 
         blocks = M._serve_blocks(cfg, params)["blocks"]
-        x, pools = jax.lax.scan(body, x, (blocks, pools))
+        (x, pools), _ = jax.lax.scan(body, (x, pools),
+                                     (blocks, jnp.arange(cfg.n_layers)))
         x = M._final_norm(cfg, denv, params, x)
         return emb.lm_logits(cfg, denv, params["embed"], x), pools
 
@@ -260,3 +268,134 @@ def test_verify_logits_match_the_reference(case):
         out.append(lg[:K1])
     got = np.concatenate(jax.device_get(out))[:, :cfg.vocab_size]
     assert worst_rel(got, ref[PROMPT:]) < TOL["float32"]
+
+
+# ---------------------------------------------------------------------------
+# the serve steps' layer loop against a per-layer reference loop
+# ---------------------------------------------------------------------------
+
+
+def per_layer_loop(cfg, params, x, caches, layer):
+    """Reference for `M._scan_layers`: the loop as the serve steps ran it
+    before their caches became its carry.  Each layer's caches are
+    sliced out of the stack as the scan's input, updated as a stack of
+    one, and stacked back as its output."""
+    blocks = M._serve_blocks(cfg, params)
+    caches = M.per_stack(cfg, caches)
+    out = {}
+    for key, ffn, _ in M.layer_stacks(cfg):
+        def body(x, inp, ffn=ffn):
+            lp, cache = inp
+            x, cache = layer(lp, x, jax.tree.map(lambda a: a[None], cache),
+                             0, ffn)
+            return x, jax.tree.map(lambda a: a[0], cache)
+
+        x, out[key] = jax.lax.scan(body, x, (blocks[key], caches[key]))
+    return x, M.from_stacks(cfg, out)
+
+
+def serve_sequence(arch: str, tp: int, verify: bool):
+    """Tokens and pools (as bits) after each step of a short serving
+    sequence on `arch`'s smoke model at tensor parallelism `tp`: prefill
+    chunks for three of four slots (one chunk with a padded tail), three
+    decode ticks with slot 2 inactive, and, if `verify`, a spec-decode
+    verify of two drafts with slots 1 and 2 inactive.  Inactive slots,
+    padded lanes and rows another rank owns write to scratch page 0."""
+    page, chunk, k, slots, n_lp = 16, 16, 2, 4, 4
+    runner = api.Runner(get_smoke_config(arch), make_local_mesh(1, tp),
+                        fsdp=False, seq_parallel=False, max_seq=page * n_lp)
+    params = runner.serve_params(runner.init_params(0))
+    vocab = runner.cfg.vocab_size
+    prefill = jax.jit(runner.make_paged_prefill(page))
+    decode = jax.jit(runner.make_paged_decode_step(page))
+    pools = runner.init_paged_pools(1 + slots * n_lp, page)
+    table = np.arange(1, 1 + slots * n_lp, dtype=np.int32).reshape(slots,
+                                                                   n_lp)
+    table[2] = 0                                   # slot 2 holds no pages
+    rs = np.random.RandomState(3)
+    f32, i32 = jnp.float32, jnp.int32
+    out = []
+
+    def record(tokens, pools):
+        out.append(np.asarray(tokens))
+        out.extend(np.asarray(a).view(np.uint16) for a in jax.tree.leaves(
+            pools))
+
+    lens = [chunk - 3, chunk, 0, chunk]
+    for b in (0, 1, 3):
+        nxt, pools = prefill(params, pools,
+                             jnp.asarray(rs.randint(0, vocab, chunk), i32),
+                             i32(0), i32(lens[b]), jnp.asarray(table[b]))
+        record(nxt, pools)
+    active = jnp.asarray([True, True, False, True])
+    for t in range(3):
+        pos = jnp.asarray([lens[0] + t, lens[1] + t, 0, lens[3] + t], i32)
+        nxt, pools = decode(params, pools,
+                            jnp.asarray(rs.randint(0, vocab, slots), i32),
+                            pos, jnp.asarray(table), active)
+        record(nxt, pools)
+    if not verify:
+        return out
+    verify = jax.jit(runner.make_paged_verify_step(page, k))
+    knobs = (jnp.arange(slots, dtype=i32), jnp.asarray([0, 0.7, 0, 1.0], f32),
+             jnp.ones((slots,), f32), jnp.zeros((slots,), i32))
+    vp = emb.padded_vocab(runner.cfg, runner.env)
+    draft_probs = jax.nn.softmax(
+        jnp.asarray(rs.normal(size=(slots, k, vp)), f32), -1)
+    n_acc, toks, pools = verify(
+        params, pools, jnp.asarray(rs.randint(0, vocab, (slots, k + 1)), i32),
+        jnp.asarray([lens[0] + 3, 0, 0, lens[3] + 3], i32), jnp.asarray(table),
+        jnp.asarray([True, False, False, True]), draft_probs, *knobs)
+    record(toks, pools)
+    out.append(np.asarray(n_acc))
+    return out
+
+
+def check_loop_parity(arch: str, tp: int, verify: bool):
+    """The serve steps' layer loop gives bitwise the tokens and the pools
+    of `per_layer_loop`."""
+    got = serve_sequence(arch, tp, verify)
+    carry = M._scan_layers
+    M._scan_layers = per_layer_loop
+    try:
+        want = serve_sequence(arch, tp, verify)
+    finally:
+        M._scan_layers = carry
+    assert len(got) == len(want)
+    for i, (a, b) in enumerate(zip(got, want)):
+        assert a.shape == b.shape and np.array_equal(a, b), i
+
+
+ARCHS = {"gqa": "ling-lite", "latent_dense_first": "deepseek-v2-lite"}
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_layer_loop_updates_the_pools_as_the_per_layer_loop(arch):
+    check_loop_parity(ARCHS[arch], 1, verify=True)
+
+
+_TP2_PARITY = """
+import os
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=2")
+from tests.test_mla import check_loop_parity
+check_loop_parity({arch!r}, 2, verify=False)
+print("LOOP PARITY TP2 OK")
+"""
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_layer_loop_updates_the_pools_as_the_per_layer_loop_tp2(arch):
+    """At tp=2 each rank owns half of every page's rows: a row another
+    rank owns goes to that rank's scratch page, on both loops alike
+    (prefill and decode; the verify pass writes through the same
+    scatter)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in ("src", root, env.get("PYTHONPATH", "")) if p)
+    res = subprocess.run(
+        [sys.executable, "-c", _TP2_PARITY.format(arch=ARCHS[arch])],
+        capture_output=True, text=True, env=env, cwd=root)
+    assert res.returncode == 0, res.stdout + res.stderr
+    assert "LOOP PARITY TP2 OK" in res.stdout

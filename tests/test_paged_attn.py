@@ -30,15 +30,20 @@ from repro.serving.online import OnlineConfig, OnlineEngine, OnlineRequest
 # ---------------------------------------------------------------------------
 
 
+# the layer of the stacked pools each case reads; the other layer holds
+# different values, so a kernel or gather that reads the wrong layer fails
+LAYER = 1
+
+
 def _gathered_ref(q, k_pool, v_pool, table, mask, cdt):
     """tp=1 reference reproducing `_paged_scores_combine`'s gathered
     math exactly: grouped einsum scores, softmax vs the global row max,
     p rounded to the compute dtype for the PV contraction."""
     B, Qn, Hp, hd = q.shape
-    KV = k_pool.shape[1]
+    KV = k_pool.shape[3]
     g = Hp // KV
-    k_g = kops.paged_gather(k_pool, table)
-    v_g = kops.paged_gather(v_pool, table)
+    k_g = kops.paged_gather(k_pool, table, LAYER)
+    v_g = kops.paged_gather(v_pool, table, LAYER)
     S_g = k_g.shape[1]
     q_g = q.reshape(B, Qn, KV, g, hd)
     s = jnp.einsum("bqkgd,bskd->bqkgs", q_g, k_g,
@@ -64,10 +69,10 @@ def _make_case(rng, *, B, Qn, n_lp, ps_loc, Hp, KV, hd, page_counts,
     filled with large garbage so any masking hole shows up loudly."""
     n_pages = n_pages or (1 + sum(page_counts))
     q = jnp.asarray(rng.normal(size=(B, Qn, Hp, hd)), dtype)
-    kp = jnp.asarray(rng.normal(size=(n_pages, KV, ps_loc, hd)), dtype)
-    vp = jnp.asarray(rng.normal(size=(n_pages, KV, ps_loc, hd)), dtype)
-    kp = kp.at[0].set(100.0)
-    vp = vp.at[0].set(-100.0)
+    kp = jnp.asarray(rng.normal(size=(2, n_pages, ps_loc, KV, hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(2, n_pages, ps_loc, KV, hd)), dtype)
+    kp = kp.at[:, 0].set(100.0)
+    vp = vp.at[:, 0].set(-100.0)
     table = np.zeros((B, n_lp), np.int32)
     nxt = 1
     for b, c in enumerate(page_counts):
@@ -97,10 +102,10 @@ def _fused_out(q, kp, vp, table, mask):
     # the tp=1 compose of the two-pass fused path: max walk, safe max,
     # accumulate walk, normalize (layers._paged_attention_core with the
     # pmax/psum collectives dropping out at tp=1)
-    m = kops.paged_attention_scores_max(q, kp, table, mask)
+    m = kops.paged_attention_scores_max(q, kp, table, mask, LAYER)
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
     num, den = kops.paged_attention_accumulate(q, kp, vp, table, mask,
-                                               m_safe)
+                                               m_safe, LAYER)
     return num / jnp.maximum(den, 1e-20)[..., None]
 
 

@@ -410,13 +410,15 @@ def test_engine_publishes_serve_weight_bytes(runner_params):
 def test_engine_publishes_serve_kv_bytes_per_token(arch, layout):
     """`serve_kv_bytes_per_token{layout=...}`: a token's cache bytes over
     all layers, K and V of each KV head or one latent row of
-    kv_lora_rank + rope values, in the compute dtype (bf16)."""
+    kv_lora_rank + rope values padded to whole lanes, in the compute
+    dtype (bf16)."""
     cfg = get_smoke_config(arch)
     runner = api.Runner(cfg, make_local_mesh(1, 1), fsdp=False,
                         seq_parallel=False, max_seq=64)
     eng = OnlineEngine(runner, runner.init_params(0), OnlineConfig(
         max_slots=4, max_context=32, page_size=8, prefill_chunk=4))
-    per_layer = (cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim
+    per_layer = (-(-(cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim)
+                   // 128) * 128
                  if cfg.mla else 2 * cfg.n_kv_heads * cfg.head_dim)
     assert eng.registry.get("serve_kv_bytes_per_token",
                             layout=layout).value == cfg.n_layers * per_layer * 2
