@@ -150,8 +150,8 @@ def run(fast: bool = False):
         "speculative greedy output diverged from non-spec greedy"
 
     # -- hot-prefix case (shared system prompt, radix cache) ------------------
-    # No caller-supplied prefix_key anywhere: the content-addressed radix
-    # cache must find the shared 16-token prefix on its own.
+    # No caller coordination: the content-addressed radix cache must
+    # find the shared 16-token prefix on its own.
     eng = OnlineEngine(runner, params, OnlineConfig(**geometry))
     run_poisson_load(eng, rate=100.0, n_requests=2, prompt_len=8,
                      max_new=2, vocab_size=cfg.vocab_size, seed=7)

@@ -12,7 +12,6 @@ Paper artifacts covered:
   table2_pcache      checkpoint-write dispersal (~2.3-2.7x)
   babel_metadata     parallel metadata prefetch (36x claim shape)
   babel_crc          sampled-CRC vs full-MD5 verification
-  table3_flood       Flood pipeline vs synchronous baseline token/s
   serve_online       online continuous batching: TTFT/ITL/tok/s vs load
   dpo_packing        DPO data packing (3.7x claim)
   table1_hetero      heterogeneous cost model (20% savings claim)
@@ -33,7 +32,7 @@ import time
 
 BENCHES = [
     "fig4_xputimer", "fig8_edit", "table2_pcache", "babel_metadata",
-    "babel_crc", "table3_flood", "serve_online", "spec_decode",
+    "babel_crc", "serve_online", "spec_decode",
     "dpo_packing", "table1_hetero", "fig12_13_scaling", "fig14_spikes",
     "fig18_eval", "kernels", "paged_attn", "train_step", "roofline",
 ]

@@ -34,7 +34,7 @@ for i in range(30):
               f"balance={float(m['router/balance_loss']):.3f}  "
               f"dropped={float(m['moe/dropped_frac']):.4f}")
 
-# greedy decode a few tokens with the segment-cache-backed decode step
+# greedy decode a few tokens with the dense-cache decode step
 decode, _ = runner.make_decode_step(global_batch=4, seq_len=128)
 decode = jax.jit(decode)
 caches = M.init_caches(cfg, runner.env, 4, 128)

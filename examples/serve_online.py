@@ -30,10 +30,11 @@ sys_prompt = rs.randint(0, cfg.vocab_size, 16).astype(np.int32)
 reqs = [OnlineRequest(rid=i, prompt=sys_prompt, max_new=16)
         for i in range(10)]
 
-# no prefix keys anywhere: the first request's prefill publishes the
+# no caller coordination: the first request's prefill publishes the
 # shared system prompt's two full pages into the content-addressed radix
-# cache; every later arrival attaches them and skips re-prefilling the
-# 16 tokens (watch `prefix_hits` / `radix_hit_tokens` below)
+# cache; every later arrival attaches the first and skips re-prefilling
+# its 8 tokens (the page holding a prompt's last token is always
+# prefilled privately; watch `prefix_hits` / `radix_hit_tokens` below)
 eng.submit(reqs[0])
 while reqs[0].state != "decode":
     eng.tick()

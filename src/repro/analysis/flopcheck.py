@@ -106,7 +106,7 @@ KNOWN_DONATING_MAKERS = {"jit_train_step": (0, 1), "for_accum": (0, 1)}
 
 # hot per-step loops: the Trainer train loop and the OnlineEngine tick
 # paths (plus anything matching the naming convention)
-HOT_CLASSES = {"Trainer", "OnlineEngine", "FloodEngine"}
+HOT_CLASSES = {"Trainer", "OnlineEngine"}
 HOT_FUNC_RE = re.compile(r"^(train|tick|_drain)$|_tick$")
 
 # callees whose results are host data (safe to convert per-step)

@@ -343,7 +343,7 @@ class Runner:
         """Dense fixed-batch decode step.  With ``sample=True`` it takes
         four extra per-sequence arrays ``(seeds, temperature, top_p,
         top_k)`` and draws under the same (seed, pos, stream) key
-        schedule as the online paged path (offline/online stream parity
+        schedule as the online paged path (dense/paged stream parity
         for matching seeds; bitwise greedy at temperature <= 0)."""
         cfg, env, flags = self.cfg, self.env, self.flags
         b = batch_sharding(env, global_batch)

@@ -1,6 +1,6 @@
-"""Model-evaluation efficiency subsystem (paper §2.4 "Flood"-backed
-evaluation + §5.1.2 benchmark optimization, the third headline
-optimization: C-eval).
+"""Model-evaluation efficiency subsystem (paper §2.4 evaluation +
+§5.1.2 benchmark optimization, the third headline optimization:
+C-eval).
 
 Implements the paper's three mechanisms:
 

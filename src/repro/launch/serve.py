@@ -1,21 +1,22 @@
-"""Serving launcher: offline (Flood) and online continuous-batching modes.
+"""Serving launcher: the online engine over an offline batch or a
+Poisson load.
 
-    # offline: Flood pipeline engine over a fixed request set
+    # offline: every request submitted at t=0, served to completion
     PYTHONPATH=src python -m repro.launch.serve --arch ling-lite --smoke \
         --requests 16 --max-new 16
 
-    # online: continuous batching + paged KV + Poisson load generator
+    # online: Poisson arrivals at each rate
     PYTHONPATH=src python -m repro.launch.serve --arch ling-lite --smoke \
         --online --rates 4,16 --requests 24 --max-new 8
 
-Offline builds the model, splits its layers into pipeline stages, and
-drives the FloodEngine (segment KV cache, S+1 in-flight micro-batches);
-`--baseline` runs the synchronous global-batch engine instead for the
-Table-3-shaped comparison.  Online drives the `OnlineEngine`
-(docs/serving.md): slot-based continuous batching over a paged device KV
-cache, measured under Poisson arrivals at each `--rates` entry — TTFT /
-inter-token-latency percentiles and sustained tok/s land in
-BENCH_serve_online.json (`--report` to relocate).
+Both modes build the same `api.Runner` + `OnlineEngine` (docs/serving.md):
+slot-based continuous batching over a paged device KV cache, chunked
+prefill and one compiled decode step.  Offline submits `--requests`
+random prompts at once, runs the engine until it drains and prints
+tokens, wall time and tokens/s.  Online measures the engine under
+Poisson arrivals at each `--rates` entry — TTFT / inter-token-latency
+percentiles and sustained tok/s land in BENCH_serve_online.json
+(`--report` to relocate).
 """
 from __future__ import annotations
 
@@ -25,7 +26,6 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import api
@@ -35,68 +35,10 @@ from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.serving.draft import (ConfigDrafter, SelfDrafter,
                                  adapt_drafter_config)
-from repro.serving.flood import (FloodEngine, GenRequest,
-                                 baseline_step_engine, quantize_microbatch)
-from repro.serving.online import (OnlineConfig, OnlineEngine,
+from repro.serving.online import (OnlineConfig, OnlineEngine, OnlineRequest,
+                                  make_prompts, quantize_microbatch,
                                   run_poisson_load)
-from repro.serving.segment_cache import SegmentCache
 from repro.telemetry import MetricsServer, SLOConfig, write_chrome_trace
-
-
-def build_model_engine(cfg, mesh, n_stages: int, seq_len: int,
-                       batch: int, flags: M.RunFlags = M.DEFAULT_FLAGS,
-                       temperature: float = 0.0, top_p: float = 1.0,
-                       top_k: int = 0, seed: int = 0):
-    """Real-model Flood engine: layers split into n_stages jitted chunks.
-
-    Stage state carries (x, caches_slice, pos); decode math is exactly the
-    model's block_decode.  `flags.moe_dispatch` selects the MoE decode
-    path — with tp > 1 and "ep" the decode batch routes tokens over the
-    mesh through the same all-to-all dispatch training uses.
-
-    Sampling knobs ride the sampled decode step as per-sequence data;
-    each request draws under seed `(seed + rid) % 2**31` with the same
-    counter-based (seed, position, stream) key schedule the online
-    engine uses, so an offline run reproduces an online request's token
-    stream for matching seeds/positions (temperature 0 = exact greedy).
-    """
-    runner = api.Runner(cfg, mesh, fsdp=False, seq_parallel=False,
-                        max_seq=seq_len, flags=flags)
-    params = runner.init_params(0)
-    decode, _ = runner.make_decode_step(batch, seq_len, sample=True)
-    decode = jax.jit(decode)
-    caches = M.init_caches(cfg, runner.env, batch, seq_len,
-                           cross_len=cfg.encoder_seq_len)
-    state = {"caches": caches, "pos": 0}
-
-    def embed_fn(reqs):
-        toks = np.zeros((batch,), np.int32)
-        seeds = np.zeros((batch,), np.int32)
-        for i, r in enumerate(reqs[:batch]):
-            toks[i] = (r.out[-1] if r.out else r.prompt[-1])
-            seeds[i] = (seed + r.rid) % (2 ** 31)
-        return {"tokens": jnp.asarray(toks), "seeds": jnp.asarray(seeds),
-                "reqs": len(reqs)}
-
-    def stage_fn(_i):
-        def fn(x):
-            return x  # layer stages fused into head_fn for the real model
-        return fn
-
-    knobs = (np.full((batch,), temperature, np.float32),
-             np.full((batch,), top_p, np.float32),
-             np.full((batch,), top_k, np.int32))
-
-    def head_fn(x, reqs):
-        nonlocal state
-        nxt, state["caches"] = decode(
-            params, state["caches"], x["tokens"], jnp.int32(state["pos"]),
-            x["seeds"], jnp.asarray(knobs[0]), jnp.asarray(knobs[1]),
-            jnp.asarray(knobs[2]))
-        state["pos"] += 1
-        return np.asarray(nxt)[:len(reqs)]
-
-    return embed_fn, [stage_fn(i) for i in range(n_stages)], head_fn
 
 
 def make_drafter(cfg, args):
@@ -126,12 +68,11 @@ def parse_tenant_budgets(spec):
     return out
 
 
-def run_online(cfg, mesh, flags, args) -> None:
-    """Online continuous batching under a Poisson load at each rate."""
+def build_engine(cfg, mesh, flags, args) -> OnlineEngine:
+    """The `OnlineEngine` both modes serve with, from the CLI flags."""
     runner = api.Runner(cfg, mesh, fsdp=False, seq_parallel=False,
                         max_seq=args.seq, flags=flags)
     params = runner.init_params(0)
-    budgets = parse_tenant_budgets(args.tenant_budgets)
     slo = None
     if args.overload == "slo":
         if args.slo_ttft_ms is None:
@@ -148,8 +89,33 @@ def run_online(cfg, mesh, flags, args) -> None:
         seed=args.seed, spec_k=args.spec_k,
         radix_cache=not args.no_radix_cache, policy=args.policy,
         max_queue=args.max_queue, overload=args.overload,
-        tenant_budgets=budgets, slo=slo)
-    eng = OnlineEngine(runner, params, ocfg, drafter=make_drafter(cfg, args))
+        tenant_budgets=parse_tenant_budgets(args.tenant_budgets), slo=slo)
+    return OnlineEngine(runner, params, ocfg,
+                        drafter=make_drafter(cfg, args))
+
+
+def run_offline(cfg, eng: OnlineEngine, args) -> None:
+    """Submit every request at t=0 and serve them to completion."""
+    prompts = make_prompts(np.random.RandomState(0), args.requests,
+                           args.prompt_len, cfg.vocab_size,
+                           min(args.shared_prefix_len, args.prompt_len))
+    t0 = time.perf_counter()
+    eng.submit_many([OnlineRequest(rid=i, prompt=p, max_new=args.max_new)
+                     for i, p in enumerate(prompts)])
+    eng.run()
+    wall = time.perf_counter() - t0
+    tokens = sum(len(eng.reqs[i].out) for i in range(args.requests))
+    print("allocator stats:", eng.alloc.stats)
+    print(f"tokens={tokens} wall={wall:.2f}s tok/s={tokens / wall:.1f} "
+          f"compiles=prefill:{eng.prefill_traces}+"
+          f"decode:{eng.decode_traces}")
+
+
+def run_online(cfg, eng: OnlineEngine, args) -> None:
+    """Online continuous batching under a Poisson load at each rate."""
+    ocfg = eng.cfg
+    budgets = ocfg.tenant_budgets
+    slo = ocfg.slo
     server = None
     if args.metrics_port is not None:
         # point-in-time Prometheus scrape on a background daemon thread
@@ -224,29 +190,28 @@ def run_online(cfg, mesh, flags, args) -> None:
         server.stop()
 
 
-def main():
+def main(argv=None) -> OnlineEngine:
+    """Run the launcher; returns the engine, which keeps the served
+    requests (`engine.reqs`)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="ling-lite")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=8)
-    ap.add_argument("--microbatch", type=int, default=4)
-    ap.add_argument("--stages", type=int, default=2)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--baseline", action="store_true")
     ap.add_argument("--online", action="store_true",
                     help="continuous-batching engine + Poisson load "
                          "generator (docs/serving.md)")
     ap.add_argument("--slots", type=int, default=4,
-                    help="online: decode slots (rounded up to tp)")
+                    help="decode slots (rounded up to tp)")
     ap.add_argument("--page-size", type=int, default=16,
-                    help="online: KV page size in tokens")
+                    help="KV page size in tokens")
     ap.add_argument("--pages", type=int, default=None,
-                    help="online: physical page pool size (default: every "
-                         "slot can hold a full --seq context)")
+                    help="physical page pool size (default: every slot "
+                         "can hold a full --seq context)")
     ap.add_argument("--prefill-chunk", type=int, default=8,
-                    help="online: prompt tokens prefix-filled per tick")
+                    help="prompt tokens prefilled per tick")
     ap.add_argument("--rates", default="4,16",
                     help="online: comma-separated Poisson arrival rates "
                          "(req/s), one load run each")
@@ -260,22 +225,20 @@ def main():
                     help="sampling seed base; request rid r draws under "
                          "seed (seed + r) %% 2**31")
     ap.add_argument("--spec-k", type=int, default=0,
-                    help="online: speculative draft length per tick "
-                         "(0 = off)")
+                    help="speculative draft length per tick (0 = off)")
     ap.add_argument("--draft-layers", type=int, default=1,
-                    help="online: self-draft drafter depth (first N "
-                         "target layers, no new weights)")
+                    help="self-draft drafter depth (first N target "
+                         "layers, no new weights)")
     ap.add_argument("--draft-arch", default=None,
-                    help="online: use a separate small arch as the "
-                         "drafter instead of self-draft (vocab aligned "
-                         "via adapt_drafter_config; fresh weights)")
+                    help="use a separate small arch as the drafter "
+                         "instead of self-draft (vocab aligned via "
+                         "adapt_drafter_config; fresh weights)")
     ap.add_argument("--shared-prefix-len", type=int, default=0,
-                    help="online: tokens of shared system prompt per "
-                         "request (hot-prefix workload; 0 = disjoint "
-                         "prompts)")
+                    help="tokens of shared system prompt per request "
+                         "(hot-prefix workload; 0 = disjoint prompts)")
     ap.add_argument("--policy", default="fcfs",
                     choices=["fcfs", "decode-priority", "prefill-priority"],
-                    help="online: tick-ordering policy (decode-priority "
+                    help="tick-ordering policy (decode-priority "
                          "never preempts decoders for arriving prompts; "
                          "prefill-priority drains all prefill chunks "
                          "before decoding to bound head-of-queue TTFT)")
@@ -307,9 +270,9 @@ def main():
                          "run (per-slot + scheduler-phase tracks, counter "
                          "tracks) viewable at https://ui.perfetto.dev")
     ap.add_argument("--no-radix-cache", action="store_true",
-                    help="online: disable the content-addressed radix "
-                         "prefix cache (on by default; token streams are "
-                         "identical either way)")
+                    help="share nothing: disable the content-addressed "
+                         "radix prefix cache (on by default; token "
+                         "streams are identical either way)")
     ap.add_argument("--tenant-budgets", default=None,
                     help="online: per-tenant admitted-token caps as "
                          "'name:tokens,name:tokens'; the loadgen round-"
@@ -323,51 +286,28 @@ def main():
                     choices=["auto", "fused", "ragged", "batched", "ep"],
                     help="MoE decode dispatch; 'ep' routes decode batches "
                          "over the mesh via the all-to-all expert-parallel "
-                         "path (requires microbatch %% tp == 0)")
+                         "path (slots and chunk are rounded up to tp)")
     ap.add_argument("--paged-attn", default="auto",
                     choices=["auto", "fused", "gathered"],
-                    help="online paged-attention backend: 'fused' walks the "
+                    help="paged-attention backend: 'fused' walks the "
                          "page table inside the Pallas kernel (no gathered "
                          "KV view in HBM), 'gathered' materializes it via "
                          "paged_gather (parity oracle); 'auto' = fused on "
                          "interpret builds, gathered on real TPUs")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_local_mesh(1, args.tp)
     flags = M.RunFlags(moe_dispatch=args.moe_dispatch,
                        paged_attn=args.paged_attn)
+    eng = build_engine(cfg, mesh, flags, args)
     if args.online:
-        run_online(cfg, mesh, flags, args)
-        return
-    rs = np.random.RandomState(0)
-    reqs = [GenRequest(rid=i,
-                       prompt=rs.randint(0, cfg.vocab_size,
-                                         args.prompt_len).astype(np.int32),
-                       max_new=args.max_new)
-            for i in range(args.requests)]
-
-    micro = quantize_microbatch(args.microbatch, args.tp)
-    embed_fn, stage_fns, head_fn = build_model_engine(
-        cfg, mesh, args.stages, args.seq, micro, flags=flags,
-        temperature=args.temperature, top_p=args.top_p, top_k=args.top_k,
-        seed=args.seed)
-
-    if args.baseline:
-        stats = baseline_step_engine(head_fn, embed_fn, reqs)
+        run_online(cfg, eng, args)
     else:
-        eng = FloodEngine(stage_fns, head_fn, embed_fn,
-                          cache=SegmentCache(max_tokens=1 << 16,
-                                             initial_segment=32,
-                                             extend_chunk=32),
-                          microbatch=micro, batch_multiple=args.tp)
-        eng.submit(reqs)
-        stats = eng.run()
-        print("cache stats:", eng.cache.stats)
-    print(f"tokens={stats.tokens_out} wall={stats.wall_s:.2f}s "
-          f"tok/s={stats.tokens_per_s:.1f}")
+        run_offline(cfg, eng, args)
+    return eng
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -1,10 +1,9 @@
-"""Online continuous-batching serving engine over a paged device KV cache.
+"""Continuous-batching serving engine over a paged device KV cache.
 
-Flood (serving/flood.py) is the *offline* half of the paper's §2.4 story:
-a fixed request set, dense (B, seq_len) caches, host-side segment
-bookkeeping.  This module is the *online* half the ROADMAP north star
-asks for — requests arrive over time, join a running batch, and stream
-tokens out — built from three pieces:
+The one serving engine: requests arrive over time, join a running
+batch, and stream tokens out.  An offline batch is the same engine with
+every request submitted at t=0 (`launch/serve.py` without `--online`).
+It is built from these pieces:
 
   * **Fixed-shape jitted serve steps.**  `max_slots` request slots; one
     paged decode tick over all slots (`api.Runner.make_paged_decode_step`)
@@ -42,18 +41,19 @@ tokens out — built from three pieces:
     `PageAllocator` keeps a trie keyed by page-aligned token blocks:
     admission walks it with the request's exact prefill tokens and
     attaches every matching refcounted KV page automatically — repeated
-    system prompts cost zero prefill with no caller-supplied
-    `prefix_key`.  Full pages publish into the trie when prefill
-    completes, when a request releases, and when it is preempted;
+    system prompts skip their prefill with no caller coordination.
+    Full pages publish into the trie when prefill completes, when a
+    request releases, and when it is preempted;
     unreferenced cached pages are LRU-evicted (leaf-first) only when an
     allocation would otherwise fail, so caching never causes an OOM an
-    uncached run would not hit.  Cache on/off is bitwise-invisible in
-    the token streams (greedy and seeded sampling alike).
+    uncached run would not hit.  With the cache off the engine shares
+    nothing; on/off is bitwise-invisible in the token streams (greedy
+    and seeded sampling alike).
 
-The per-slot decode batch shares every MoE decode constraint with the
-offline engine: `max_slots` and `prefill_chunk` must satisfy
-`quantize_microbatch(n, tp) == n` (the EP all-to-all path slices token
-ownership over tp), checked at construction.
+The per-slot decode batch rides the MoE decode dispatch: `max_slots`
+and `prefill_chunk` must satisfy `quantize_microbatch(n, tp) == n` (the
+EP all-to-all path slices token ownership over tp), checked at
+construction.
 
 Two newer layers ride the same fixed-shape contract:
 
@@ -61,8 +61,8 @@ Two newer layers ride the same fixed-shape contract:
     (B,)-shaped DATA into the jitted steps; draws use the counter-based
     (seed, position, stream) key schedule in `models.embedding`, so
     token streams are reproducible across preemption replay and match
-    the offline engine's `make_decode_step(sample=True)` for equal
-    seeds.  Temperature 0 is bitwise-equal to greedy argmax.
+    the dense `make_decode_step(sample=True)` for equal seeds.
+    Temperature 0 is bitwise-equal to greedy argmax.
 
   * **Speculative decoding** (`spec_k > 0` + a `serving.draft` drafter).
     Each spec tick: the drafter proposes k tokens per slot (scan of
@@ -109,7 +109,6 @@ import numpy as np
 from repro.analysis import contracts
 from repro.models import layers as L
 from repro.models import model as M
-from repro.serving.flood import quantize_microbatch
 from repro.serving.segment_cache import PageAllocator
 from repro.telemetry.metrics import MetricsRegistry
 from repro.telemetry.request_log import RequestLog
@@ -119,6 +118,16 @@ from repro.telemetry.xputimer import XPUTimer
 
 POLICIES = ("fcfs", "decode-priority", "prefill-priority")
 OVERLOAD = ("defer", "shed", "slo")
+
+
+def quantize_microbatch(n: int, multiple: int) -> int:
+    """Round a batch size up to a multiple.  The expert-parallel MoE
+    decode path (core/moe.py dispatch="ep") slices token ownership over
+    the tp mesh axis, so the slot batch and the prefill chunk must
+    satisfy n % tp == 0."""
+    if multiple > 1 and n % multiple:
+        n += multiple - n % multiple
+    return n
 
 
 @dataclasses.dataclass
@@ -136,12 +145,12 @@ class OnlineConfig:
     k+1 candidate KV rows before the host commits.
 
     `radix_cache` turns on the cross-request content-addressed prefix
-    cache (docs/serving.md): matching KV pages attach at admission with
-    no caller-supplied `prefix_key`, full pages publish into the trie on
-    prefill completion / release / preemption, and unreferenced cached
-    pages LRU-evict only when an allocation would otherwise fail.  The
-    scheduler knobs are pure host data — `policy` picks the tick
-    ordering ("fcfs" | "decode-priority" | "prefill-priority"),
+    cache (docs/serving.md): matching KV pages attach at admission, full
+    pages publish into the trie on prefill completion / release /
+    preemption, and unreferenced cached pages LRU-evict only when an
+    allocation would otherwise fail.  With it off, requests share
+    nothing.  The scheduler knobs are pure host data — `policy` picks
+    the tick ordering ("fcfs" | "decode-priority" | "prefill-priority"),
     `max_queue` bounds the arrival queue (`overload` picks shed vs defer
     when it is full), and `tenant_budgets` caps each tenant's admitted
     prompt+max_new tokens — none of them change any jitted step shape,
@@ -193,8 +202,6 @@ class OnlineRequest:
     rid: int
     prompt: np.ndarray
     max_new: int
-    prefix_key: Optional[str] = None
-    prefix_len: int = 0              # tokens to auto-publish under prefix_key
     tenant: Optional[str] = None     # admission-budget accounting key
     arrival_t: float = 0.0
     # sampling overrides (None -> the OnlineConfig default); the seed is
@@ -243,7 +250,7 @@ class OnlineEngine:
             raise ValueError(
                 f"max_slots={cfg.max_slots} violates the EP decode batch "
                 f"constraint (max_slots % tp == 0 for tp={tp}); round up "
-                f"with serving.flood.quantize_microbatch(max_slots, tp) = "
+                f"with serving.online.quantize_microbatch(max_slots, tp) = "
                 f"{quantize_microbatch(cfg.max_slots, tp)}")
         if quantize_microbatch(cfg.prefill_chunk, tp) != cfg.prefill_chunk:
             raise ValueError(
@@ -520,13 +527,6 @@ class OnlineEngine:
                     f"submit_many is for unbounded batches — use submit "
                     f"and handle the False return")
 
-    def register_prefix(self, rid: int, key: str, n_tokens: int):
-        """Publish a live request's leading full pages for prefix reuse;
-        later submissions carrying `prefix_key=key` skip prefilling the
-        shared tokens (contract: their prompt starts with the same
-        tokens)."""
-        self.alloc.register_prefix(rid, key, n_tokens)
-
     # -- scheduling helpers ---------------------------------------------------
     def _free_slots(self) -> List[int]:
         return [int(s) for s in np.flatnonzero(self.slot_rid < 0)]
@@ -576,20 +576,14 @@ class OnlineEngine:
                                      np.asarray(r.out[:-1], np.int32)])
                      if r.out else np.asarray(r.prompt, np.int32)
                      ).astype(np.int32)
-            if self.cfg.radix_cache:
-                # content-addressed attach: walk the radix trie with the
-                # exact tokens this request will prefill (on re-admission
-                # after a preempt that includes its own emitted tokens,
-                # so a published victim re-attaches nearly everything)
-                shared = self.alloc.admit(rid, tokens=r.fed)
-            else:
-                # legacy keyed attach, capped at the request's ORIGINAL
-                # prompt: generated tokens diverge from the publisher's
-                # continuation, and shared pages must never receive this
-                # request's writes
-                shared = self.alloc.admit(rid, prefix_key=r.prefix_key,
-                                          prompt_len=len(r.prompt))
-            r.prefill_pos = min(shared, max(len(r.fed) - 1, 0))
+            # content-addressed attach: walk the radix trie with the
+            # exact tokens this request will prefill (on re-admission
+            # after a preempt that includes its own emitted tokens, so a
+            # published victim re-attaches nearly everything); the
+            # allocator never attaches the page of the last fed token,
+            # which the prefill writes
+            r.prefill_pos = self.alloc.admit(
+                rid, tokens=r.fed if self.cfg.radix_cache else None)
             r.state = "prefill"
             r.admit_t = now
             self.slot_rid[slot] = rid
@@ -691,23 +685,20 @@ class OnlineEngine:
         LRU-evicted unreferenced cached pages before reporting failure —
         eviction always precedes preemption).  Failing with no victims
         left means this request is the sole resident and STILL cannot
-        fit — nothing will ever free (only pinned prefix pages and its
-        own remain), so raise instead of letting the scheduler thrash
-        through endless self-preemption.  With `allow_preempt=False`
+        fit — nothing will ever free (only its own pages remain), so
+        raise instead of letting the scheduler thrash through endless
+        self-preemption.  With `allow_preempt=False`
         (decode-priority prefill) a grow that would need a victim
         returns False instead — the caller defers to a later tick."""
         while not self.alloc.ensure_capacity(rid, n_tokens):
             victims = [s for s in self._busy_slots()
                        if int(self.slot_rid[s]) != rid]
             if not victims:
-                pinned = sum(len(p) for p in
-                             self.alloc.prefix_index.values())
                 raise RuntimeError(
                     f"request {rid} needs {n_tokens} tokens "
                     f"({-(-n_tokens // self.cfg.page_size)} pages) but the "
                     f"pool cannot satisfy it even empty: {self.alloc.n_free}"
-                    f" free, {pinned} page refs pinned by registered "
-                    f"prefixes (drop_prefix to release)")
+                    f" free")
             if not allow_preempt:
                 return False
             self._preempt_slot(max(victims, key=lambda s: self.slot_seq[s]))
@@ -784,15 +775,8 @@ class OnlineEngine:
             # the moment they are written, so concurrent arrivals with
             # the same prefix hit while this request is still decoding.
             # Content addressing dedupes same-prefix racers — no
-            # prefix_key coordination, no double-publish
+            # coordination, no double-publish
             self.alloc.publish_radix(rid, r.fed)
-        elif (r.prefix_key and r.prefix_len > 0
-                and r.prefix_key not in self.alloc.prefix_index):
-            # legacy keyed auto-publish: first finisher wins; a same-key
-            # racer's identical pages stay private (content-dedup needs
-            # the radix path) and recycle on its release
-            self.alloc.register_prefix(rid, r.prefix_key,
-                                       min(r.prefix_len, len(r.prompt)))
         if not r.out:
             with self._span("prefill.sync"):
                 tok = int(jax.device_get(nxt))
@@ -1063,11 +1047,23 @@ def _pctl(xs: Sequence[float], q: float) -> float:
     return float(np.percentile(np.asarray(xs, np.float64), q)) if xs else 0.0
 
 
+def make_prompts(rs: np.random.RandomState, n: int, prompt_len: int,
+                 vocab_size: int, shared_prefix_len: int = 0
+                 ) -> List[np.ndarray]:
+    """`n` random prompts of `prompt_len` tokens that all open with the
+    same `shared_prefix_len` tokens (a shared system prompt)."""
+    shared = rs.randint(0, vocab_size, shared_prefix_len).astype(np.int32)
+    return [np.concatenate([
+        shared,
+        rs.randint(0, vocab_size,
+                   prompt_len - shared_prefix_len).astype(np.int32)])
+        for _ in range(n)]
+
+
 def run_poisson_load(engine: OnlineEngine, *, rate: float, n_requests: int,
                      prompt_len: int, max_new: int, vocab_size: int,
                      seed: int = 0, max_ticks: int = 1_000_000,
                      shared_prefix_len: int = 0,
-                     prefix_key: Optional[str] = None,
                      tenants: Optional[Sequence[str]] = None
                      ) -> Dict[str, Any]:
     """Open-loop Poisson arrivals at `rate` req/s against a live engine.
@@ -1084,9 +1080,9 @@ def run_poisson_load(engine: OnlineEngine, *, rate: float, n_requests: int,
     hits need **no coordination**: the first request to finish prefill
     publishes its full pages into the trie and later arrivals attach by
     content — the report's `prefix_hits` / `prefix_hit_rate` count how
-    many did.  With the cache off the legacy `prefix_key` registry
-    carries the sharing instead.  The cache is flushed before returning
-    so repeated loads on one engine start cold.
+    many did.  With the cache off nothing is shared.  The cache is
+    flushed before returning so repeated loads on one engine start
+    cold.
 
     A bounded-queue engine may defer (submission retried while the
     arrival is late) or shed (request dropped, counted in `n_shed`)
@@ -1096,15 +1092,8 @@ def run_poisson_load(engine: OnlineEngine, *, rate: float, n_requests: int,
     gaps = rs.exponential(1.0 / rate, size=n_requests)
     arrivals = np.cumsum(gaps)
     shared_prefix_len = min(shared_prefix_len, prompt_len)
-    use_key = shared_prefix_len > 0 and not engine.cfg.radix_cache
-    if use_key and prefix_key is None:
-        prefix_key = f"poisson-load-{seed}"
-    shared = rs.randint(0, vocab_size, shared_prefix_len).astype(np.int32)
-    prompts = [np.concatenate([
-        shared,
-        rs.randint(0, vocab_size,
-                   prompt_len - shared_prefix_len).astype(np.int32)])
-        for _ in range(n_requests)]
+    prompts = make_prompts(rs, n_requests, prompt_len, vocab_size,
+                           shared_prefix_len)
     base = (max(engine.reqs) + 1) if engine.reqs else 0   # engine reuse
     ticks0, preempts0 = engine.ticks, engine.n_preemptions
     hits0 = engine.alloc.stats["prefix_hits"]
@@ -1113,8 +1102,6 @@ def run_poisson_load(engine: OnlineEngine, *, rate: float, n_requests: int,
     shed0, budget_skips0 = engine.n_shed, engine.n_budget_skips
     proposed0, accepted0 = engine.spec_proposed, engine.spec_accepted
     reqs = [OnlineRequest(rid=base + i, prompt=prompts[i], max_new=max_new,
-                          prefix_key=(prefix_key if use_key else None),
-                          prefix_len=(shared_prefix_len if use_key else 0),
                           tenant=(tenants[i % len(tenants)]
                                   if tenants else None))
             for i in range(n_requests)]
@@ -1147,8 +1134,6 @@ def run_poisson_load(engine: OnlineEngine, *, rate: float, n_requests: int,
     n_shed = len(reqs) - len(served)
     assert all(r.done for r in served)
     engine.pop_done()              # keep the engine bounded across loads
-    if prefix_key is not None and prefix_key in engine.alloc.prefix_index:
-        engine.alloc.drop_prefix(prefix_key)
     engine.alloc.flush_radix()     # repeated loads start cache-cold
     ttft = [r.first_token_t - r.arrival_t for r in served]
     itl: List[float] = []

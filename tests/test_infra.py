@@ -1,5 +1,5 @@
 """Infrastructure tests: PCache, Babel, XPUTimer, hetero cost model,
-scaling laws, DPO packing, Flood engine."""
+scaling laws, DPO packing."""
 import os
 import time
 
@@ -11,8 +11,6 @@ import pytest
 from repro.checkpoint import babel as B
 from repro.checkpoint import pcache as PC
 from repro.core import hetero, scaling_laws as SL
-from repro.serving.flood import FloodEngine, GenRequest, baseline_step_engine
-from repro.serving.segment_cache import SegmentCache
 from repro.telemetry.xputimer import XPUTimer
 from repro.training import dpo
 
@@ -262,52 +260,3 @@ def test_segment_pooling_matches_per_sequence():
         want_r = tok_lp[seg == 2 * pid + 1].sum()
         assert float(chosen[pid]) == pytest.approx(want_c, rel=1e-4)
         assert float(rejected[pid]) == pytest.approx(want_r, rel=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# Flood engine (scheduling level)
-# ---------------------------------------------------------------------------
-
-
-def _stub_engine(n_stages=4, micro=4):
-    def embed_fn(reqs):
-        return {"n": len(reqs)}
-
-    def stage_fn(x):
-        return x
-
-    def head_fn(x, reqs):
-        return [r.rid % 50 for r in reqs]
-
-    return embed_fn, [stage_fn] * n_stages, head_fn
-
-
-def test_flood_completes_all_requests():
-    embed, stages, head = _stub_engine()
-    eng = FloodEngine(stages, head, embed,
-                      cache=SegmentCache(4096, 16, 16), microbatch=4)
-    reqs = [GenRequest(i, np.arange(4, dtype=np.int32), max_new=5)
-            for i in range(12)]
-    eng.submit(reqs)
-    stats = eng.run()
-    assert all(len(r.out) == 5 for r in reqs)
-    assert stats.tokens_out == 60
-    eng.cache.check_invariants()
-
-
-def test_flood_beats_sync_baseline_with_sync_overhead():
-    """With per-step global-sync cost (the TP pattern), the pipeline engine
-    sustains higher token throughput — the Table-3 direction."""
-    embed, stages, head = _stub_engine()
-    reqs_a = [GenRequest(i, np.arange(4, dtype=np.int32), max_new=8)
-              for i in range(16)]
-    reqs_b = [GenRequest(i, np.arange(4, dtype=np.int32), max_new=8)
-              for i in range(16)]
-    eng = FloodEngine(stages, head, embed,
-                      cache=SegmentCache(1 << 16, 16, 16), microbatch=4)
-    eng.submit(reqs_a)
-    flood = eng.run()
-    base = baseline_step_engine(lambda x, r: head(x, r), embed, reqs_b,
-                                sync_overhead_s=0.002)
-    assert flood.tokens_out == base.tokens_out
-    assert flood.tokens_per_s > base.tokens_per_s

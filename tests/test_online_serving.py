@@ -9,8 +9,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,9 +16,9 @@ from repro import api
 from repro.analysis.contracts import compile_guard
 from repro.configs.base import get_smoke_config
 from repro.launch.mesh import make_local_mesh
-from repro.models import model as M
 from repro.serving.online import (OnlineConfig, OnlineEngine, OnlineRequest,
                                   run_poisson_load)
+from util import dense_greedy
 
 
 @pytest.fixture(scope="module")
@@ -31,26 +29,6 @@ def runner_params():
     return runner, runner.init_params(0)
 
 
-def _dense_greedy(runner, params, prompts: np.ndarray, n_new: int,
-                  seq_len: int) -> np.ndarray:
-    """Reference: the fixed-batch make_decode_step path, prompt fed
-    token-by-token (the contract the online engine must reproduce)."""
-    B, P = prompts.shape
-    decode, _ = runner.make_decode_step(global_batch=B, seq_len=seq_len)
-    decode = jax.jit(decode)
-    caches = M.init_caches(runner.cfg, runner.env, B, seq_len,
-                           cross_len=runner.cfg.encoder_seq_len)
-    tok = None
-    for pos in range(P):
-        tok, caches = decode(params, caches, jnp.asarray(prompts[:, pos]),
-                             jnp.int32(pos))
-    out = [tok]
-    for pos in range(P, P + n_new - 1):
-        tok, caches = decode(params, caches, tok, jnp.int32(pos))
-        out.append(tok)    # device until the loop ends (FC-HOSTSYNC)
-    return np.stack(jax.device_get(out), 1)       # (B, n_new)
-
-
 def test_online_matches_fixed_batch_decode(runner_params):
     """Greedy online serving (chunked prefill + paged decode) emits
     token-for-token what the dense fixed-batch path emits — bitwise at
@@ -59,7 +37,7 @@ def test_online_matches_fixed_batch_decode(runner_params):
     B, P, NEW, S = 4, 6, 5, 64
     rs = np.random.RandomState(0)
     prompts = rs.randint(0, runner.cfg.vocab_size, (B, P)).astype(np.int32)
-    ref = _dense_greedy(runner, params, prompts, NEW, S)
+    ref = dense_greedy(runner, params, prompts, NEW, S)
 
     # page_size * max_pages == dense seq_len -> identical gathered length
     eng = OnlineEngine(runner, params,
@@ -119,48 +97,11 @@ def test_online_compile_count_under_churn(runner_params):
         assert a.out == b.out, (a.rid, a.out, b.out)
 
 
-def test_online_prefix_sharing(runner_params):
-    """Legacy keyed prefix path (radix_cache=False): a second request
-    carrying the prefix key skips prefilling the shared full pages and
-    still produces exactly the no-sharing outputs; pages free only once
-    the index is dropped."""
-    runner, params = runner_params
-    S, P, NEW = 64, 16, 4
-    rs = np.random.RandomState(3)
-    prompt = rs.randint(0, runner.cfg.vocab_size, P).astype(np.int32)
-    ocfg = OnlineConfig(max_slots=4, max_context=S, page_size=8,
-                        prefill_chunk=8, radix_cache=False)
-
-    eng = OnlineEngine(runner, params, ocfg)
-    a = OnlineRequest(rid=0, prompt=prompt, max_new=NEW)
-    eng.submit(a)
-    # prefill request 0 fully, then publish its prompt as a shared prefix
-    while a.state != "decode":
-        eng.tick()
-    eng.register_prefix(0, "sys", P)
-    eng.run(max_ticks=200)
-
-    b = OnlineRequest(rid=1, prompt=prompt, max_new=NEW, prefix_key="sys")
-    eng.submit(b)
-    eng.run(max_ticks=200)
-    assert eng.alloc.stats["prefix_hits"] == 1
-    assert b.out == a.out                      # same prompt, greedy decode
-    # the shared pages outlive both requests via the index...
-    held = len(eng.alloc.prefix_index["sys"])
-    assert held == P // ocfg.page_size
-    assert (eng.alloc.n_free
-            == eng.alloc.n_pages - eng.alloc.reserved - held)
-    # ...and return to the pool when dropped
-    eng.alloc.drop_prefix("sys")
-    eng.alloc.check_invariants()
-    assert eng.alloc.n_free == eng.alloc.n_pages - eng.alloc.reserved
-
-
 def test_online_radix_prefix_sharing(runner_params):
-    """Radix twin of the keyed test: NO caller-supplied prefix_key
-    anywhere.  The first request's prompt pages are published into the
-    content-addressed trie on release; a second request with the same
-    prompt attaches them automatically, emits identical greedy output,
+    """No caller coordination: the first request's prompt pages are
+    published into the content-addressed trie; a second request with
+    the same prompt attaches every one but the page holding its last
+    token (which its prefill writes), emits identical greedy output,
     and flushing the cache returns every page to the pool."""
     runner, params = runner_params
     S, P, NEW = 64, 16, 4
@@ -181,7 +122,7 @@ def test_online_radix_prefix_sharing(runner_params):
     eng.submit(b)
     eng.run(max_ticks=200)
     assert eng.alloc.stats["prefix_hits"] >= 1
-    assert eng.alloc.stats["radix_hit_tokens"] >= P
+    assert eng.alloc.stats["radix_hit_tokens"] == P - 8
     assert b.out == a.out                      # same prompt, greedy decode
     eng.alloc.check_invariants()
     eng.alloc.flush_radix()
@@ -271,49 +212,11 @@ def test_radix_parity_eviction_reprefill(runner_params):
     eng_on.alloc.check_invariants()
 
 
-def test_legacy_same_key_racer_regression(runner_params):
-    """Legacy keyed path regression (the bug the radix cache fixes): two
-    same-key requests racing through prefill — only the first finisher
-    publishes; the second's identical pages must stay private and
-    recycle on its release (no leak, no double-registration), and a
-    later keyed request still hits the published copy."""
-    runner, params = runner_params
-    S, P = 64, 16
-    rs = np.random.RandomState(3)
-    prompt = rs.randint(0, runner.cfg.vocab_size, P).astype(np.int32)
-    eng = OnlineEngine(runner, params,
-                       OnlineConfig(max_slots=4, max_context=S,
-                                    page_size=8, prefill_chunk=8,
-                                    radix_cache=False))
-    # both admitted before either finishes prefill: neither hits at
-    # admission (index empty), both race to the publish point
-    eng.submit_many([OnlineRequest(rid=0, prompt=prompt, max_new=3,
-                                   prefix_key="sys", prefix_len=P),
-                     OnlineRequest(rid=1, prompt=prompt, max_new=3,
-                                   prefix_key="sys", prefix_len=P)])
-    eng.run(max_ticks=300)
-    assert eng.alloc.stats["prefix_hits"] == 0
-    held = len(eng.alloc.prefix_index["sys"])
-    assert held == P // 8                      # registered exactly once
-    # the loser's duplicate pages recycled on release — only the
-    # published copy outlives the pair
-    eng.alloc.check_invariants()
-    assert eng.alloc.n_free == eng.alloc.n_pages - eng.alloc.reserved - held
-
-    c = OnlineRequest(rid=2, prompt=prompt, max_new=3, prefix_key="sys",
-                      prefix_len=P)
-    eng.submit(c)
-    eng.run(max_ticks=200)
-    assert eng.alloc.stats["prefix_hits"] == 1
-    eng.alloc.drop_prefix("sys")
-    assert eng.alloc.n_free == eng.alloc.n_pages - eng.alloc.reserved
-
-
 def test_radix_same_prefix_racer_dedupes(runner_params):
-    """Radix counterpart: two same-prompt racers both publish on prefill
-    completion; content addressing keeps exactly one cached copy (the
-    dedups stat counts the collision) and the invariant checker proves
-    no page is cached at two nodes."""
+    """Two same-prompt racers both publish on prefill completion;
+    content addressing keeps exactly one cached copy (the dedups stat
+    counts the collision) and the invariant checker proves no page is
+    cached at two nodes."""
     runner, params = runner_params
     S, P = 64, 16
     rs = np.random.RandomState(3)

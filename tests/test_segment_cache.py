@@ -1,174 +1,11 @@
-"""Segment KV cache: unit tests + hypothesis property tests on the
-allocator invariants (no overlap, coalesced free list, waiter progress),
-a randomized admit/extend/release/preempt churn stress, and the
-page-granular `PageAllocator` the online serving engine uses."""
+"""The online engine's `PageAllocator` and its radix prefix cache:
+admission, growth, preemption, deterministic recycling, refcounted
+sharing, content-addressed attach, LRU eviction, and a randomized churn
+stress with exact page accounting after every op."""
 import numpy as np
 import pytest
-from util import given, settings, st   # hypothesis, or a skip shim
 
-from repro.serving.segment_cache import PageAllocator, SegmentCache
-
-
-def test_admit_and_write():
-    c = SegmentCache(max_tokens=1024, initial_segment=16, extend_chunk=16)
-    assert c.admit(1, prompt_len=8, max_new=100)
-    slots = [c.write_token(1) for _ in range(30)]
-    assert all(s is not None for s in slots)
-    assert len(set(slots)) == 30                  # distinct cache rows
-    c.check_invariants()
-    assert c.stats["extends"] >= 1                # grew past the first seg
-
-
-def test_extend_prefers_adjacent():
-    c = SegmentCache(max_tokens=1024, initial_segment=8, extend_chunk=8)
-    c.admit(1, 4, 100)
-    for _ in range(40):
-        c.write_token(1)
-    # single request: all growth should be in-place extension
-    assert c.stats["appends"] == 0
-    assert len(c.requests[1].segments) == 1
-    c.check_invariants()
-
-
-def test_append_when_blocked():
-    c = SegmentCache(max_tokens=256, initial_segment=32, extend_chunk=32)
-    c.admit(1, 8, 200)
-    c.admit(2, 8, 200)     # sits right after request 1 -> blocks extension
-    for _ in range(80):
-        assert c.write_token(1) is not None
-    assert c.stats["appends"] >= 1
-    c.check_invariants()
-
-
-def test_wait_and_revive():
-    c = SegmentCache(max_tokens=80, initial_segment=32, extend_chunk=32)
-    assert c.admit(1, 8, 100)           # 40 tokens
-    assert c.admit(2, 8, 100)           # 40 tokens -> cache full
-    # exhaust request 1's capacity; extension and append both impossible
-    got_none = False
-    for _ in range(200):
-        if c.write_token(1) is None:
-            got_none = True
-            break
-    assert got_none, "cache should eventually be exhausted"
-    assert c.stats["waits"] >= 1
-    revived = c.release(2)
-    assert 1 in revived                 # waiter makes progress
-    assert c.write_token(1) is not None
-    c.check_invariants()
-
-
-def test_prefix_caching_shares_segments():
-    c = SegmentCache(max_tokens=4096, initial_segment=64, extend_chunk=64)
-    c.admit(1, 32, 10)
-    c.register_prefix(1, "sys-prompt")
-    before_free = sum(l for _, l in c.free)
-    c.admit(2, 32, 10, prefix_key="sys-prompt")
-    c.admit(3, 32, 10, prefix_key="sys-prompt")
-    assert c.stats["prefix_hits"] == 2
-    # shared prefix: requests 2,3 allocated less fresh memory than req 1
-    seg1 = c.requests[1].segments[0]
-    assert seg1.refcount >= 3
-    c.release(2)
-    c.release(3)
-    assert seg1.refcount >= 1           # still owned by request 1 + index
-    c.check_invariants()
-
-
-def _churn(seed: int, n_ops: int = 400, max_tokens: int = 256):
-    """One deterministic admit/extend/release/preempt churn run.  Returns
-    (cache, admission order, revived-waiter log) for cross-run
-    comparison."""
-    rs = np.random.RandomState(seed)
-    c = SegmentCache(max_tokens=max_tokens, initial_segment=16,
-                     extend_chunk=16)
-    live, admitted, revived_log = [], [], []
-    next_rid = 0
-    for _ in range(n_ops):
-        op = rs.randint(4)
-        if op == 0:                                   # admit
-            next_rid += 1
-            if c.admit(next_rid, prompt_len=int(rs.randint(1, 16)),
-                       max_new=int(rs.randint(1, 64))):
-                live.append(next_rid)
-                admitted.append(next_rid)
-        elif op == 1 and live:                        # extend (write run)
-            rid = live[rs.randint(len(live))]
-            for _ in range(int(rs.randint(1, 24))):
-                if c.write_token(rid) is None:
-                    break
-        elif op == 2 and live:                        # release
-            rid = live.pop(rs.randint(len(live)))
-            revived_log.append(tuple(c.release(rid)))
-        elif op == 3 and live:                        # preempt
-            rid = live.pop(rs.randint(len(live)))
-            revived_log.append(tuple(c.preempt(rid)))
-        c.check_invariants()
-    for rid in list(live):
-        c.release(rid)
-    c.check_invariants()
-    return c, admitted, revived_log
-
-
-def test_churn_stress_admit_extend_release_preempt():
-    """Randomized churn (incl. the new preempt path) never violates the
-    allocator invariants, leaks no ranges, and replays identically —
-    admissions AND the order waiters are revived in are deterministic."""
-    for seed in (0, 1, 2):
-        c, admitted, revived = _churn(seed)
-        assert sum(l for _, l in c.free) == c.max_tokens   # nothing leaked
-        assert not c.requests
-        assert c.stats["preempts"] >= 1, "churn never preempted"
-        c2, admitted2, revived2 = _churn(seed)
-        assert admitted2 == admitted
-        assert revived2 == revived
-
-
-def test_preempt_frees_and_allows_readmission():
-    c = SegmentCache(max_tokens=96, initial_segment=32, extend_chunk=32)
-    assert c.admit(1, 8, 100)
-    assert c.admit(2, 8, 100)
-    for _ in range(20):
-        assert c.write_token(1) is not None
-    c.preempt(1)
-    assert 1 not in c.requests
-    assert c.stats["preempts"] == 1
-    c.check_invariants()
-    assert c.admit(1, 8, 100)          # deterministic re-admission works
-    assert c.write_token(1) is not None
-    c.check_invariants()
-
-
-@settings(max_examples=60, deadline=None)
-@given(st.lists(st.tuples(st.integers(0, 2), st.integers(1, 40)),
-                min_size=1, max_size=60),
-       st.integers(128, 512))
-def test_allocator_invariants(ops, max_tokens):
-    """Random admit/write/release sequences never violate the allocator
-    invariants."""
-    c = SegmentCache(max_tokens=max_tokens, initial_segment=16,
-                     extend_chunk=16)
-    rid = 0
-    live = []
-    for kind, arg in ops:
-        if kind == 0:  # admit
-            rid += 1
-            if c.admit(rid, prompt_len=arg % 16 + 1, max_new=arg):
-                live.append(rid)
-        elif kind == 1 and live:  # write tokens
-            r = live[arg % len(live)]
-            for _ in range(arg):
-                if c.write_token(r) is None:
-                    break
-        elif kind == 2 and live:  # release
-            r = live.pop(arg % len(live))
-            c.release(r)
-        c.check_invariants()
-    # drain
-    for r in list(live):
-        c.release(r)
-    c.check_invariants()
-    assert sum(l for _, l in c.free) == max_tokens   # all memory returned
+from repro.serving.segment_cache import PageAllocator
 
 
 # ---------------------------------------------------------------------------
@@ -206,29 +43,6 @@ def test_page_allocator_all_or_nothing_and_preempt():
     a.check_invariants()
 
 
-def test_page_allocator_prefix_sharing_refcounts():
-    a = PageAllocator(n_pages=12, page_size=8)
-    a.admit(1)
-    a.ensure_capacity(1, 20)                    # 2 full pages + 1 partial
-    a.register_prefix(1, "sys", 16)             # only FULL pages shared
-    assert len(a.prefix_index["sys"]) == 2
-    shared = a.admit(2, prefix_key="sys")
-    assert shared == 16
-    assert a.pages[2][:2] == a.pages[1][:2]
-    assert a.stats["prefix_hits"] == 1
-    a.ensure_capacity(2, 24)                    # private growth page
-    assert a.pages[2][2] != a.pages[1][2]
-    a.check_invariants()
-    a.release(1)                                # shared pages stay (index+2)
-    a.check_invariants()
-    a.release(2)
-    held = len(a.prefix_index["sys"])
-    assert a.n_free == a.n_pages - a.reserved - held
-    a.drop_prefix("sys")
-    assert a.n_free == a.n_pages - a.reserved
-    a.check_invariants()
-
-
 def test_page_allocator_deterministic_recycling():
     """Identical op sequences hand out identical page ids (the engine's
     parity and compile-count tests rely on this)."""
@@ -245,18 +59,28 @@ def test_page_allocator_deterministic_recycling():
     assert run() == run()
 
 
-def test_page_allocator_prefix_clamped_to_consumer_prompt():
-    """A consumer whose prompt is shorter than the published prefix must
-    not attach shared pages beyond its own prompt — its decode would
-    write new-token KV straight into pages other requests attend."""
-    a = PageAllocator(n_pages=12, page_size=8)
-    a.admit(1)
-    a.ensure_capacity(1, 24)
-    a.register_prefix(1, "sys", 24)             # 3 full pages published
-    shared = a.admit(2, prefix_key="sys", prompt_len=16)
-    assert shared == 16                          # clamped, not 24
-    assert len(a.pages[2]) == 2
-    assert a.pages[2] == a.pages[1][:2]
+@pytest.mark.parametrize("n_tokens", [8, 10])
+def test_page_allocator_without_tokens_shares_nothing(n_tokens):
+    """A request admitted without tokens attaches no page, even when
+    another request has published the same content, and gives back
+    every page it took."""
+    a = PageAllocator(n_pages=12, page_size=4)
+    toks = list(range(n_tokens))
+    a.admit(1, tokens=toks)
+    a.ensure_capacity(1, n_tokens)
+    a.release(1, tokens=toks)                   # full pages cached
+    cached = a.n_cached_pages
+    assert cached == n_tokens // 4
+    assert a.admit(2) == 0
+    assert a.pages[2] == [] and a.shared_len[2] == 0
+    assert a.stats["prefix_hits"] == 0
+    a.ensure_capacity(2, n_tokens)
+    assert not set(a.pages[2]) & {n.page for n in a.match_radix(toks)}
+    a.check_invariants()
+    a.release(2)
+    assert a.n_free == a.n_pages - a.reserved - cached
+    a.flush_radix()
+    assert a.n_free == a.n_pages - a.reserved
     a.check_invariants()
 
 
@@ -308,8 +132,9 @@ def test_radix_eviction_lru_leaf_first():
         a.ensure_capacity(rid, len(toks))
         a.release(rid, tokens=toks)
     assert a.n_cached_pages == 5
-    # touch chain_a -> chain_b is now the LRU chain
-    a.admit(3, tokens=chain_a)
+    # touch chain_a -> chain_b is now the LRU chain (the fed tokens run
+    # one past the chain: the last fed token's page never attaches)
+    a.admit(3, tokens=chain_a + [0])
     a.release(3)
     assert a.evict_radix(1) == 1                # takes chain_b's LEAF
     assert [n.key for n in a.match_radix(chain_b)] == [(7, 8)]
@@ -333,7 +158,7 @@ def test_radix_eviction_mid_attach_adversarial():
     a.release(1, tokens=chain)
     assert a.n_cached_pages == 5
 
-    b = a.admit(2, tokens=chain[:4])            # attach first 2 pages
+    b = a.admit(2, tokens=chain[:5])            # attach first 2 pages
     assert b == 4
     held = list(a.pages[2])
     # pool pressure: a new request wants 4 pages; only 2 are free, so
@@ -345,7 +170,7 @@ def test_radix_eviction_mid_attach_adversarial():
     assert [n.page for n in a.match_radix(chain[:4])] == held
     a.check_invariants()
     # the survivor keeps serving hits after the sweep
-    assert a.admit(4, tokens=chain[:4]) == 4
+    assert a.admit(4, tokens=chain[:5]) == 4
     a.check_invariants()
     # drain everything; flush returns the pool to empty
     for rid in (2, 3, 4):
@@ -489,21 +314,58 @@ def test_radix_ensure_capacity_evicts_before_failing():
     a.check_invariants()
 
 
-def test_page_allocator_reregister_prefix_releases_old():
-    """Re-registering a key must drop the old entry's refcounts — the
-    old pages return to the pool instead of leaking forever."""
-    a = PageAllocator(n_pages=12, page_size=8)
-    a.admit(1)
-    a.ensure_capacity(1, 16)
-    a.register_prefix(1, "sys", 16)
-    a.admit(2)
-    a.ensure_capacity(2, 16)
-    a.register_prefix(2, "sys", 16)             # replaces the entry
+@pytest.mark.parametrize("cached_len", [8, 12])
+def test_radix_attach_leaves_last_token_private(cached_len):
+    """A wholly cached, page-aligned prompt attaches every cached page
+    but the one holding its last token: the prefill writes that token's
+    KV, and it must land in a private page, not in one other requests
+    read."""
+    ps = 4
+    a = PageAllocator(n_pages=12, page_size=ps)
+    prompt = list(range(100, 100 + cached_len))
+    a.admit(1, tokens=prompt)
+    a.ensure_capacity(1, cached_len)
+    a.release(1, tokens=prompt)
+    cached = [n.page for n in a.match_radix(prompt)]
+    assert len(cached) == cached_len // ps
+    shared = a.admit(2, tokens=prompt)
+    last_page = (cached_len - 1) // ps
+    assert shared == last_page * ps             # ends before that page
+    assert a.pages[2] == cached[:last_page]
+    a.ensure_capacity(2, cached_len)
+    assert a.pages[2][last_page] not in cached
+    assert a.refcount[a.pages[2][last_page]] == 1
     a.check_invariants()
-    a.release(1)
-    a.release(2)
-    held = len(a.prefix_index["sys"])
-    assert a.n_free == a.n_pages - a.reserved - held
-    a.drop_prefix("sys")
-    assert a.n_free == a.n_pages - a.reserved   # nothing leaked
+
+
+@pytest.mark.parametrize("order", [(1, 2), (2, 1)])
+def test_radix_refcounts_survive_either_release_order(order):
+    """Pages two live requests attached stay allocated until both
+    release, then stay cached until the trie is flushed; the private
+    pages each grew recycle on their own release."""
+    a = PageAllocator(n_pages=12, page_size=8)
+    prompt = list(range(17))                    # 2 full pages + 1
+    a.admit(0, tokens=prompt)
+    a.ensure_capacity(0, len(prompt))
+    a.release(0, tokens=prompt)
+    shared = [n.page for n in a.match_radix(prompt)]
+    assert len(shared) == 2
+    for rid in (1, 2):
+        assert a.admit(rid, tokens=prompt) == 16
+        a.ensure_capacity(rid, 24)              # private growth page
+        assert a.pages[rid][:2] == shared
+    assert a.pages[1][2] != a.pages[2][2]
+    assert all(a.refcount[p] == 3 for p in shared)   # trie + 2 requests
+    a.check_invariants()
+    first, second = order
+    a.release(first)
+    assert all(a.refcount[p] == 2 for p in shared)
+    assert not set(shared) & set(a.free_list)
+    a.check_invariants()
+    a.release(second)
+    assert all(a.refcount[p] == 1 for p in shared)   # the trie's own
+    assert a.n_free == a.n_pages - a.reserved - 2
+    a.check_invariants()
+    assert a.flush_radix() == 2
+    assert a.n_free == a.n_pages - a.reserved
     a.check_invariants()

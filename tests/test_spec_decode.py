@@ -257,11 +257,11 @@ def test_page_allocator_trim():
     alloc.check_invariants()
 
     # published prefix pages survive a trim below their extent
-    alloc.register_prefix(0, "sys", 8)             # first 2 pages shared
+    alloc.publish_radix(0, list(range(8)))         # first 2 pages shared
     alloc.trim(0, 0)
     assert alloc.pages[0] == held[:2]
     alloc.release(0)
-    alloc.drop_prefix("sys")
+    alloc.flush_radix()
     alloc.check_invariants()
     assert alloc.n_free == alloc.n_pages - alloc.reserved
 

@@ -1,5 +1,7 @@
 """Shared test helpers."""
 import jax
+import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 # Optional-dep shim: `hypothesis` is not installed in every container.
@@ -33,6 +35,7 @@ except ImportError:                                   # pragma: no cover
 
 from repro import sharding
 from repro.launch.mesh import make_local_mesh
+from repro.models import model as M
 from repro.sharding import make_axis_env
 
 
@@ -50,3 +53,23 @@ def smap_env(fn, *, out_specs=None):
         return wrapped(*args)
 
     return call, env
+
+
+def dense_greedy(runner, params, prompts: np.ndarray, n_new: int,
+                 seq_len: int) -> np.ndarray:
+    """Reference: the fixed-batch make_decode_step path, prompt fed
+    token-by-token (the contract the online engine must reproduce)."""
+    B, T = prompts.shape
+    decode, _ = runner.make_decode_step(global_batch=B, seq_len=seq_len)
+    decode = jax.jit(decode)
+    caches = M.init_caches(runner.cfg, runner.env, B, seq_len,
+                           cross_len=runner.cfg.encoder_seq_len)
+    tok = None
+    for pos in range(T):
+        tok, caches = decode(params, caches, jnp.asarray(prompts[:, pos]),
+                             jnp.int32(pos))
+    out = [tok]
+    for pos in range(T, T + n_new - 1):
+        tok, caches = decode(params, caches, tok, jnp.int32(pos))
+        out.append(tok)    # device until the loop ends (FC-HOSTSYNC)
+    return np.stack(jax.device_get(out), 1)       # (B, n_new)
